@@ -25,7 +25,8 @@ lifecycle").
   under a join;
 * the AOT compile-cache manifest: key sensitivity, hit/miss
   accounting across boots, corrupt-manifest recovery, and
-  ``boot_precompile`` never raising;
+  ``boot_precompile`` surviving an unwritable cache dir (compile
+  errors propagate — tests/test_device_runtime.py);
 * the ScanServer lifecycle surface (warming /healthz, token-gated
   /handoff, /prefetch adoption, metrics sections) and the
   prewarm/handoff/compile-cache exposition on both planes.
@@ -597,7 +598,7 @@ class TestAotManifest:
         finally:
             COMPILE_CACHE_METRICS.reset()
 
-    def test_boot_precompile_never_raises(self, tmp_path):
+    def test_boot_precompile_survives_a_bad_cache_dir(self, tmp_path):
         from trivy_tpu.runtime.aot import boot_precompile
         blocker = tmp_path / "file"
         blocker.write_text("x")

@@ -191,7 +191,12 @@ class Index:
 
     @staticmethod
     def _donate_positions(value) -> Optional[tuple]:
-        """``jax.jit(f, donate_argnums=...)`` -> donated positions."""
+        """``jax.jit(f, donate_argnums=...)`` -> donated positions
+        (seen through the ``ops.program.DeviceProgram`` wrapper)."""
+        if isinstance(value, ast.Call) and value.args and \
+                isinstance(value.func, ast.Name) and \
+                value.func.id == "DeviceProgram":
+            value = value.args[0]
         if not (isinstance(value, ast.Call)
                 and isinstance(value.func, ast.Attribute)
                 and value.func.attr == "jit"):

@@ -739,6 +739,8 @@ def main(argv=None) -> int:
             return 2
     from .artifact.redis_cache import RedisError
     from .artifact.s3_cache import S3Error
+    from .ops.program import DeviceProgramError
+    from .runtime.device import DeviceUnavailable
     # --profile-out supersedes --profile-dir (same jax trace, plus
     # the host profiler's folded stacks); one wrapper, never two
     # stacked jax.profiler.trace contexts
@@ -753,11 +755,17 @@ def main(argv=None) -> int:
         if args.command in ("server", "watch") else 0.0
     try:
         with scan_deadline(timeout_s), \
-                _profiled(profile_dir, profile_window):
+                _profiled(profile_dir, profile_window,
+                          device=_owns_device(args)):
             return _dispatch(args)
     except (RedisError, S3Error, ValueError) as e:
         # cache-backend connect/IO failures and bad backend values
         # fail cleanly, never with a traceback
+        print(f"error: {e}", file=sys.stderr)
+        return 1
+    except (DeviceUnavailable, DeviceProgramError) as e:
+        # no device where one was asked for, or a kernel that does
+        # not compile: never a fallback, never exit 0
         print(f"error: {e}", file=sys.stderr)
         return 1
     except ScanTimeout:
@@ -770,27 +778,73 @@ import contextlib
 
 
 @contextlib.contextmanager
-def _profiled(profile_dir: str, max_seconds: float = 0.0):
+def _profiled(profile_dir: str, max_seconds: float = 0.0,
+              device: bool = True):
     """--profile-out / --profile-dir: capture a jax.profiler device
     trace of the scan (the reference's pprof/trace analog; SURVEY §5
     tracing row) plus the host profiler's collapsed stacks
     (host_profile.folded). The trace opens in TensorBoard/Perfetto;
     phase-level host/device timings live in
     BatchScanRunner.last_stats and the bench JSON. The single
-    jax-trace wrapper lives in obs.profiler.device_trace — a box
-    with no jax profiler plugin still gets the host profile."""
+    jax-trace wrapper lives in obs.profiler.device_trace; a process
+    that owns no device (``device=False``: thin clients, cpu-ref)
+    writes the host profile only."""
     if not profile_dir:
         yield
         return
     from .obs.profiler import device_trace
     try:
-        with device_trace(profile_dir, max_seconds=max_seconds):
+        with device_trace(profile_dir, max_seconds=max_seconds,
+                          device=device):
             yield
     finally:
         # the trace flushes even when the scan errors or times out —
         # exactly when it is most wanted
         print(f"profile trace written to {profile_dir}",
               file=sys.stderr)
+
+
+# commands whose scans dispatch kernels (vuln → interval match,
+# secret → DFA sieve); everything else is host-only
+_SCAN_COMMANDS = ("image", "filesystem", "fs", "rootfs", "repo",
+                  "sbom", "k8s", "client", "c", "watch")
+
+
+def _device_backend(args) -> str:
+    """The backend this process resolves a device for: the
+    ``--backend`` flag, except that a process which dispatches no
+    kernel resolves none (``cpu-ref``) — a ``--server`` client (the
+    server owns the chip; the client-side secret pass runs the host
+    engine, byte-identical by construction), the server-less
+    commands, and scans with neither vuln nor secret checks."""
+    if args.command == "server":
+        return "tpu"
+    if args.command not in _SCAN_COMMANDS:
+        return "cpu-ref"
+    if getattr(args, "server", "") or (
+            args.command in ("client", "c")
+            and getattr(args, "remote", "")):
+        return "cpu-ref"
+    checks = {c for c in getattr(args, "security_checks",
+                                 "").split(",") if c}
+    if args.command != "sbom" and not checks & {"vuln", "secret"}:
+        return "cpu-ref"
+    if args.command == "k8s" and not getattr(args, "images_dir", ""):
+        return "cpu-ref"        # manifests only: no image to scan
+    return getattr(args, "backend", "tpu")
+
+
+def _owns_device(args) -> bool:
+    return _device_backend(args) != "cpu-ref"
+
+
+def _resolve_device(args):
+    """Place the compile cache and check the device, first thing in
+    every command that dispatches kernels (after the multi-host
+    join where there is one, so ``jax.devices()`` is the global
+    set). Raises DeviceUnavailable — main() turns it into exit 1."""
+    from .runtime.device import resolve_device
+    return resolve_device(_device_backend(args))
 
 
 def _dispatch(args) -> int:
@@ -1051,6 +1105,7 @@ def run_k8s(args) -> int:
         print(f"error: compliance reports support table/json, not "
               f"{args.format}", file=sys.stderr)
         return 2
+    _resolve_device(args)
     checks = [c for c in args.security_checks.split(",") if c]
     scanner = K8sScanner(
         store=_store(args),
@@ -1138,6 +1193,7 @@ def run_server(args) -> int:
     rc = _init_multihost(args)
     if rc:
         return rc
+    _resolve_device(args)
     sched = "off"
     scheduler = None
     if getattr(args, "sched", "on") == "on":
@@ -1232,17 +1288,16 @@ def run_server(args) -> int:
         return 2
     print(f"trivy-tpu server listening on {args.listen}")
     try:
-        serve_forever(host or "127.0.0.1", int(port), server,
-                      db_watch_prefix=args.compiled_db,
-                      db_watch_interval_s=args.db_watch_interval,
-                      drain_timeout_s=getattr(args, "drain_timeout",
-                                              30.0))
+        return serve_forever(
+            host or "127.0.0.1", int(port), server,
+            db_watch_prefix=args.compiled_db,
+            db_watch_interval_s=args.db_watch_interval,
+            drain_timeout_s=getattr(args, "drain_timeout", 30.0))
     finally:
         if adm_runner is not None:
             adm_runner.close()
         if scheduler is not None:
             scheduler.close()
-    return 0
 
 
 def run_route(args) -> int:
@@ -1388,6 +1443,7 @@ def run_watch(args) -> int:
                         WebhookSource, dir_resolver,
                         make_event_storm)
 
+    _resolve_device(args)
     try:
         store = _store(args)
     except (OSError, ValueError) as e:
@@ -1678,7 +1734,9 @@ def _artifact_option(args) -> ArtifactOption:
     scanner = None
     if "secret" in checks:
         cpu = new_scanner(load_config(args.secret_config))
-        backend = "cpu-ref" if args.backend == "cpu-ref" else "tpu"
+        # a process that owns no device (--backend cpu-ref, or a
+        # --server client) sieves on the host engine
+        backend = "tpu" if _owns_device(args) else "cpu-ref"
         scanner = BatchSecretScanner(scanner=cpu, backend=backend)
         # the rule config itself is excluded from scanning
         from .analyzer import registered_analyzers
@@ -1921,6 +1979,7 @@ def run_image(args) -> int:
         print("error: image target or --input required",
               file=sys.stderr)
         return 2
+    _resolve_device(args)
     opt = _artifact_option(args)
     from .guard import make_budget
     budget = make_budget(opt.ingest_limits,
@@ -2105,6 +2164,7 @@ def _run_image_batch(args, targets: list) -> int:
     rc = _init_multihost(args)
     if rc:
         return rc
+    _resolve_device(args)
     runner = BatchScanRunner(
         store=store, cache=cache, backend=backend,
         secret_scanner=opt.secret_scanner,
@@ -2129,9 +2189,8 @@ def _run_image_batch(args, targets: list) -> int:
             import shutil
             shutil.rmtree(hostile_dir, ignore_errors=True)
     if getattr(args, "sched_stats", False):
-        dump = stats.get("sched", stats)
+        dump = _process_stats(stats.get("sched", stats))
         if injector is not None:
-            dump = dict(dump)
             dump["faults"] = injector.stats()
         print(json.dumps(dump, indent=2), file=sys.stderr)
     if trace_out:
@@ -2140,6 +2199,31 @@ def _run_image_batch(args, targets: list) -> int:
               f"({get_tracer().n_exported} total this process)",
               file=sys.stderr)
     return _finish_many(args, results)
+
+
+def _process_stats(stats: dict) -> dict:
+    """The ``--sched-stats`` dump: the run's own stats plus the
+    process-wide books, so the dump names the device the kernels
+    ran on and counts the device work on both execution paths. The
+    scheduler's snapshot already carries the detect/secret/dispatch
+    books; the direct path's phase stats gain them here (its
+    per-batch sieve stats move to ``secret_batch``)."""
+    from .ops.program import compiled_programs
+    from .runtime.aot import COMPILE_CACHE_METRICS
+    from .runtime.device import device_identity
+    dump = dict(stats)
+    if "counters" not in dump:
+        from .detect.metrics import DETECT_METRICS
+        from .runtime.ring import RING_METRICS
+        from .secret.metrics import SECRET_METRICS
+        dump["secret_batch"] = dump.pop("secret", {})
+        dump["secret"] = SECRET_METRICS.snapshot()
+        dump["detect"] = DETECT_METRICS.snapshot()
+        dump["dispatch"] = RING_METRICS.snapshot()
+    dump["compile_cache"] = COMPILE_CACHE_METRICS.snapshot()
+    dump["programs"] = compiled_programs()
+    dump["device"] = device_identity()
+    return dump
 
 
 def _trace_out(args) -> str:
@@ -2237,6 +2321,7 @@ def run_sbom(args) -> int:
     if not os.path.isfile(args.target):
         print(f"error: no such file: {args.target}", file=sys.stderr)
         return 1
+    _resolve_device(args)
     cache = _cache(args)
     # vuln-only scan: no analyzers or secret stack needed
     artifact = SBOMArtifact(args.target, cache,
@@ -2271,6 +2356,7 @@ def run_repo(args) -> int:
     from .artifact.remote import GitError, RemoteRepoArtifact
     if _reject_unwired_fault_spec(args):
         return 2
+    _resolve_device(args)
     cache = _cache(args)
     artifact = RemoteRepoArtifact(
         args.target, cache, option=_artifact_option(args),
@@ -2307,6 +2393,7 @@ def run_fs(args) -> int:
         print(f"error: no such directory: {args.target}",
               file=sys.stderr)
         return 1
+    _resolve_device(args)
     cache = _cache(args)
     artifact = LocalFSArtifact(args.target, cache,
                                option=_artifact_option(args))

@@ -267,6 +267,8 @@ def detect_pairs(jobs: list, backend: str = "tpu",
         else:
             hits = np.asarray(_device_hits(
                 pkg_rank, v_lo, v_hi, s_lo, s_hi, flags_arr))
+        if backend != "cpu-ref":
+            DETECT_METRICS.note_wave(P)
         sink["device_s"] = sink.get("device_s", 0.0) + \
             _time.perf_counter() - t0
         for i in np.nonzero(hits[:P])[0]:
@@ -507,6 +509,8 @@ def detect_pairs_resident(jobs: list, backend: str = "tpu",
                 # dispatch of this generation → never donated
                 hits = np.asarray(interval_hits_resident_donated(
                     dr, di, *tables))
+        if backend != "cpu-ref":
+            DETECT_METRICS.note_wave(P)
         sink["device_s"] = sink.get("device_s", 0.0) + \
             _time.perf_counter() - t0
         for i in np.nonzero(hits[:P])[0]:
@@ -618,6 +622,7 @@ class _WaveSegment:
             wave["slot"] = slot
         else:
             wave = build()
+        DETECT_METRICS.note_wave(wave["rows"])
         self.waves.append(wave)
 
     def _collect_wave(self, wave: dict):

@@ -21,6 +21,9 @@ class DetectMetrics:
     _KEYS = (
         # dispatch_jobs: jobs submitted vs unique after dedup
         "jobs_in", "jobs_unique",
+        # interval kernel launches on the device and the (unpadded)
+        # pair rows they carried — cpu-ref evaluations add nothing
+        "device_waves", "device_rows",
         # constraint-interval compile cache (detect/ccache.py)
         "interval_cache_hits", "interval_cache_misses",
         # purl parse cache (purl.from_string)
@@ -47,6 +50,11 @@ class DetectMetrics:
         with self._lock:
             self._c["jobs_in"] += jobs_in
             self._c["jobs_unique"] += jobs_unique
+
+    def note_wave(self, rows: int) -> None:
+        with self._lock:
+            self._c["device_waves"] += 1
+            self._c["device_rows"] += rows
 
     def note_db_upload(self, nbytes: int) -> None:
         with self._lock:

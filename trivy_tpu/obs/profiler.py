@@ -21,8 +21,9 @@ profiler on vs off.
 The optional **device** trace rides :func:`device_trace`: an opt-in
 ``jax.profiler`` hook behind ``--profile-out DIR`` (the host
 profiler's folded stacks are dumped next to it as
-``host_profile.folded``). Import of jax is deferred and failure-
-tolerant — a CPU-only box still gets the host profile.
+``host_profile.folded``). Import of jax is deferred; a CPU
+platform whose trace cannot start still gets the host profile, a
+TPU platform fails instead — the device timeline is the point.
 
 Clock discipline: bucket keys and sample timing are
 ``time.monotonic``; wall time appears nowhere in the math (lint-
@@ -35,6 +36,10 @@ import os
 import sys
 import threading
 import time
+
+from ..utils import get_logger
+
+log = get_logger("obs.profiler")
 
 DEFAULT_HZ = 49.0
 # per-second buckets retained — 15 minutes of history
@@ -217,16 +222,22 @@ def get_profiler(start: bool = True) -> HostProfiler:
 
 class _DeviceTraceCtx:
     """Context manager behind :func:`device_trace`: jax.profiler
-    around the body when available, host folded stacks dumped either
-    way. ``max_seconds > 0`` bounds the capture: a daemon timer
-    closes the trace and writes the artifacts after the window, so a
-    long-lived body (the server's ``serve_forever``) cannot
-    accumulate an unbounded device trace that only flushes at
-    process exit."""
+    around the body, host folded stacks dumped either way.
+    ``device=False`` (a process that owns no device) skips the jax
+    trace — starting it would initialise a backend. On a TPU
+    platform a trace that cannot start is an error, not a quiet
+    host-only profile: the device timeline is what the flag was
+    given for. ``max_seconds > 0`` bounds the capture: a daemon
+    timer closes the trace and writes the artifacts after the
+    window, so a long-lived body (the server's ``serve_forever``)
+    cannot accumulate an unbounded device trace that only flushes
+    at process exit."""
 
-    def __init__(self, out_dir: str, max_seconds: float = 0.0):
+    def __init__(self, out_dir: str, max_seconds: float = 0.0,
+                 device: bool = True):
         self.out_dir = out_dir
         self.max_seconds = max_seconds
+        self.device = device
         self._jax_trace = None
         self._timer = None
         self._done = threading.Lock()
@@ -237,18 +248,33 @@ class _DeviceTraceCtx:
             return self
         os.makedirs(self.out_dir, exist_ok=True)
         get_profiler()
-        try:
-            import jax
-            self._jax_trace = jax.profiler.trace(self.out_dir)
-            self._jax_trace.__enter__()
-        except Exception:           # noqa: BLE001 — no jax / no
-            self._jax_trace = None  # profiler plugin: host-only
+        if self.device:
+            self._start_jax_trace()
         if self.max_seconds > 0:
             self._timer = threading.Timer(self.max_seconds,
                                           self._finish)
             self._timer.daemon = True
             self._timer.start()
         return self
+
+    def _start_jax_trace(self) -> None:
+        import jax
+        try:
+            self._jax_trace = jax.profiler.trace(self.out_dir)
+            self._jax_trace.__enter__()
+        except Exception as e:      # noqa: BLE001 — whatever the
+            # profiler plugin raises; classified just below
+            self._jax_trace = None
+            try:
+                platform = jax.default_backend()
+            except RuntimeError:
+                platform = "tpu"    # the backend itself is broken
+            if platform == "tpu":
+                raise RuntimeError(
+                    f"device trace could not start on the TPU "
+                    f"({self.out_dir}): {e!r}") from e
+            log.warning("jax device trace unavailable on %s, host "
+                        "profile only: %r", platform, e)
 
     def _finish(self, *exc) -> None:
         with self._done:
@@ -277,11 +303,12 @@ class _DeviceTraceCtx:
         self._finish(*exc)
 
 
-def device_trace(out_dir: str,
-                 max_seconds: float = 0.0) -> _DeviceTraceCtx:
+def device_trace(out_dir: str, max_seconds: float = 0.0,
+                 device: bool = True) -> _DeviceTraceCtx:
     """``--profile-out DIR``: opt-in jax.profiler device trace (open
     in TensorBoard/Perfetto) + the host profiler's collapsed stacks
     written to ``DIR/host_profile.folded``. A falsy ``out_dir`` is a
     no-op; ``max_seconds`` bounds the capture window (0 = until the
-    context exits)."""
-    return _DeviceTraceCtx(out_dir, max_seconds=max_seconds)
+    context exits); ``device=False`` writes the host profile only."""
+    return _DeviceTraceCtx(out_dir, max_seconds=max_seconds,
+                           device=device)

@@ -164,7 +164,10 @@ def render_prometheus(stats: dict, phase_hists=None,
         w.sample(name, [("version", binfo.get("version", "")),
                         ("jax_version",
                          binfo.get("jax_version", "")),
-                        ("backend", binfo.get("backend", "")),
+                        ("platform", binfo.get("platform", "")),
+                        ("device_kind",
+                         binfo.get("device_kind", "")),
+                        ("devices", binfo.get("devices", 0)),
                         ("sched", binfo.get("sched", ""))], 1)
 
     counters = stats.get("counters") or {}

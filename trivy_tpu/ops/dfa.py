@@ -68,6 +68,7 @@ from jax import lax
 from ..db.compiled import ResidentTables
 from .keywords import (CODE_CHUNK, MAX_CODE_LEN, N_BLOCKS, SIEVE_CAP,
                        pack_code, pad_batch)
+from .program import DeviceProgram
 from .runs import RunSpec
 
 MAX_LIT_BYTES = 32        # literal patterns: up to 4 masked words
@@ -683,8 +684,7 @@ def _build_mesh_sieve(table: DfaTable, mesh, run_specs: tuple,
                       platform: str):
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import (DATA_AXIS, RULES_AXIS,
-                                 shard_map_compat)
+    from ..parallel.mesh import DATA_AXIS, RULES_AXIS
     from .runs import run_hits_impl
     masks_fn = _masks_fn(table, platform)
     row = P((DATA_AXIS, RULES_AXIS), None)
@@ -699,10 +699,11 @@ def _build_mesh_sieve(table: DfaTable, mesh, run_specs: tuple,
 
     rep = tuple(P(*([None] * a.ndim))
                 for a in table._resident_arrays())
-    fn = shard_map_compat(local, mesh=mesh,
-                          in_specs=(row,) + rep,
-                          out_specs=(row, row))
-    return jax.jit(fn)
+    # check_vma off: masks are row-elementwise per shard, nothing
+    # is replicated on the way out
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(row,) + rep,
+                       out_specs=(row, row), check_vma=False)
+    return DeviceProgram(jax.jit(fn), "dfa_mesh_sieve")
 
 
 def _build_sieve(table: DfaTable, kind: str, run_specs: tuple,
@@ -729,10 +730,9 @@ def _build_sieve(table: DfaTable, kind: str, run_specs: tuple,
             hits = jnp.zeros((B, 0), jnp.bool_)
         return masks, hits
 
-    full = jax.jit(full, donate_argnums=(0,))
-
     if kind == "full":
-        return full
+        return DeviceProgram(jax.jit(full, donate_argnums=(0,)),
+                             "dfa_full_sieve")
 
     def fused(segments, *dev):
         masks = masks_fn(segments, dev).astype(jnp.uint16)
@@ -749,7 +749,8 @@ def _build_sieve(table: DfaTable, kind: str, run_specs: tuple,
             hits = jnp.zeros((B, 0), jnp.bool_)
         return nhit, idx, cmasks, hits
 
-    return jax.jit(fused, donate_argnums=(0,))
+    return DeviceProgram(jax.jit(fused, donate_argnums=(0,)),
+                         "dfa_fused_sieve")
 
 
 __all__ = [

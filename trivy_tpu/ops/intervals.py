@@ -23,6 +23,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .program import DeviceProgram
+
 # NOTE: the donated kernels below free their per-batch payload
 # buffers as soon as the kernel consumes them (the slot-reuse
 # contract of the async runtime). The hit output is bool while the
@@ -57,7 +59,8 @@ def interval_hits_impl(pkg_rank: jax.Array, vuln_lo: jax.Array,
     return force | jnp.where(has_vuln, with_vuln, without_vuln)
 
 
-interval_hits = jax.jit(interval_hits_impl)
+interval_hits = DeviceProgram(jax.jit(interval_hits_impl),
+                              "interval_hits")
 
 # donated variant for the async slot runtime (docs/performance.md
 # "Async device runtime"): every operand is a PER-BATCH payload
@@ -66,8 +69,9 @@ interval_hits = jax.jit(interval_hits_impl)
 # next upload instead of holding two copies alive per in-flight
 # batch. Callers must device_put fresh buffers per dispatch and
 # never touch them again (the arrays are deleted after the call).
-interval_hits_donated = jax.jit(interval_hits_impl,
-                                donate_argnums=(0, 1, 2, 3, 4, 5))
+interval_hits_donated = DeviceProgram(
+    jax.jit(interval_hits_impl, donate_argnums=(0, 1, 2, 3, 4, 5)),
+    "interval_hits")
 
 
 def interval_hits_resident_impl(pkg_rank: jax.Array,
@@ -84,7 +88,8 @@ def interval_hits_resident_impl(pkg_rank: jax.Array,
                               sec_hi[row_idx], flags[row_idx])
 
 
-interval_hits_resident = jax.jit(interval_hits_resident_impl)
+interval_hits_resident = DeviceProgram(
+    jax.jit(interval_hits_resident_impl), "interval_hits_resident")
 
 # resident variant: ONLY the per-batch gather operands (pkg ranks +
 # candidate row indices) are donated — argnums 2..6 are the
@@ -92,8 +97,9 @@ interval_hits_resident = jax.jit(interval_hits_resident_impl)
 # generation, and donating one would free the store under every
 # concurrent scanner (the buffer-donation audit's hard rule:
 # payload buffers yes, resident tables never).
-interval_hits_resident_donated = jax.jit(
-    interval_hits_resident_impl, donate_argnums=(0, 1))
+interval_hits_resident_donated = DeviceProgram(
+    jax.jit(interval_hits_resident_impl, donate_argnums=(0, 1)),
+    "interval_hits_resident")
 
 
 def interval_hits_host(pkg_rank, vuln_lo, vuln_hi, sec_lo, sec_hi,

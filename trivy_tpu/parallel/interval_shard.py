@@ -24,8 +24,9 @@ import functools
 import numpy as np
 
 from ..ops.intervals import interval_hits_impl
+from ..ops.program import DeviceProgram
 from .mesh import (DATA_AXIS, RULES_AXIS, mesh_axis_sizes,
-                   pad_to_multiple, shard_map_compat)
+                   pad_to_multiple)
 
 _PAIR_AXES = (DATA_AXIS, RULES_AXIS)
 
@@ -38,13 +39,16 @@ def _build_pair_hits(mesh):
     row = P(_PAIR_AXES)
     tbl = P(_PAIR_AXES, None)
 
-    fn = shard_map_compat(
+    # check_vma off: hits are row-elementwise, every output is a
+    # per-shard value with no replication to verify
+    fn = jax.shard_map(
         interval_hits_impl,
         mesh=mesh,
         in_specs=(row, tbl, tbl, tbl, tbl, row),
         out_specs=row,
+        check_vma=False,
     )
-    return jax.jit(fn)
+    return DeviceProgram(jax.jit(fn), "interval_hits_mesh")
 
 
 @functools.lru_cache(maxsize=8)
@@ -60,13 +64,14 @@ def _build_resident_hits(mesh):
             pkg_rank, v_lo[row_idx], v_hi[row_idx],
             s_lo[row_idx], s_hi[row_idx], flags[row_idx])
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(row, row, rep, rep, rep, rep, P(None)),
         out_specs=row,
+        check_vma=False,
     )
-    return jax.jit(fn)
+    return DeviceProgram(jax.jit(fn), "interval_hits_resident_mesh")
 
 
 def _pad_rows(n_devices: int, *arrs):

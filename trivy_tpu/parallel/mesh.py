@@ -52,26 +52,6 @@ def make_mesh(n_devices: Optional[int] = None,
     return Mesh(grid, (DATA_AXIS, RULES_AXIS))
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: the top-level API
-    (``check_vma``, jax ≥ 0.6), its ``check_rep`` predecessor, and
-    the 0.4.x ``jax.experimental.shard_map`` module. Replication
-    checking is disabled either way — the sharded kernels here
-    return per-shard values joined by explicit collectives."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def mesh_axis_sizes(mesh) -> tuple:
     """(data, rules) axis sizes of a mesh built by make_mesh."""
     return (mesh.shape[DATA_AXIS], mesh.shape[RULES_AXIS])

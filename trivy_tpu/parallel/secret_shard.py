@@ -53,7 +53,7 @@ import numpy as np
 
 from ..ops.keywords import CODE_CHUNK, code_blockmask_impl
 from .mesh import (DATA_AXIS, RULES_AXIS, mesh_axis_sizes,
-                   pad_to_multiple, shard_map_compat)
+                   pad_to_multiple)
 
 
 class ShardedSieve:
@@ -206,12 +206,13 @@ def _build_blockmask(mesh, L: int):
         masks = code_blockmask_impl(segments, lo_c, hi_c, lo_m, hi_m)
         return jax.lax.all_gather(masks, RULES_AXIS, axis=1, tiled=True)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(RULES_AXIS), P(RULES_AXIS),
                   P(RULES_AXIS), P(RULES_AXIS)),
         out_specs=P(DATA_AXIS, None),
+        check_vma=False,
     )
     return jax.jit(fn)
 

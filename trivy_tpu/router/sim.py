@@ -561,7 +561,8 @@ class SimReplica:
 
     def build_info(self) -> dict:
         return {"version": "sim", "jax_version": "",
-                "backend": "sim", "sched": "sim"}
+                "platform": "sim", "device_kind": "sim",
+                "devices": 0, "sched": "sim"}
 
     def metrics_text(self) -> str:
         """Minimal but valid 0.0.4 exposition — enough families for

@@ -372,14 +372,10 @@ class ScanServer:
 
     def build_info(self) -> dict:
         """The trivy_tpu_build_info identity labels (also mirrored
-        into the /healthz JSON so probes see versions token-free)."""
+        into the /healthz JSON so probes see versions and the real
+        device token-free)."""
         from ..sched.metrics import build_info
-        backend = ""
-        if self.scheduler is not None:
-            cfg = getattr(self.scheduler, "config", None)
-            backend = str(getattr(cfg, "backend", "") or "")
         return build_info(
-            backend=backend,
             sched="on" if self.scheduler is not None else "off")
 
     def health(self) -> dict:
@@ -1276,11 +1272,15 @@ def serve(addr: str = "127.0.0.1", port: int = 4954,
 def serve_forever(addr: str, port: int, server: ScanServer,
                   db_watch_prefix: str = "",
                   db_watch_interval_s: float = 60.0,
-                  drain_timeout_s: float = 30.0) -> None:
+                  drain_timeout_s: float = 30.0) -> int:
     """Foreground serve with graceful SIGTERM handling: on signal,
     new Scan RPCs answer 503 while queued and in-flight requests run
     to completion (bounded by ``drain_timeout_s``), then the process
-    exits — a rolling restart never drops accepted work."""
+    exits — a rolling restart never drops accepted work. Returns the
+    process exit code: 0 after a signalled drain, 1 when the
+    scheduler latched a program fault (a kernel that does not
+    compile) — such a server drains and exits instead of answering
+    every scan with a 500."""
     import signal
 
     httpd, worker = serve(addr, port, server, db_watch_prefix,
@@ -1295,9 +1295,14 @@ def serve_forever(addr: str, port: int, server: ScanServer,
         signal.signal(signal.SIGTERM, _term)
     except ValueError:
         pass                    # not the main thread (tests)
+    rc = 0
     try:
         while not stop.wait(1.0):
-            pass
+            fault = getattr(server.scheduler, "program_fault", None)
+            if fault is not None:
+                log.error("program fault, shutting down: %s", fault)
+                rc = 1
+                break
     except KeyboardInterrupt:
         pass
     finally:
@@ -1307,3 +1312,4 @@ def serve_forever(addr: str, port: int, server: ScanServer,
         # server still delivers in-flight responses, THEN stop it
         server.shutdown_gracefully(drain_timeout_s)
         httpd.shutdown()
+    return rc

@@ -6,11 +6,13 @@ a scale-up pays that right in the middle of the SLO burn that
 triggered it. This module makes the compile spike a boot cost, and a
 cheap one:
 
-* :func:`enable_persistent_cache` points jax's persistent
-  compilation cache at an on-disk directory, so an executable
-  compiled by ANY earlier boot of the same (jax version, backend)
-  is deserialized instead of rebuilt — measured 0.34 s → 0.11 s per
-  shape on the CPU sim.
+* :func:`configure_compile_cache` turns on jax's persistent
+  compilation cache for the process — where
+  ``JAX_COMPILATION_CACHE_DIR`` says, else at one fixed path inside
+  the checkout — so an executable compiled by ANY earlier process
+  of the same (jax version, backend) is deserialized instead of
+  rebuilt. Every entry point calls it through
+  ``runtime.device.resolve_device``.
 * :func:`precompile_interval_shapes` / :func:`precompile_dfa_shapes`
   walk the SAME shape ladders the serving path buckets into
   (``ops/keywords._bucket`` for segment buffers,
@@ -61,13 +63,19 @@ class CompileCacheMetrics:
     directory (the persistent cache is shared state on disk, not an
     in-process accumulator)."""
 
-    _KEYS = ("hits", "misses", "precompiled")
+    # hits/misses/precompiled: the AOT manifest split (boot
+    # precompile). persistent_requests/persistent_hits: jax's own
+    # compile requests that consulted the persistent cache and those
+    # it answered — their difference is this process's fresh compiles
+    _KEYS = ("hits", "misses", "precompiled",
+             "persistent_requests", "persistent_hits")
 
     def __init__(self):
         self._lock = threading.Lock()
         self._c = {k: 0 for k in self._KEYS}
         self._dir = ""
         self._seconds = 0.0
+        self._jax_compile_s = 0.0
 
     def inc(self, name: str, n: int = 1) -> None:
         with self._lock:
@@ -77,9 +85,16 @@ class CompileCacheMetrics:
         with self._lock:
             self._seconds += max(0.0, seconds)
 
-    def set_dir(self, path: str) -> None:
+    def add_jax_compile_seconds(self, seconds: float) -> None:
         with self._lock:
+            self._jax_compile_s += max(0.0, seconds)
+
+    def set_dir(self, path: str) -> bool:
+        """True when ``path`` differs from the directory booked."""
+        with self._lock:
+            changed = self._dir != path
             self._dir = path
+        return changed
 
     def reset(self) -> None:
         """Test hook — production code never calls this."""
@@ -88,12 +103,14 @@ class CompileCacheMetrics:
                 self._c[k] = 0
             self._dir = ""
             self._seconds = 0.0
+            self._jax_compile_s = 0.0
 
     def snapshot(self) -> dict:
         with self._lock:
             out = dict(self._c)
             out["dir"] = self._dir
             out["seconds"] = round(self._seconds, 6)
+            out["jax_compile_s"] = round(self._jax_compile_s, 6)
         out["bytes"] = _dir_bytes(out["dir"])
         return out
 
@@ -115,28 +132,62 @@ def _dir_bytes(path: str) -> int:
     return total
 
 
-def enable_persistent_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``
-    (created if missing), with the thresholds dropped so every
-    kernel here qualifies. Returns False — and leaves the process on
-    in-memory compilation only — if this jax build lacks the cache
-    knobs; AOT warm-calling still works without it."""
-    if not cache_dir:
-        return False
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+# the one in-checkout location (git-ignored). The path is part of
+# the cache's key on disk, so it must not move between processes:
+# never a temp name, a pid or a timestamp.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+_JAX_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache":
+        "persistent_requests",
+    "/jax/compilation_cache/cache_hits": "persistent_hits",
+}
+_listening = False
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    name = _JAX_EVENTS.get(event)
+    if name is not None:
+        COMPILE_CACHE_METRICS.inc(name)
+
+
+def _on_jax_duration(event: str, seconds: float, **_kw) -> None:
+    # backend compile wall: a fresh compile, or the cache read that
+    # replaced it
+    if event == "/jax/core/compile/backend_compile_duration":
+        COMPILE_CACHE_METRICS.add_jax_compile_seconds(seconds)
+
+
+def configure_compile_cache(preferred: str = "") -> str:
+    """Turn on jax's persistent compilation cache for this process
+    and return its directory. ``JAX_COMPILATION_CACHE_DIR`` wins and
+    is left to jax (no directory is set in code); otherwise
+    ``preferred`` (the server's ``--compile-cache DIR``), otherwise
+    :data:`DEFAULT_CACHE_DIR`. The size and compile-time thresholds
+    are dropped so the small interval kernels qualify too. Raises
+    ``OSError`` when the directory cannot be created."""
+    global _listening
     import jax
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
+    env_dir = os.environ.get(ENV_CACHE_DIR, "")
+    cache_dir = env_dir or preferred or DEFAULT_CACHE_DIR
+    os.makedirs(cache_dir, exist_ok=True)
+    if not env_dir:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 0.0)
-    except (AttributeError, ValueError, OSError) as e:
-        log.warning("persistent compile cache unavailable: %r", e)
-        return False
-    COMPILE_CACHE_METRICS.set_dir(cache_dir)
-    log.info("persistent compile cache at %s", cache_dir)
-    return True
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      0.0)
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_event_listener(_on_jax_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+    if COMPILE_CACHE_METRICS.set_dir(cache_dir):
+        log.info("persistent compile cache at %s", cache_dir)
+    return cache_dir
 
 
 def cache_key(kind: str, shape_sig: str, table_hash: str = "") -> str:
@@ -277,28 +328,31 @@ def boot_precompile(cache_dir: str = "",
                     pair_buckets: Optional[Tuple[int, ...]] = None,
                     seg_buckets: Optional[Tuple[int, ...]] = None,
                     ) -> dict:
-    """The boot-time glue the server/CLI calls once: enable the
-    persistent cache, then warm the interval and (when a table is
-    supplied) DFA ladders. Never raises — a broken cache dir costs
-    compile time, not the boot."""
+    """The boot-time glue the server calls once (``--compile-cache``):
+    place the persistent cache, then warm the interval and (when a
+    table is supplied) DFA ladders, the manifest beside the cache
+    entries. A cache directory that cannot be written costs compile
+    time, not the boot; a kernel that does not compile is a program
+    fault and propagates (``ops.program.DeviceProgramError``)."""
     t0 = time.monotonic()
-    persistent = enable_persistent_cache(cache_dir)
-    summary = {"cache_dir": cache_dir, "persistent": persistent,
+    summary = {"cache_dir": cache_dir, "persistent": False,
                "kernels": []}
     try:
-        summary["kernels"].append(precompile_interval_shapes(
-            pair_buckets or DEFAULT_PAIR_BUCKETS, cache_dir))
-        if dfa_table is not None:
-            summary["kernels"].append(precompile_dfa_shapes(
-                dfa_table, run_specs,
-                seg_buckets or DEFAULT_SEG_BUCKETS, cache_dir))
-    except (RuntimeError, OSError, ValueError) as e:
-        # AOT warmth is an optimization: a failed precompile means
-        # the first request pays the compile, like before this PR
-        log.warning("boot precompile degraded: %r", e)
+        cache_dir = summary["cache_dir"] = \
+            configure_compile_cache(cache_dir)
+        summary["persistent"] = True
+    except OSError as e:
+        log.warning("persistent compile cache unavailable: %r", e)
         summary["error"] = repr(e)
+        cache_dir = ""
+    summary["kernels"].append(precompile_interval_shapes(
+        pair_buckets or DEFAULT_PAIR_BUCKETS, cache_dir))
+    if dfa_table is not None:
+        summary["kernels"].append(precompile_dfa_shapes(
+            dfa_table, run_specs,
+            seg_buckets or DEFAULT_SEG_BUCKETS, cache_dir))
     summary["seconds"] = round(time.monotonic() - t0, 4)
     log.info("boot precompile: %d kernels in %.2fs "
              "(persistent=%s)", len(summary["kernels"]),
-             summary["seconds"], persistent)
+             summary["seconds"], summary["persistent"])
     return summary

@@ -1017,9 +1017,14 @@ def _failed_slot(name: str, err: BaseException, trace_id: str = "",
     import tarfile as _tarfile
 
     from ..guard.budget import GuardError
+    from ..ops.program import DeviceProgramError
     from ..sched import (DeadlineExceeded, QueueFullError,
                          SchedulerClosed)
-    if isinstance(err, GuardError):
+    if isinstance(err, DeviceProgramError):
+        # a kernel that does not compile: the package's fault, not
+        # the image's — the CLI exits non-zero on it
+        stage, kind = "device", "program_fault"
+    elif isinstance(err, GuardError):
         # ingest-guard trip (docs/robustness.md): resource-budget
         # (bombs, floods, deadlines) or malformed-archive
         # (traversal, truncation, undecodable names)

@@ -23,13 +23,18 @@ import time
 from bisect import bisect_left
 
 
-def build_info(backend: str = "", sched: str = "") -> dict:
+def build_info(sched: str = "") -> dict:
     """The ``trivy_tpu_build_info`` identity labels (value-1 info
     gauge on /metrics, mirrored into the /healthz JSON): enough for
-    a fleet scrape to tell replica versions apart mid-rolling-
-    deploy. jax is resolved lazily and tolerated missing — metrics
-    must render on a box with no accelerator stack at all."""
+    a fleet scrape to tell replica versions — and the device each
+    replica really runs on — apart mid-rolling-deploy. The device
+    labels are what ``runtime.device.resolve_device`` found, empty
+    in a process that never resolved one; rendering them never
+    initialises a backend. jax is resolved lazily and tolerated
+    missing — metrics must render on a box with no accelerator
+    stack at all."""
     from .. import __version__
+    from ..runtime.device import device_identity
     try:
         import jax
         jax_version = getattr(jax, "__version__", "")
@@ -37,7 +42,7 @@ def build_info(backend: str = "", sched: str = "") -> dict:
         jax_version = ""
     return {"version": __version__,
             "jax_version": jax_version,
-            "backend": str(backend or ""),
+            **device_identity(),
             "sched": str(sched or "")}
 
 

@@ -33,7 +33,11 @@ when nothing is queued, analyzing, or pending coalesce, the
 effective depth shrinks to 1 — an interactive admission verdict
 never waits behind a speculative batch. A slot whose launch or
 collect fails falls back to the synchronous bisect/quarantine
-ladder, so poison isolation is unchanged.
+ladder, so poison isolation is unchanged. One failure never enters
+the ladder: a device program that does not lower or compile
+(``ops.program.DeviceProgramError``) is a fault of this package, not
+of a request — it fails the whole batch and latches
+``program_fault`` so the process can exit non-zero.
 
 Cross-request consistency: two concurrent requests can share a layer
 blob (fleets share file trees). A request that analyzed a layer will
@@ -53,6 +57,7 @@ from typing import Optional
 
 from ..obs.cost import COST_LEDGER, parse_budget_config
 from ..obs.trace import get_tracer, trace_cause
+from ..ops.program import DeviceProgramError
 from ..utils import get_logger
 from .coalescer import Batch, Coalescer, SchedConfig
 from .metrics import SchedMetrics
@@ -147,6 +152,9 @@ class ScanScheduler:
         self._running = False
         self._draining = False
         self._batch_seq = 0       # device-thread only (batch ids)
+        # first DeviceProgramError seen (a kernel that does not
+        # compile); the CLI and serve_forever exit non-zero on it
+        self.program_fault: Optional[DeviceProgramError] = None
         self._lock = threading.Lock()
         # blob id → patch event of the request that will write it
         self._blob_lock = threading.Lock()
@@ -609,15 +617,15 @@ class ScanScheduler:
                 for r in reqs:
                     self._fail(r,
                                SchedulerClosed("scheduler closed"))
+        except DeviceProgramError as e:
+            self._program_fault(reqs, e)
         except Exception as e:       # noqa: BLE001 — a failed
             # launch (fault injection fires at dispatch, packing
             # errors) falls back to the synchronous isolated ladder:
             # bisect corners the poison exactly as before
             log.warning("async launch failed for %d requests "
                         "(%r); synchronous fallback", len(reqs), e)
-            results = self._dispatch_isolated(reqs, group,
-                                              batch_id=bid)
-            self._resolve_batch(reqs, results)
+            self._sync_fallback(reqs, group, bid)
 
     def _launch(self, reqs: list, group: str, bid: int) -> dict:
         """Non-blocking half of one batch dispatch: flatten + tag
@@ -782,13 +790,16 @@ class ScanScheduler:
                         found_by.setdefault(
                             slot["owner"][idx], []).append(
                             (slot["local"][idx], secret))
+        except DeviceProgramError as e:
+            # the >CAP overflow fetch compiles its program here
+            self._unwind_slot(slot, error=e)
+            self._program_fault(reqs, e)
+            return
         except Exception as e:       # noqa: BLE001
             log.warning("slot collect failed for %d requests "
                         "(%r); synchronous fallback", len(reqs), e)
             self._unwind_slot(slot, error=e)
-            results = self._dispatch_isolated(
-                reqs, slot["group"], batch_id=slot["bid"])
-            self._resolve_safe(reqs, results)
+            self._sync_fallback(reqs, slot["group"], slot["bid"])
             return
         for job, orig in slot["wrapped"]:
             job.payload = orig
@@ -805,6 +816,33 @@ class ScanScheduler:
                            detected_by.get(i, []))
                    for i, r in enumerate(reqs)}
         self._resolve_safe(reqs, results)
+
+    def _sync_fallback(self, reqs: list, group: str,
+                       bid: int) -> None:
+        """The synchronous isolated ladder for a batch whose async
+        slot failed at run time. A program fault met on the way
+        still fails the whole batch."""
+        try:
+            results = self._dispatch_isolated(reqs, group,
+                                              batch_id=bid)
+        except DeviceProgramError as e:
+            self._program_fault(reqs, e)
+            return
+        self._resolve_safe(reqs, results)
+
+    def _program_fault(self, reqs: list,
+                       err: DeviceProgramError) -> None:
+        """A device program did not lower or compile: fail every
+        request of the batch with it — no bisect, no quarantine, no
+        host fallback (a complete all-host scan would hide that the
+        kernel does not run) — and latch the fault."""
+        log.error("device program fault, failing %d requests: %s",
+                  len(reqs), err)
+        self.metrics.inc("program_faults")
+        if self.program_fault is None:
+            self.program_fault = err
+        for r in reqs:
+            self._fail(r, err)
 
     def _resolve_safe(self, reqs: list, results: dict) -> None:
         """_resolve_batch, but a raising resolution can never leak a
@@ -969,6 +1007,8 @@ class ScanScheduler:
         try:
             return self._dispatch(reqs, group, depth=depth,
                                   batch_id=batch_id)
+        except DeviceProgramError:
+            raise
         except Exception as e:       # noqa: BLE001
             if len(reqs) == 1:
                 return self._quarantine(reqs[0], group, e,
@@ -999,6 +1039,8 @@ class ScanScheduler:
                 return self._dispatch([req], group, depth=depth,
                                       batch_id=batch_id,
                                       attempt="quarantine_retry")
+            except DeviceProgramError:
+                raise
             except Exception as e:   # noqa: BLE001
                 err = e
         self.metrics.inc("quarantined")

@@ -27,6 +27,9 @@ class SecretMetrics:
         # regex ran
         "rules_verified", "rules_windowed", "rules_wholefile",
         "rules_chain_gated",
+        # file bytes whose sieve ran on the device (fused or
+        # sharded dispatch) — cpu-ref batches add nothing here
+        "device_bytes",
         # wall-time accumulators (seconds, float)
         "sieve_s", "verify_s",
         # DFA table residency (ops/dfa.py DfaTable hooks)
@@ -64,6 +67,8 @@ class SecretMetrics:
             c["rules_wholefile"] += stats.get("rules_wholefile", 0)
             c["rules_chain_gated"] += stats.get(
                 "rules_chain_gated", 0)
+            if stats.get("mode") in ("fused", "sharded"):
+                c["device_bytes"] += stats.get("bytes_total", 0)
             c["sieve_s"] += stats.get("sieve_s", 0.0)
             c["verify_s"] += stats.get("verify_s", 0.0)
 
