@@ -277,7 +277,8 @@ class ImageArtifact:
             # staging), so the layer_analyze stage span deliberately
             # starts AFTER the open and covers only walk + analyzers
             with layer.open() as tf:
-                with phase_span("layer_analyze", layer=i):
+                with phase_span("layer_analyze", pipeline="ingest",
+                                layer=i):
                     files, opq_dirs, wh_files = collect_layer_tar(
                         tf, budget=self.budget)
                     for path, size, read in files:

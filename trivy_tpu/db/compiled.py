@@ -227,6 +227,7 @@ class ResidentTables:
     the ``_note_*`` hooks."""
 
     _UPLOAD_SPAN = "db_upload"
+    _PIPELINE = "detect"       # whose phase rows the upload joins
     _TABLE = "advisory_db"      # /metrics residency label
 
     def _init_resident(self) -> None:
@@ -268,7 +269,9 @@ class ResidentTables:
             if placed is None:
                 arrs = self._resident_arrays()
                 nbytes = int(sum(a.nbytes for a in arrs))
-                with phase_span(self._UPLOAD_SPAN, bytes=nbytes,
+                with phase_span(self._UPLOAD_SPAN,
+                                pipeline=self._PIPELINE,
+                                bytes=nbytes,
                                 generation=self.generation,
                                 **self._span_attrs()):
                     if placement is None:

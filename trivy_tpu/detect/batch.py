@@ -114,7 +114,16 @@ class _RankSpace:
 # for the host/device split in bench + tracing. Callers that
 # dispatch from several threads (the sched device executor) pass
 # their own ``stats`` sink instead of sharing this module global.
-last_dispatch_stats: dict = {"device_s": 0.0}
+last_dispatch_stats: dict = {"device_s": 0.0, "dispatch_s": 0.0}
+
+
+def _book(sink: dict, span, *keys: str) -> None:
+    """Add a finished phase's seconds to a per-call stats sink:
+    ``device_s`` (upload + kernel wall, what the cost ledger bills)
+    and ``dispatch_s`` (every interval phase the calling thread sat
+    in) are sums of span durations, never a second clock."""
+    for key in keys:
+        sink[key] = sink.get(key, 0.0) + span.duration_s
 
 
 def _job_bucket(n: int) -> int:
@@ -237,40 +246,55 @@ def detect_pairs(jobs: list, backend: str = "tpu",
 
     hit_jobs: list = []          # original job indices that hit
     if rows:
-        with phase_span("pack", jobs=len(jobs), unique=len(reps)):
+        with phase_span("pack", pipeline="detect", jobs=len(jobs),
+                        unique=len(reps)) as psp:
             for sp in spaces.values():
                 sp.finalize()
             P = len(rows)
             Pp = P if backend == "cpu-ref" else _job_bucket(P)
             (pkg_rank, v_lo, v_hi, s_lo, s_hi,
              flags_arr) = _pack_classic(rows, spaces, Pp)
-        import time as _time
-        t0 = _time.perf_counter()
+        _book(sink, psp, "dispatch_s")
         # device_compute brackets the kernel execution alone — it is
         # what the idle-attribution timeline (obs/timeline.py) counts
         # as the device being busy; the H2D upload keeps its own
-        # disjoint h2d_upload span (inside _device_hits) so upload
+        # disjoint h2d_upload span, so upload
         # wall attributes as upload_serialized, never as compute
         if backend == "cpu-ref":
-            with phase_span("device_compute", kind="interval",
-                            rows=P):
+            with phase_span("device_compute", pipeline="detect",
+                            kind="interval", rows=P) as csp:
                 hits = np.asarray(interval_hits_host(
                     pkg_rank, v_lo, v_hi, s_lo, s_hi, flags_arr))
         elif mesh is not None:
             from ..parallel.interval_shard import \
                 sharded_interval_hits
-            with phase_span("device_compute", kind="interval",
-                            rows=P):
+            with phase_span("device_compute", pipeline="detect",
+                            kind="interval", rows=P) as csp:
                 hits = sharded_interval_hits(
                     mesh, pkg_rank, v_lo, v_hi, s_lo, s_hi,
                     flags_arr)
         else:
-            hits = np.asarray(_device_hits(
-                pkg_rank, v_lo, v_hi, s_lo, s_hi, flags_arr))
+            import jax
+            from ..ops.intervals import interval_hits_donated
+            arrs = (pkg_rank, v_lo, v_hi, s_lo, s_hi, flags_arr)
+            with phase_span("h2d_upload", pipeline="detect",
+                            bytes=int(sum(a.nbytes for a in arrs))) \
+                    as usp:
+                dev = [jax.device_put(a) for a in arrs]
+            _book(sink, usp, "device_s", "dispatch_s")
+            with phase_span("device_compute", pipeline="detect",
+                            kind="interval", rows=P) as csp:
+                # materialize INSIDE the span: interval_hits is
+                # jitted (async dispatch), so closing the span after
+                # the enqueue would leave the real kernel wall to
+                # dispatch_gap. Every operand is a fresh
+                # per-dispatch upload, so the donated variant lets
+                # the kernel reuse the payload HBM (buffer-donation
+                # audit, docs/performance.md §8)
+                hits = np.asarray(interval_hits_donated(*dev))
+        _book(sink, csp, "device_s", "dispatch_s")
         if backend != "cpu-ref":
             DETECT_METRICS.note_wave(P)
-        sink["device_s"] = sink.get("device_s", 0.0) + \
-            _time.perf_counter() - t0
         for i in np.nonzero(hits[:P])[0]:
             hit_jobs.extend(members[rows[i][0]])
 
@@ -284,25 +308,6 @@ def detect_pairs(jobs: list, backend: str = "tpu",
             host_hits.extend(members[gi])
     out.extend(jobs[i].payload for i in sorted(host_hits))
     return out
-
-
-def _device_hits(*arrs):
-    import jax
-    from ..obs.trace import phase_span
-    from ..ops.intervals import interval_hits_donated
-    with phase_span("h2d_upload",
-                    bytes=int(sum(a.nbytes for a in arrs))):
-        dev = [jax.device_put(a) for a in arrs]
-    with phase_span("device_compute", kind="interval",
-                    rows=int(arrs[0].shape[0])):
-        # materialize INSIDE the span: interval_hits is jitted
-        # (async dispatch), so returning the lazy array would close
-        # the span after enqueue microseconds and the timeline would
-        # misattribute the real kernel wall to dispatch_gap.
-        # Every operand is a fresh per-dispatch upload, so the
-        # donated variant lets the kernel reuse the payload HBM
-        # (buffer-donation audit, docs/performance.md §8)
-        return np.asarray(interval_hits_donated(*dev))
 
 
 class _HostFallback(Exception):
@@ -460,26 +465,27 @@ def detect_pairs_resident(jobs: list, backend: str = "tpu",
             out.extend(detect_pairs_resident(
                 js, backend=backend, mesh=mesh, stats=stats))
         return out
-    with phase_span("pack", jobs=len(jobs)) as psp:
+    hit_jobs: list = []
+    with phase_span("pack", pipeline="detect", jobs=len(jobs)) \
+            as psp:
         reps, members, kept, ranks, rows, host = \
             _prep_resident(jobs, cdb, sink)
         psp.set("unique", len(reps))
+        if kept:
+            P = len(kept)
+            Pp = P if backend == "cpu-ref" else _job_bucket(P)
+            pkg_rank = np.zeros(Pp, np.int32)
+            row_idx = np.zeros(Pp, np.int32)
+            pkg_rank[:P] = ranks
+            row_idx[:P] = rows
+    _book(sink, psp, "dispatch_s")
 
-    hit_jobs: list = []
     if kept:
-        import time as _time
-        P = len(kept)
-        Pp = P if backend == "cpu-ref" else _job_bucket(P)
-        pkg_rank = np.zeros(Pp, np.int32)
-        row_idx = np.zeros(Pp, np.int32)
-        pkg_rank[:P] = ranks
-        row_idx[:P] = rows
-        t0 = _time.perf_counter()
         # device_compute = kernel execution only (obs/timeline.py
         # busy set); table staging keeps its db_upload span
         if backend == "cpu-ref":
-            with phase_span("device_compute", kind="interval",
-                            rows=P):
+            with phase_span("device_compute", pipeline="detect",
+                            kind="interval", rows=P) as csp:
                 hits = interval_hits_host(
                     pkg_rank, cdb.v_lo[row_idx], cdb.v_hi[row_idx],
                     cdb.s_lo[row_idx], cdb.s_hi[row_idx],
@@ -488,8 +494,8 @@ def detect_pairs_resident(jobs: list, backend: str = "tpu",
             from ..parallel.interval_shard import \
                 sharded_interval_hits_resident
             tables = cdb.device_tables(mesh=mesh)
-            with phase_span("device_compute", kind="interval",
-                            rows=P):
+            with phase_span("device_compute", pipeline="detect",
+                            kind="interval", rows=P) as csp:
                 hits = sharded_interval_hits_resident(
                     mesh, pkg_rank, row_idx, tables)
         else:
@@ -497,22 +503,22 @@ def detect_pairs_resident(jobs: list, backend: str = "tpu",
             from ..ops.intervals import \
                 interval_hits_resident_donated
             tables = cdb.device_tables()
-            with phase_span("h2d_upload",
+            with phase_span("h2d_upload", pipeline="detect",
                             bytes=int(pkg_rank.nbytes +
-                                      row_idx.nbytes)):
+                                      row_idx.nbytes)) as usp:
                 dr = jax.device_put(pkg_rank)
                 di = jax.device_put(row_idx)
-            with phase_span("device_compute", kind="interval",
-                            rows=P):
+            _book(sink, usp, "device_s", "dispatch_s")
+            with phase_span("device_compute", pipeline="detect",
+                            kind="interval", rows=P) as csp:
                 # dr/di are fresh per-dispatch uploads → donated;
                 # the resident tables are shared across every
                 # dispatch of this generation → never donated
                 hits = np.asarray(interval_hits_resident_donated(
                     dr, di, *tables))
+        _book(sink, csp, "device_s", "dispatch_s")
         if backend != "cpu-ref":
             DETECT_METRICS.note_wave(P)
-        sink["device_s"] = sink.get("device_s", 0.0) + \
-            _time.perf_counter() - t0
         for i in np.nonzero(hits[:P])[0]:
             hit_jobs.extend(members[kept[i]])
     out = [jobs[i].payload for i in sorted(hit_jobs)]
@@ -538,6 +544,7 @@ def dispatch_jobs(jobs: list, backend: str = "tpu",
     shared module global — pass one per thread."""
     sink = stats if stats is not None else last_dispatch_stats
     sink["device_s"] = 0.0
+    sink["dispatch_s"] = 0.0
     sink["jobs_in"] = 0
     sink["jobs_unique"] = 0
     plain = [j for j in jobs if isinstance(j, PairJob)]
@@ -626,12 +633,11 @@ class _WaveSegment:
         self.waves.append(wave)
 
     def _collect_wave(self, wave: dict):
-        import time as _time
         from ..obs.trace import phase_span
-        t0 = _time.perf_counter()
         with _activate_ctx(self.ctx_span):
-            with phase_span("device_compute", kind="interval",
-                            rows=wave["rows"]):
+            with phase_span("device_compute", pipeline="detect",
+                            kind="interval",
+                            rows=wave["rows"]) as sp:
                 # materializing blocks until the enqueued kernel
                 # finished — on the drain thread this runs
                 # concurrently with the next wave's pack/upload,
@@ -639,15 +645,23 @@ class _WaveSegment:
                 hits = np.asarray(wave["lazy"])
         wave["hits"] = hits
         wave["lazy"] = None          # free the donated output early
-        self.sink["device_s"] = self.sink.get("device_s", 0.0) + \
-            _time.perf_counter() - t0
+        _book(self.sink, sp, "device_s")
+        if self.ring is None:
+            # collected inline: the caller's own wall (a ring's
+            # drain thread overlaps the caller, whose wait is the
+            # wave_wait phase in _kernel_hits)
+            _book(self.sink, sp, "dispatch_s")
 
     def _kernel_hits(self) -> list:
+        from ..obs.trace import phase_span
         hit_jobs: list = []
         for wave in self.waves:
             slot = wave.get("slot")
             if slot is not None:
-                slot.wait()
+                with phase_span("wave_wait", pipeline="detect",
+                                rows=wave["rows"]) as sp:
+                    slot.wait()
+                _book(self.sink, sp, "dispatch_s")
             elif "hits" not in wave:
                 self._collect_wave(wave)
             for i in np.nonzero(wave["hits"][:wave["rows"]])[0]:
@@ -668,12 +682,14 @@ class _ClassicSegment(_WaveSegment):
         super().__init__(jobs, sink, ring)
         import jax
         from ..obs.trace import phase_span
-        with phase_span("pack", jobs=len(jobs)) as psp:
+        with phase_span("pack", pipeline="detect",
+                        jobs=len(jobs)) as psp:
             (self.reps, self.members, spaces, rows,
              self.host_groups) = _prep_classic(jobs, sink)
             for sp in spaces.values():
                 sp.finalize()
             psp.set("unique", len(self.reps))
+        _book(sink, psp, "dispatch_s")
         if not rows:
             return
         w = max(1, int(max_wave_rows))
@@ -682,7 +698,8 @@ class _ClassicSegment(_WaveSegment):
         def _pack(sl):
             Pp = _job_bucket(len(sl))
             with _activate_ctx(self.ctx_span):
-                with phase_span("pack", rows=len(sl)):
+                with phase_span("pack", pipeline="detect",
+                                rows=len(sl)):
                     return _pack_classic(sl, spaces, Pp)
 
         # pool-parallel wave packing: the fancy-index fills of every
@@ -711,9 +728,12 @@ class _ClassicSegment(_WaveSegment):
                 else:
                     from ..ops.intervals import \
                         interval_hits_donated
-                    with phase_span("h2d_upload", bytes=int(
-                            sum(a.nbytes for a in arrays))):
+                    with phase_span("h2d_upload",
+                                    pipeline="detect", bytes=int(
+                                        sum(a.nbytes
+                                            for a in arrays))) as usp:
                         dev = [jax.device_put(a) for a in arrays]
+                    _book(sink, usp, "dispatch_s")
                     # dev buffers are this wave's alone → donated;
                     # the kernel reuses the slot HBM for its output
                     lazy = interval_hits_donated(*dev)
@@ -738,10 +758,12 @@ class _ResidentSegment(_WaveSegment):
         import jax
         from ..obs.trace import phase_span
         self.cdb = cdb
-        with phase_span("pack", jobs=len(jobs)) as psp:
+        with phase_span("pack", pipeline="detect",
+                        jobs=len(jobs)) as psp:
             (self.reps, self.members, kept, ranks, rows,
              self.host_groups) = _prep_resident(jobs, cdb, sink)
             psp.set("unique", len(self.reps))
+        _book(sink, psp, "dispatch_s")
         if not kept:
             return
         w = max(1, int(max_wave_rows))
@@ -765,10 +787,13 @@ class _ResidentSegment(_WaveSegment):
                 else:
                     from ..ops.intervals import \
                         interval_hits_resident_donated
-                    with phase_span("h2d_upload", bytes=int(
-                            pkg_rank.nbytes + row_idx.nbytes)):
+                    with phase_span("h2d_upload",
+                                    pipeline="detect", bytes=int(
+                                        pkg_rank.nbytes
+                                        + row_idx.nbytes)) as usp:
                         dr = jax.device_put(pkg_rank)
                         di = jax.device_put(row_idx)
+                    _book(sink, usp, "dispatch_s")
                     # gather operands donated; the resident
                     # advisory tables are shared state and NEVER
                     # donated
@@ -823,6 +848,7 @@ def dispatch_jobs_async(jobs: list, backend: str = "tpu",
     any wave size, ring depth, and device count."""
     sink = stats if stats is not None else last_dispatch_stats
     sink["device_s"] = 0.0
+    sink["dispatch_s"] = 0.0
     sink["jobs_in"] = 0
     sink["jobs_unique"] = 0
     handle = IntervalDispatch(sink)

@@ -82,6 +82,11 @@ class DetectMetrics:
         out["upload_amortization"] = round(
             out["resident_dispatches"] / out["db_uploads"], 2) \
             if out["db_uploads"] else 0.0
+        # the interval and SBOM rows of the phase clock
+        # (obs/trace.phase_span: decode, decode_task, join, pack,
+        # h2d_upload, device_compute, finish, gc), cumulative
+        from ..obs.trace import phase_rows
+        out["phase"] = phase_rows("detect")
         return out
 
 

@@ -240,7 +240,8 @@ class FindingsMemo:
                 sorted(groups),
                 tenant=getattr(prepared, "tenant", ""))
         from ..obs.trace import phase_span
-        with phase_span("memo_lookup", layers=len(groups),
+        with phase_span("memo_lookup", pipeline="detect",
+                        layers=len(groups),
                         queries=len(queries)):
             for bid, qs in groups.items():
                 key = K.make_key(ctx, bid, opts)
@@ -306,7 +307,7 @@ class FindingsMemo:
                 if loc is not None:
                     hit_idx.setdefault(loc[:2], set()).add(loc[2])
             from ..obs.trace import phase_span
-            with phase_span("memo_store",
+            with phase_span("memo_store", pipeline="detect",
                             entries=len(plan.pending)):
                 for key, pend in plan.pending.items():
                     entry = pend["base"]
@@ -351,7 +352,8 @@ class FindingsMemo:
             # stop matching the new context and age out
             return out
         try:
-            with phase_span("delta_rematch") as sp:
+            with phase_span("delta_rematch",
+                            pipeline="detect") as sp:
                 out = self._hot_swap(old_db, new_db)
                 delta_stats = out.get("delta") or {}
                 sp.set("touched_keys",

@@ -260,7 +260,15 @@ class _DeviceTraceCtx:
     def _start_jax_trace(self) -> None:
         import jax
         try:
-            self._jax_trace = jax.profiler.trace(self.out_dir)
+            # jax's own Python tracer stays off: it records every
+            # Python call (tens of MB for a three-image scan) and
+            # stretches the host phases it is there to show; stacks
+            # come from the sampling profiler (host_profile.folded),
+            # phases from the trivy.* annotations (obs/trace.py)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            self._jax_trace = jax.profiler.trace(
+                self.out_dir, profiler_options=opts)
             self._jax_trace.__enter__()
         except Exception as e:      # noqa: BLE001 — whatever the
             # profiler plugin raises; classified just below

@@ -98,21 +98,45 @@ def _histograms(w: _Writer, name: str, label: str, hists: dict,
     w.header(full, "histogram", help_)
     for key in sorted(hists):
         h = hists[key]
+        # a histogram that brings its own labels (the tracer's
+        # span + pipeline) is rendered under them, else under its key
+        labels = sorted((h.get("labels") or {label: key}).items())
         bounds, counts = h["bounds"], h["counts"]
         cum = 0
         for i, (b, c) in enumerate(zip(bounds, counts)):
             cum += c
             w.sample(full + "_bucket",
-                     [(label, key), ("le", _fmt(float(b)))], cum,
+                     labels + [("le", _fmt(float(b)))], cum,
                      suffix=_exemplar_suffix(h, i)
                      if openmetrics else "")
         cum += counts[len(bounds)] if len(counts) > len(bounds) else 0
-        w.sample(full + "_bucket", [(label, key), ("le", "+Inf")],
+        w.sample(full + "_bucket", labels + [("le", "+Inf")],
                  cum,
                  suffix=_exemplar_suffix(h, len(bounds))
                  if openmetrics else "")
-        w.sample(full + "_sum", [(label, key)], float(h["sum"]))
-        w.sample(full + "_count", [(label, key)], h["count"])
+        w.sample(full + "_sum", labels, float(h["sum"]))
+        w.sample(full + "_count", labels, h["count"])
+
+
+def _phase_rows(w: _Writer, by_pipeline: dict) -> None:
+    """The phase clock's rows (obs/trace.phase_span): runs, wall
+    seconds and thread-CPU seconds by (pipeline, phase)."""
+    rows = [(pl, ph, r) for pl, phases in sorted(by_pipeline.items())
+            for ph, r in sorted((phases or {}).items())]
+    if not rows:
+        return
+    for key, name, help_ in (
+            ("n", "phase_runs_total", "Pipeline phases run."),
+            ("busy_s", "phase_busy_seconds_total",
+             "Wall seconds inside each pipeline phase."),
+            ("cpu_s", "phase_cpu_seconds_total",
+             "Thread-CPU seconds inside each pipeline phase; busy "
+             "less cpu is time the thread waited.")):
+        full = f"{_PREFIX}_{name}"
+        w.header(full, "counter", help_)
+        for pl, ph, r in rows:
+            w.sample(full, [("pipeline", pl), ("phase", ph)],
+                     r.get(key))
 
 
 def _process_gauges(w: _Writer, proc: dict) -> None:
@@ -268,7 +292,7 @@ def render_prometheus(stats: dict, phase_hists=None,
                  "hits/misses, resident-DB uploads).")
         for k in sorted(detect):
             if k.endswith(("_rate", "_ratio", "amortization")) \
-                    or k == "db_upload_bytes":
+                    or k in ("db_upload_bytes", "phase"):
                 continue     # derived gauges / byte totals below —
                 # a byte count inside an event-count family would
                 # poison any sum() over it
@@ -301,7 +325,7 @@ def render_prometheus(stats: dict, phase_hists=None,
                  "shard/decode tasks).")
         for k in sorted(secret):
             if k.endswith(("_s", "_selectivity", "amortization")) \
-                    or k == "dfa_upload_bytes":
+                    or k in ("dfa_upload_bytes", "phase"):
                 continue     # derived gauges / seconds / bytes below
             w.sample(name, [("event", k)], secret[k])
         w.scalar(f"{_PREFIX}_secret_sieve_selectivity", "gauge",
@@ -324,6 +348,10 @@ def render_prometheus(stats: dict, phase_hists=None,
                  "gauge",
                  "DFA-table dispatches served per HBM upload.",
                  secret.get("dfa_upload_amortization"))
+
+    _phase_rows(w, {"sched": stats.get("phase"),
+                    "detect": detect.get("phase"),
+                    "secret": secret.get("phase")})
 
     ingest = stats.get("ingest") or {}
     if ingest:

@@ -29,6 +29,7 @@ reach :func:`add_event` without cycles.
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import os
 import re
@@ -86,41 +87,140 @@ def add_event(name: str, **attrs) -> None:
         span.event(name, **attrs)
 
 
+# ---- the phase clock -------------------------------------------------
+#
+# One table for the whole process: every ``phase_span`` exit books a
+# row here, traced or not, so the numbers /metrics, ``--sched-stats``
+# and the benchmark read are the ones measured where the work
+# happens. Keys are code-literal call sites (pipeline, phase), a few
+# dozen in all; MAX_PHASE_NAMES still folds a runaway caller.
+
+_PHASE_LOCK = threading.Lock()
+_PHASE_ROWS: dict = {}        # (pipeline, phase) -> [n, busy_s, cpu_s]
+
+# ``factory(name)`` -> context manager that puts ``name`` on the
+# device profiler's clock (jax.profiler.TraceAnnotation), installed
+# by runtime/device.py once jax is imported; obs/ stays stdlib-only
+_ANNOTATOR = None
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def set_annotator(factory) -> None:
+    """Install (or with None remove) the profiler-timeline hook."""
+    global _ANNOTATOR
+    _ANNOTATOR = factory
+
+
+def annotation(name: str):
+    """``with annotation("trivy.compile.fused"):`` — one span on the
+    profiler's clock, or nothing when no annotator is installed."""
+    ann = _ANNOTATOR
+    return ann(name) if ann is not None else _NO_ANNOTATION
+
+
+def _book_phase(pipeline: str, phase: str, busy_s: float,
+                cpu_s: float) -> None:
+    key = (pipeline, phase)
+    with _PHASE_LOCK:
+        row = _PHASE_ROWS.get(key)
+        if row is None:
+            if len(_PHASE_ROWS) >= MAX_PHASE_NAMES:
+                key = (pipeline, "other")
+                row = _PHASE_ROWS.get(key)
+            if row is None:
+                row = _PHASE_ROWS[key] = [0, 0.0, 0.0]
+        row[0] += 1
+        row[1] += busy_s
+        row[2] += cpu_s
+
+
+def phase_table() -> dict:
+    """``{pipeline: {phase: {"n", "busy_s", "cpu_s"}}}``, cumulative
+    since process start. ``busy_s`` is wall time inside the phase,
+    ``cpu_s`` the calling thread's CPU time inside it: busy less cpu
+    is time the thread waited (for the interpreter, a lock, the
+    device)."""
+    out: dict = {}
+    with _PHASE_LOCK:
+        for (pl, ph), r in _PHASE_ROWS.items():
+            out.setdefault(pl, {})[ph] = {
+                "n": r[0], "busy_s": r[1], "cpu_s": r[2]}
+    return out
+
+
+def phase_rows(pipeline: str) -> dict:
+    """One pipeline's rows of :func:`phase_table`."""
+    return phase_table().get(pipeline, {})
+
+
 class _PhaseSpanCtx:
-    """Context manager behind :func:`phase_span`: opens a child of
-    the active span (activated, so nested phases chain), ends it on
-    exit — with status "error" when the body raised."""
+    """Context manager behind :func:`phase_span`, and the handle the
+    ``with`` binds: ``set`` forwards to the tracer span,
+    ``duration_s``/``cpu_s`` hold the phase's seconds after exit
+    (error exits too)."""
 
-    __slots__ = ("name", "attrs", "span", "_token")
+    __slots__ = ("name", "pipeline", "attrs", "span", "duration_s",
+                 "cpu_s", "_token", "_ann", "_t0", "_c0")
 
-    def __init__(self, name: str, attrs: dict):
+    def __init__(self, name: str, pipeline: str, attrs: dict):
         self.name = name
+        self.pipeline = pipeline
         self.attrs = attrs
         self.span = NOOP_SPAN
+        self.duration_s = 0.0
+        self.cpu_s = 0.0
         self._token = None
+        self._ann = None
+
+    def set(self, key: str, value) -> None:
+        self.span.set(key, value)
 
     def __enter__(self):
+        ann = _ANNOTATOR
+        if ann is not None:
+            self._ann = ann(f"trivy.{self.pipeline}.{self.name}")
+            self._ann.__enter__()
         parent = _ACTIVE.get()
         if parent is not None and not parent.noop:
-            self.span = parent.tracer.child(parent, self.name,
-                                            **self.attrs)
+            self.span = parent.tracer.child(
+                parent, self.name, pipeline=self.pipeline,
+                **self.attrs)
             self._token = _ACTIVE.set(self.span)
-        return self.span
+        # one clock: a live tracer span's own start is the phase's
+        self._t0 = time.monotonic() if self.span.noop \
+            else self.span.start_mono
+        self._c0 = time.thread_time()
+        return self
 
     def __exit__(self, exc_type, *exc):
+        cpu = time.thread_time() - self._c0
         if self._token is not None:
             _ACTIVE.reset(self._token)
         self.span.end("error" if exc_type is not None else None)
+        end = time.monotonic() if self.span.noop \
+            else self.span.end_mono
+        self.duration_s = max(0.0, end - self._t0)
+        self.cpu_s = max(0.0, min(cpu, self.duration_s))
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _book_phase(self.pipeline, self.name, self.duration_s,
+                    self.cpu_s)
 
 
-def phase_span(name: str, **attrs) -> _PhaseSpanCtx:
-    """``with phase_span("pack"):`` — bracket a pipeline phase as a
-    child of whatever span is active on this thread, or do nothing
-    when none is. This is how deep seams (segment packing, H2D
-    uploads, resident-DB staging) show up in Perfetto without
-    threading a tracer handle through every call chain
-    (docs/performance.md)."""
-    return _PhaseSpanCtx(name, attrs)
+def phase_span(name: str, *, pipeline: str, **attrs) -> _PhaseSpanCtx:
+    """``with phase_span("pack", pipeline="secret") as sp:`` — the
+    one boundary marker of a pipeline phase. It always measures: on
+    exit the phase's wall and thread-CPU seconds are booked in the
+    process-wide phase table (:func:`phase_rows`) and left on
+    ``sp.duration_s``/``sp.cpu_s`` for the per-call stats dicts; it
+    brackets ``trivy.<pipeline>.<name>`` on the device profiler's
+    timeline when an annotator is installed; and when a request
+    span is active on this thread it is also a child of it, so deep
+    seams (segment packing, H2D uploads, resident-DB staging) show
+    up in Perfetto without threading a tracer handle through every
+    call chain (docs/observability.md). Grain: a batch, a layer or
+    a pool task — never inside a per-file or per-row loop."""
+    return _PhaseSpanCtx(name, pipeline, attrs)
 
 
 def activate_or_null(span):
@@ -128,7 +228,6 @@ def activate_or_null(span):
     thread, or do nothing when there is none. The async slot
     runtime hops threads (hostpool packers, ring drain) and carries
     the launching batch's span along this way."""
-    import contextlib
     return span.activate() if span is not None \
         else contextlib.nullcontext()
 
@@ -334,7 +433,8 @@ class Tracer:
     def _finish(self, span: Span) -> None:
         if self._phase is not None and not span.is_root:
             self._observe_phase(span.name, span.duration_s,
-                                span.trace_id)
+                                span.trace_id,
+                                span.attrs.get("pipeline", ""))
         with self._lock:
             self.n_spans += 1
             if not span.is_root:
@@ -369,18 +469,22 @@ class Tracer:
         self._complete(span, spans, dirty=dirty)
 
     def _observe_phase(self, name: str, dur_s: float,
-                       trace_id: str = "") -> None:
+                       trace_id: str = "",
+                       pipeline: str = "") -> None:
         from ..sched.metrics import LatencyHistogram
+        # phase_span children carry their pipeline, so secret
+        # ``pack`` and interval ``pack`` are two histograms
+        key = (name, pipeline)
         with self._lock:
-            h = self._phase.get(name)
+            h = self._phase.get(key)
             if h is None:
                 if len(self._phase) >= MAX_PHASE_NAMES:
                     # cardinality cap: overflow names fold into one
                     # shared histogram so /metrics stays bounded
-                    name = "other"
-                    h = self._phase.get(name)
+                    key = ("other", "")
+                    h = self._phase.get(key)
                 if h is None:
-                    h = self._phase[name] = LatencyHistogram()
+                    h = self._phase[key] = LatencyHistogram()
             h.observe(dur_s, exemplar=trace_id)
 
     def _complete(self, root: Span, spans: list,
@@ -424,11 +528,20 @@ class Tracer:
         return to_chrome(spans, self.epoch_mono, self.epoch_wall)
 
     def phase_snapshot(self) -> dict:
-        """{span name: raw histogram} for Prometheus exposition
-        (with per-bucket trace-id exemplars)."""
+        """Raw histograms for Prometheus exposition (with per-bucket
+        trace-id exemplars), keyed by span name, or
+        ``<pipeline>.<name>`` for a phase_span child; each carries
+        its ``labels`` (``span``, and ``pipeline`` where it has
+        one)."""
+        out = {}
         with self._lock:
-            return {name: h.raw()
-                    for name, h in (self._phase or {}).items()}
+            for (name, pipeline), h in (self._phase or {}).items():
+                raw = h.raw()
+                raw["labels"] = {"span": name}
+                if pipeline:
+                    raw["labels"]["pipeline"] = pipeline
+                out[f"{pipeline}.{name}" if pipeline else name] = raw
+        return out
 
     def stats(self) -> dict:
         with self._lock:

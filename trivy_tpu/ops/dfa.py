@@ -262,6 +262,7 @@ class DfaTable(ResidentTables):
     code table), END positions for chains (file-level gates)."""
 
     _UPLOAD_SPAN = "dfa_upload"
+    _PIPELINE = "secret"
     _TABLE = "dfa"              # /metrics residency label
 
     def __init__(self, literals: list, chains: list):

@@ -259,6 +259,9 @@ def dfa_blockmask_pallas(segments: jax.Array, table,
         out_shape=out_shapes,
         grid_spec=grid_spec,
         interpret=interpret,
+        # the operation's name in a profiler trace, whatever the
+        # jitted program round it is called
+        name="dfa_blockmask",
     )(*padded, segments)
 
     cols = [outs[gi][:, :g.count] for gi, g in enumerate(groups)]

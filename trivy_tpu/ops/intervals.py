@@ -40,11 +40,7 @@ NEG_INF = -(2 ** 31) + 1
 POS_INF = 2 ** 31 - 1
 
 
-def interval_hits_impl(pkg_rank: jax.Array, vuln_lo: jax.Array,
-                       vuln_hi: jax.Array, sec_lo: jax.Array,
-                       sec_hi: jax.Array,
-                       flags: jax.Array) -> jax.Array:
-    """[P] ranks × [P, M] interval tables → [P] bool vulnerable."""
+def _hits(pkg_rank, vuln_lo, vuln_hi, sec_lo, sec_hi, flags):
     r = pkg_rank[:, None]
     vuln_any = ((vuln_lo <= r) & (r <= vuln_hi)).any(axis=1)
     sec_any = ((sec_lo <= r) & (r <= sec_hi)).any(axis=1)
@@ -57,6 +53,20 @@ def interval_hits_impl(pkg_rank: jax.Array, vuln_lo: jax.Array,
     with_vuln = vuln_any & not_sec
     without_vuln = jnp.where(has_sec, ~sec_any, False)
     return force | jnp.where(has_vuln, with_vuln, without_vuln)
+
+
+# Both implementations run under one named scope, so a profiler
+# trace names their operations ``…/interval_hits/…`` whatever jitted
+# program (plain, donated, shard_map) they were compiled into.
+
+def interval_hits_impl(pkg_rank: jax.Array, vuln_lo: jax.Array,
+                       vuln_hi: jax.Array, sec_lo: jax.Array,
+                       sec_hi: jax.Array,
+                       flags: jax.Array) -> jax.Array:
+    """[P] ranks × [P, M] interval tables → [P] bool vulnerable."""
+    with jax.named_scope("interval_hits"):
+        return _hits(pkg_rank, vuln_lo, vuln_hi, sec_lo, sec_hi,
+                     flags)
 
 
 interval_hits = DeviceProgram(jax.jit(interval_hits_impl),
@@ -83,9 +93,10 @@ def interval_hits_resident_impl(pkg_rank: jax.Array,
     across scans (compiled once at DB load — SURVEY §7 step 5); each
     dispatch gathers only the candidate rows. [P] pkg ranks + [P] row
     indices → [P] bool."""
-    return interval_hits_impl(pkg_rank, vuln_lo[row_idx],
-                              vuln_hi[row_idx], sec_lo[row_idx],
-                              sec_hi[row_idx], flags[row_idx])
+    with jax.named_scope("interval_hits"):
+        return _hits(pkg_rank, vuln_lo[row_idx], vuln_hi[row_idx],
+                     sec_lo[row_idx], sec_hi[row_idx],
+                     flags[row_idx])
 
 
 interval_hits_resident = DeviceProgram(

@@ -47,10 +47,12 @@ class DeviceProgram:
     def __init__(self, jitted, name: str):
         self._jitted = jitted
         self.name = name
+        self._dispatch_span = f"trivy.dispatch.{name}"
         self._compiled: dict = {}     # signature -> readable shapes
         _PROGRAMS.add(self)
 
     def __call__(self, *args):
+        from ..obs.trace import annotation
         sig = tuple((getattr(a, "shape", None),
                      str(getattr(a, "dtype", "")),
                      getattr(a, "sharding", None)) for a in args)
@@ -58,7 +60,8 @@ class DeviceProgram:
             shapes = ", ".join(f"{s[1]}{list(s[0] or ())}"
                                for s in sig)
             try:
-                self._jitted.lower(*args).compile()
+                with annotation(f"trivy.compile.{self.name}"):
+                    self._jitted.lower(*args).compile()
             except Exception as e:
                 raise DeviceProgramError(
                     f"device program {self.name!r} failed to "
@@ -67,7 +70,9 @@ class DeviceProgram:
             # a dict store is atomic under the GIL; two threads
             # racing a new signature at worst both compile
             self._compiled[sig] = shapes
-        return self._jitted(*args)
+        # the enqueue: jit returns before the device has finished
+        with annotation(self._dispatch_span):
+            return self._jitted(*args)
 
     def __getattr__(self, attr: str):
         return getattr(self._jitted, attr)

@@ -47,7 +47,6 @@ from __future__ import annotations
 
 import functools
 import threading
-import time
 
 import numpy as np
 
@@ -74,8 +73,7 @@ class ShardedSieve:
         self.metas = metas
         self.lay = scanner._layout(metas)
         self.occupancy = self.lay["occupancy"]
-        self.pack_s = 0.0
-        self.device_s = 0.0
+        self.device_s = 0.0       # the join's dfa_scan span
         self._out = None
 
     def _fill_shard(self, items: list, buf) -> None:
@@ -109,10 +107,8 @@ class ShardedSieve:
         pool = get_host_pool()
         on_pool = threading.current_thread().name.startswith(
             "trivy-hostpool")
-        # pack_s is WALL time across the parallel fills — the
-        # per-task durations overlap on the pool, and the stats this
-        # lands in are compared against other wall phases
-        t0 = time.perf_counter()
+        # the caller's pack phase brackets start(): WALL time across
+        # the parallel fills, whose per-task durations overlap
         if pool is not None and not on_pool and len(by_shard) > 1:
             fills = [pool.submit(self._fill_shard, blk, buf)
                      for blk in by_shard]
@@ -121,42 +117,41 @@ class ShardedSieve:
         else:
             for blk in by_shard:
                 self._fill_shard(blk, buf)
-        self.pack_s += time.perf_counter() - t0
 
         table = sc.table
         platform = jax.default_backend()
         fn = table.mesh_sieve(sc.mesh, tuple(sc.plan.run_specs),
                               platform)
         tbl = table.device_tables(sc.mesh)
-        t0 = time.perf_counter()
         # async: returns before the chips finish; the caller's host
         # work (squash, interval prep, the NEXT batch's packing)
         # overlaps the sieve compute
         self._out = fn(buf, *tbl)
-        self.device_s += time.perf_counter() - t0
         SECRET_METRICS.inc("shards_dispatched", len(by_shard))
         return self
 
-    def decode(self) -> tuple:
-        """Join the mesh result and decode it in parallel: returns
-        (file_codes, runs_map) merged across shard blocks —
-        ``file_codes``: file index → {pattern col: [(seg offset,
-        blockmask)]}; ``runs_map``: file index → {run-spec idx}."""
+    def fetch(self) -> tuple:
+        """Join the mesh result: ``(masks, runs)`` on the host."""
         from ..obs.trace import phase_span
-        from ..runtime.hostpool import map_in_pool
-        from ..secret.metrics import SECRET_METRICS
         K = self.scanner.table.n_patterns
-        t0 = time.perf_counter()
         # the async dispatch's device wall passes HERE — the
         # np.asarray join blocks on the mesh sieve — so this is the
         # dfa_scan busy span the idle-attribution timeline counts
         # (mirrors the fused path's dfa_scan(fetch=True))
-        with phase_span("dfa_scan", fetch=True,
-                        segments=int(self.n_valid)):
+        with phase_span("dfa_scan", pipeline="secret", fetch=True,
+                        segments=int(self.n_valid)) as sp:
             masks = np.asarray(self._out[0])[:self.n_valid, :K]
             runs = np.asarray(self._out[1])[:self.n_valid]
-        self.device_s += time.perf_counter() - t0
+        self.device_s += sp.duration_s
+        return masks, runs
 
+    def decode(self, masks, runs) -> tuple:
+        """Decode the joined result in parallel: returns
+        (file_codes, runs_map) merged across shard blocks —
+        ``file_codes``: file index → {pattern col: [(seg offset,
+        blockmask)]}; ``runs_map``: file index → {run-spec idx}."""
+        from ..runtime.hostpool import map_in_pool
+        from ..secret.metrics import SECRET_METRICS
         seg_file, seg_pos = self.seg_file, self.seg_pos
         blocks = [(r0, min(r0 + self.rps, self.n_valid))
                   for r0 in range(0, self.n_valid, self.rps)]

@@ -585,16 +585,16 @@ StreamingImageSource`: layer blobs decompress into the scan as they
             # runs — the ISSUE's fetch/join overlap. The join span
             # keeps the phase visible to idle attribution
             # (host_pack_bound).
-            t1 = _time.perf_counter()
             ref = a.reference
-            # prepare emits its own "join" phase span (scan/local.py)
             with sp.activate():
-                prepared.append(scanner.prepare(
-                    ScanTarget(name=ref.name,
-                               artifact_id=ref.id,
-                               blob_ids=ref.blob_ids),
-                    options))
-            join_s += _time.perf_counter() - t1
+                with phase_span("join", pipeline="detect",
+                                blobs=len(ref.blob_ids)) as jsp:
+                    prepared.append(scanner.join(
+                        ScanTarget(name=ref.name,
+                                   artifact_id=ref.id,
+                                   blob_ids=ref.blob_ids),
+                        options))
+            join_s += jsp.duration_s
             sp.end()
             slots.append((idx, a))
         artifacts = [a for _, a in slots]
@@ -653,11 +653,10 @@ StreamingImageSource`: layer blobs decompress into the scan as they
             dispatch_jobs_async
         from .ring import (RING_METRICS, DispatchRing, RingMetrics,
                            TeeRingMetrics)
-        t0 = _time.perf_counter()
         if sieve_future is not None:
+            t0 = _time.perf_counter()
             sieve_handle = sieve_future.result()
             secret_s += _time.perf_counter() - t0
-            t0 = _time.perf_counter()
         all_jobs = []
         for idx, p in enumerate(prepared):
             for job in p.jobs:
@@ -683,7 +682,6 @@ StreamingImageSource`: layer blobs decompress into the scan as they
                                          backend=options.backend,
                                          mesh=self.mesh,
                                          stats=kstats, ring=ring)
-            interval_s = _time.perf_counter() - t0
 
             # ---- phase 2b: sieve collect + late secret merge ----
             # overlaps the interval waves still computing/draining
@@ -695,7 +693,8 @@ StreamingImageSource`: layer blobs decompress into the scan as they
                     # verify phase spans; the blob patch + re-merge
                     # is collect-side host work too
                     found = self.secret_scanner.collect(sieve_handle)
-                    with phase_span("decode", stage="patch"):
+                    with phase_span("decode", pipeline="secret",
+                                    stage="patch"):
                         _patch_blobs(self.cache, artifacts, found)
                         sec_stats = dict(getattr(self.secret_scanner,
                                                  "stats", {}))
@@ -716,12 +715,10 @@ StreamingImageSource`: layer blobs decompress into the scan as they
             secret_s += _time.perf_counter() - t0
 
             # ---- phase 4b: collect the interval waves ----
-            t0 = _time.perf_counter()
             with activate_or_null(sp0):
                 detected_pairs = collect_dispatch(ih)
             for idx, payload in detected_pairs:
                 detected_by_image.setdefault(idx, []).append(payload)
-            interval_s += _time.perf_counter() - t0
         finally:
             if ring is not None:
                 ring.close()
@@ -737,7 +734,10 @@ StreamingImageSource`: layer blobs decompress into the scan as they
             "analyze_s": round(analyze_s, 4),
             "secret_batch_s": round(secret_s, 4),
             "squash_join_s": round(join_s, 4),
-            "interval_dispatch_s": round(interval_s, 4),
+            # the calling thread's seconds in the interval phases:
+            # pack, h2d_upload, and the wait for the ring's waves
+            "interval_dispatch_s": round(
+                kstats.get("dispatch_s", 0.0), 4),
             "interval_device_s": round(
                 kstats.get("device_s", 0.0), 4),
             "interval_jobs": len(all_jobs),
@@ -882,12 +882,17 @@ StreamingImageSource`: layer blobs decompress into the scan as they
 
     def _scan_boms_db(self, db, boms: list,
                       options: Optional[ScanOptions] = None) -> list:
-        import time as _time
-
         from ..artifact.sbom import decode_to_blob
+        from ..obs.trace import phase_span
 
         options = options or ScanOptions(
             backend=self.backend, security_checks=["vuln"])
+
+        # The phases below (decode, join, the interval pack /
+        # h2d_upload / device_compute inside dispatch_jobs, finish,
+        # and defer_gc's gc round this call) run one after the other
+        # on the calling thread, so their seconds partition a pass's
+        # wall time; the stats keys are sums of their durations.
 
         # ---- phase 1: decode + blob (host, pooled) ----
         # decode is the dominant host phase at fleet scale (BENCH_r05:
@@ -897,9 +902,10 @@ StreamingImageSource`: layer blobs decompress into the scan as they
         # dispatch overhead the visible cost in the hostpool stats —
         # and repeated purl strings short-circuit in the purl parse
         # cache (docs/performance.md). A malformed document still
-        # fails only its own slot.
+        # fails only its own slot. Each slab books a decode_task on
+        # its pool thread: cpu_s over busy_s there is how much of a
+        # task's wall the thread really ran.
         from .hostpool import map_in_pool
-        t0 = _time.perf_counter()
         scanner = LocalScanner(self.cache, db, memo=self.memo)
 
         def decode_one(item):
@@ -909,55 +915,65 @@ StreamingImageSource`: layer blobs decompress into the scan as they
             except ValueError as e:
                 return e
 
-        decodes = map_in_pool(decode_one, list(boms), chunk=64)
+        with phase_span("decode", pipeline="detect",
+                        docs=len(boms)) as dsp:
+            decodes = map_in_pool(
+                decode_one, list(boms), chunk=64,
+                around=lambda: phase_span("decode_task",
+                                          pipeline="detect"))
         prepared, metas, failures = [], [], {}
-        for i, ((name, _data), dec) in enumerate(zip(boms,
-                                                     decodes)):
-            if isinstance(dec, ValueError):
-                failures[i] = _failed_slot(name, dec)
-                continue
-            atype, decoded, blob, blob_id = dec
-            self.cache.put_blob(blob_id, blob)
-            prepared.append((i, scanner.prepare(
-                ScanTarget(name=name, artifact_id=blob_id,
-                           blob_ids=[blob_id]), options)))
-            metas.append((i, name, atype, decoded))
-        decode_s = _time.perf_counter() - t0
+        all_jobs = []
+        with phase_span("join", pipeline="detect",
+                        docs=len(boms)) as jsp:
+            for i, ((name, _data), dec) in enumerate(zip(boms,
+                                                         decodes)):
+                if isinstance(dec, ValueError):
+                    failures[i] = _failed_slot(name, dec)
+                    continue
+                atype, decoded, blob, blob_id = dec
+                self.cache.put_blob(blob_id, blob)
+                prepared.append((i, scanner.join(
+                    ScanTarget(name=name, artifact_id=blob_id,
+                               blob_ids=[blob_id]), options)))
+                metas.append((i, name, atype, decoded))
+            # the jobs leave the join tagged with their document
+            for idx, (_, p) in enumerate(prepared):
+                for job in p.jobs:
+                    job.payload = (idx, job.payload)
+                    all_jobs.append(job)
 
         # ---- phase 2: ONE interval dispatch over all SBOMs ----
-        t0 = _time.perf_counter()
-        all_jobs = []
-        for idx, (_, p) in enumerate(prepared):
-            for job in p.jobs:
-                job.payload = (idx, job.payload)
-                all_jobs.append(job)
-        detected: dict = {}
         kstats: dict = {}
-        for idx, payload in dispatch_jobs(all_jobs,
-                                          backend=options.backend,
-                                          mesh=self.mesh,
-                                          stats=kstats):
-            detected.setdefault(idx, []).append(payload)
-        interval_s = _time.perf_counter() - t0
+        hits = dispatch_jobs(all_jobs, backend=options.backend,
+                             mesh=self.mesh, stats=kstats)
 
         # ---- phase 3: assemble ----
-        out = dict(failures)
-        for idx, ((i, p), (_, name, atype, decoded)) in \
-                enumerate(zip(prepared, metas)):
-            results, os_found = scanner.finish(
-                p, detected.get(idx, []))
-            out[i] = BatchScanResult(
-                name=name,
-                report=Report(artifact_name=name,
-                              artifact_type=atype,
-                              metadata=Metadata(os=os_found),
-                              results=results,
-                              cyclonedx=decoded.cyclonedx))
+        with phase_span("finish", pipeline="detect",
+                        docs=len(prepared)):
+            detected: dict = {}
+            for idx, payload in hits:
+                detected.setdefault(idx, []).append(payload)
+            out = dict(failures)
+            for idx, ((i, p), (_, name, atype, decoded)) in \
+                    enumerate(zip(prepared, metas)):
+                results, os_found = scanner.finish(
+                    p, detected.get(idx, []))
+                out[i] = BatchScanResult(
+                    name=name,
+                    report=Report(artifact_name=name,
+                                  artifact_type=atype,
+                                  metadata=Metadata(os=os_found),
+                                  results=results,
+                                  cyclonedx=decoded.cyclonedx))
+            ordered = [out[i] for i in range(len(boms))]
         jobs_in = kstats.get("jobs_in", len(all_jobs))
         self.last_stats = {
             "sboms": len(boms),
-            "decode_s": round(decode_s, 4),
-            "interval_dispatch_s": round(interval_s, 4),
+            # decode and the name join, as ever
+            "decode_s": round(dsp.duration_s + jsp.duration_s, 4),
+            # the calling thread's seconds in the interval phases
+            "interval_dispatch_s": round(
+                kstats.get("dispatch_s", 0.0), 4),
             "interval_device_s": round(
                 kstats.get("device_s", 0.0), 4),
             "interval_jobs": len(all_jobs),
@@ -966,7 +982,7 @@ StreamingImageSource`: layer blobs decompress into the scan as they
                 1.0 - kstats.get("jobs_unique", 0) / jobs_in, 4)
             if jobs_in else 0.0,
         }
-        return [out[i] for i in range(len(boms))]
+        return ordered
 
 
 class _CollectingImageArtifact(ImageArtifact):

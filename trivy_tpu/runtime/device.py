@@ -68,7 +68,13 @@ def resolve_device(backend: str = "tpu") -> DeviceInfo:
         return DeviceInfo()
     import jax
 
+    from ..obs.trace import set_annotator
     from .aot import configure_compile_cache
+    # from here on every phase_span, and each DeviceProgram's
+    # compile and dispatch, is also a span on the profiler's clock
+    # (``--profile-out DIR`` shows them above the device rows);
+    # outside a profiler session an annotation costs a flag test
+    set_annotator(jax.profiler.TraceAnnotation)
     try:
         configure_compile_cache()
     except OSError as e:

@@ -56,7 +56,8 @@ def get_host_pool():
     return _POOL
 
 
-def map_in_pool(fn, items: list, chunk: int = 1) -> list:
+def map_in_pool(fn, items: list, chunk: int = 1,
+                around=None) -> list:
     """``[fn(x) for x in items]`` spread over the pool (input order
     preserved). Falls back to the inline loop when the pool is
     disabled, the batch is too small to amortize the hops, or the
@@ -70,23 +71,35 @@ def map_in_pool(fn, items: list, chunk: int = 1) -> list:
     submission made task-dispatch overhead the visible cost of the
     10k-document SBOM decode (BENCH_r05 ``decode_s``): a worker did
     ~0.4 ms of json parsing per ~hop. Decode callers pass 64 so
-    every hop amortizes over a real slab of work."""
+    every hop amortizes over a real slab of work.
+
+    ``around`` (optional) makes a context manager that brackets
+    each pool task (a slab, or the whole inline loop) on the thread
+    that runs it: how a caller books one phase a task, never one an
+    item."""
     from ..detect.metrics import DETECT_METRICS
     on_pool_thread = threading.current_thread().name.startswith(
         "trivy-hostpool")
     pool = get_host_pool() \
         if len(items) > max(8, chunk) and not on_pool_thread \
         else None
+    def task(slab: list) -> list:
+        if around is None:
+            return [fn(x) for x in slab]
+        with around():
+            return [fn(x) for x in slab]
+
     if pool is None:
-        return [fn(x) for x in items]
+        return task(items)
     if chunk > 1:
         slabs = [items[i:i + chunk]
                  for i in range(0, len(items), chunk)]
         DETECT_METRICS.inc("pack_tasks", len(slabs))
         out: list = []
-        for part in pool.map(lambda slab: [fn(x) for x in slab],
-                             slabs):
+        for part in pool.map(task, slabs):
             out.extend(part)
         return out
     DETECT_METRICS.inc("pack_tasks", len(items))
+    if around is not None:
+        return list(pool.map(lambda x: task([x])[0], items))
     return list(pool.map(fn, items))

@@ -308,18 +308,18 @@ class DispatchRing:
                     raise RingClosed("ring closed")
                 if self._in_flight_locked() + self._reserved \
                         >= bound:
-                    t0 = time.monotonic()
                     # a full ring is a typed stall: the pipeline is
                     # gated on the drain thread, and the timeline
                     # attributes device idle under this span to
                     # slot_wait (obs/timeline.py)
-                    with phase_span("slot_wait", ring=self.name,
-                                    depth=bound):
+                    with phase_span("slot_wait", pipeline="sched",
+                                    ring=self.name,
+                                    depth=bound) as sp:
                         while self._in_flight_locked() + \
                                 self._reserved >= bound and \
                                 not self._closed:
                             self._cv.wait(0.1)
-                    waited_s = time.monotonic() - t0
+                    waited_s = sp.duration_s
                     if self._closed:
                         raise RingClosed("ring closed")
                 self._reserved += 1

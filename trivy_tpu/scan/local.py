@@ -96,11 +96,16 @@ class LocalScanner:
         squash/name-join identically on the direct and scheduled
         paths."""
         from ..obs.trace import phase_span
-        with phase_span("join", blobs=len(target.blob_ids)):
-            return self._prepare(target, options)
+        with phase_span("join", pipeline="detect",
+                        blobs=len(target.blob_ids)):
+            return self.join(target, options)
 
-    def _prepare(self, target: ScanTarget,
-                 options: ScanOptions) -> PreparedScan:
+    def join(self, target: ScanTarget,
+             options: ScanOptions) -> PreparedScan:
+        """:meth:`prepare` without a phase of its own, for a caller
+        that brackets one ``join`` phase round a whole batch
+        (``scan_boms``: thousands of one-blob documents a call,
+        where a span a document would be a span inside the loop)."""
         blobs = [self.cache.get_blob(b) for b in target.blob_ids]
         detail = apply_layers(blobs)
 

@@ -13,7 +13,10 @@ an accumulator integrates the wall-clock during which the device was
 busy AND at least one host worker was busy. ``overlap_ratio =
 that / device_busy`` — 0 means the strict host→device ladder the
 round-5 mesh curve flattened on, 1 means the device never waited
-alone.
+alone. "Device busy" here is the host's view: the union of the
+launch-to-collect windows of the dispatches in flight
+(``device_busy_s``), which under steady load is nearly the wall. The
+device's own busy time is the profiler trace's (PERF.md, ROADMAP D9).
 """
 
 from __future__ import annotations
@@ -103,6 +106,9 @@ class LatencyHistogram:
         mean = self.sum / self.total if self.total else 0.0
         return {
             "count": self.total,
+            # cumulative, so the delta of two snapshots is the
+            # seconds observed between them
+            "sum_s": round(self.sum, 6),
             "mean_s": round(mean, 6),
             "p50_s": round(self.quantile(0.50), 6),
             "p90_s": round(self.quantile(0.90), 6),
@@ -278,6 +284,13 @@ class SchedMetrics:
             overlap = self._overlap_s
             if self._both_since is not None:
                 overlap += now - self._both_since
+            # like the overlap, busy counts the union window that is
+            # still open: under steady load the windows of
+            # consecutive batches overlap through the ring and the
+            # last one never closes, so the closed ones alone read 0
+            busy = self._device_busy_s
+            if self._device_since is not None:
+                busy += now - self._device_since
             batches = self.counters["batches"]
             occupancy = (
                 self._batch_bytes / self._bucket_bytes
@@ -303,16 +316,20 @@ class SchedMetrics:
                     "padding_waste": round(padding_waste, 4),
                 },
                 "host_busy_s": round(self._host_busy_s, 4),
-                "device_busy_s": round(self._device_busy_s, 4),
+                "device_busy_s": round(busy, 4),
                 "device_time_s": round(self._device_time_s, 6),
                 "overlap_s": round(overlap, 4),
-                "overlap_ratio": round(
-                    overlap / self._device_busy_s, 4)
-                if self._device_busy_s else 0.0,
+                "overlap_ratio": round(overlap / busy, 4)
+                if busy else 0.0,
                 "uptime_s": round(now - self._started, 2),
                 "latency": {p: h.to_dict()
                             for p, h in self.hist.items()},
             }
+        # the scheduler's own rows of the phase clock
+        # (obs/trace.phase_span: slot_wait); the pipelines' rows
+        # ride their own snapshots under detect and secret below
+        from ..obs.trace import phase_rows
+        out["phase"] = phase_rows("sched")
         # dispatch-ring accounting (runtime/ring.py): current/max
         # dispatch depth, slot occupancy, and the overlap ratio the
         # async runtime buys — process-wide like the guard totals,

@@ -297,34 +297,26 @@ class BatchSecretScanner:
         On the cpu-ref backend the dispatch runs eagerly; with a
         mesh, per-shard packing fans over the host pool and one
         non-blocking shard_map dispatch covers every chip."""
-        import time as _time
         entries = [
             _FileEntry(path=p, content=c, index=i)
             for i, (p, c) in enumerate(files)
         ]
-        t0 = _time.perf_counter()
-        handle = self._dispatch(entries)
-        handle["dispatch_s"] = _time.perf_counter() - t0
-        return handle
+        return self._dispatch(entries)
 
     def collect(self, handle) -> list:
         """Blocking half of scan_files: fetch sieve outputs, decode
         candidates, run the windowed/whole-file exact verify."""
-        import time as _time
-
         from .metrics import SECRET_METRICS
         from ..obs.trace import phase_span
         entries = handle["entries"]
-        t0 = _time.perf_counter()
         candidates = self._decode(handle)
-        sieve_s = handle["dispatch_s"] + _time.perf_counter() - t0
 
-        t0 = _time.perf_counter()
         results = []
         rules_verified = windowed = wholefile = 0
         # the verify tail is a collect-side host phase: the timeline
         # attributes device idle under it to collect_bound
-        with phase_span("verify", files=len(entries)):
+        with phase_span("verify", pipeline="secret",
+                        files=len(entries)) as vsp:
             for fe in entries:
                 chosen = candidates.get(fe.index)
                 if not chosen:
@@ -348,8 +340,11 @@ class BatchSecretScanner:
                     wholefile += len(regions)
                 if secret.findings:
                     results.append((fe.index, secret))
-        verify_s = _time.perf_counter() - t0
 
+        # every second here is a phase's own (obs/trace.phase_span):
+        # sieve_s is pack + upload + dfa_scan + decode, device_s the
+        # upload and dfa_scan part of it (the enqueue, and the fetch
+        # where the device's wall passes on the host)
         self.stats = {
             "files_total": len(entries),
             "bytes_total": sum(len(fe.content) for fe in entries),
@@ -359,10 +354,12 @@ class BatchSecretScanner:
             "rules_wholefile": wholefile,
             "rules_chain_gated": handle.get("chain_gated", 0),
             "files_with_findings": len(results),
-            "sieve_s": round(sieve_s, 4),
+            "sieve_s": round(handle.get("pack_s", 0.0)
+                             + handle["device_s"]
+                             + handle.get("decode_s", 0.0), 4),
             "pack_s": round(handle.get("pack_s", 0.0), 4),
             "device_s": round(handle["device_s"], 4),
-            "verify_s": round(verify_s, 4),
+            "verify_s": round(vsp.duration_s, 4),
             "shard_occupancy": handle.get("shard_occupancy", []),
             "mode": handle.get("mode", ""),
         }
@@ -376,8 +373,6 @@ class BatchSecretScanner:
         consumes; on the fused and sharded paths the jax arrays
         inside are NOT yet materialized — the device(s) compute in
         the background."""
-        import time as _time
-
         from ..obs.trace import phase_span
         handle = {"entries": entries, "device_s": 0.0}
         if self.mesh is not None and self.backend != "cpu-ref":
@@ -394,38 +389,40 @@ class BatchSecretScanner:
             # it brackets as pack, not device-busy; the dfa_scan
             # busy span lives at ShardedSieve.decode()'s join,
             # where the device wall actually passes
-            with phase_span("pack", files=len(entries),
-                            shards=self._shard_count()):
+            with phase_span("pack", pipeline="secret",
+                            files=len(entries),
+                            shards=self._shard_count()) as sp:
                 sharded = ShardedSieve(self, metas)
                 sharded.start()
             handle.update(mode="sharded", sharded=sharded,
+                          pack_s=sp.duration_s,
                           shard_occupancy=sharded.occupancy)
             return handle
 
-        t0 = _time.perf_counter()
-        with phase_span("pack", files=len(entries)) as sp:
+        with phase_span("pack", pipeline="secret",
+                        files=len(entries)) as sp:
             buf, seg_file, seg_pos, occupancy = \
                 self._segment(entries)
             sp.set("segments", int(buf.shape[0]))
-        pack_s = _time.perf_counter() - t0
         handle.update(buf=buf, seg_file=seg_file, seg_pos=seg_pos,
-                      pack_s=pack_s, shard_occupancy=occupancy)
+                      pack_s=sp.duration_s,
+                      shard_occupancy=occupancy)
         if buf.shape[0] == 0:
             handle["mode"] = "empty"
             return handle
         if self.backend == "cpu-ref":
-            t0 = _time.perf_counter()
             from ..ops.dfa import dfa_masks_host
             # the host kernel IS the sieve compute on this path —
             # bracketed as dfa_scan so the timeline counts it busy
             # (the fused path's span lives at its fetch instead,
             # where the async dispatch's wall actually passes)
-            with phase_span("dfa_scan", segments=int(buf.shape[0]),
+            with phase_span("dfa_scan", pipeline="secret",
+                            segments=int(buf.shape[0]),
                             patterns=self.table.n_patterns,
-                            host=True):
+                            host=True) as sp:
                 handle["masks"] = dfa_masks_host(buf, self.table)
             handle["mode"] = "host"
-            handle["device_s"] += _time.perf_counter() - t0
+            handle["device_s"] += sp.duration_s
             return handle
         # fused path: the segment buffer is uploaded ONCE,
         # pattern blockmasks + run hits come out of a single dispatch
@@ -433,23 +430,24 @@ class BatchSecretScanner:
         # compacted to the hit rows (selectivity makes this ~1% of
         # the full [B, K] array; the >CAP fallback fetches all)
         import jax
-        t0 = _time.perf_counter()
         platform = jax.default_backend()
         specs = tuple(self.plan.run_specs)
         tbl = self.table.device_tables()
         fn = self.table.fused_sieve(specs, platform)
-        with phase_span("h2d_upload", bytes=int(buf.nbytes)):
+        with phase_span("h2d_upload", pipeline="secret",
+                        bytes=int(buf.nbytes)) as usp:
             dev = jax.device_put(pad_batch(buf))
         padded_rows = int(dev.shape[0])
-        with phase_span("dfa_scan", segments=int(buf.shape[0]),
-                        patterns=self.table.n_patterns):
+        with phase_span("dfa_scan", pipeline="secret",
+                        segments=int(buf.shape[0]),
+                        patterns=self.table.n_patterns) as sp:
             # the segment buffer is donated to the kernel — ``dev``
             # is dead after this call (the >CAP fallback re-uploads)
             nhit, idx, cm, h = fn(dev, *tbl)
         handle.update(mode="fused", platform=platform,
                       padded_rows=padded_rows,
                       tbl=tbl, nhit=nhit, idx=idx, cm=cm, h=h)
-        handle["device_s"] += _time.perf_counter() - t0
+        handle["device_s"] += usp.duration_s + sp.duration_s
         return handle
 
     def _decode(self, handle: dict) -> dict:
@@ -458,32 +456,33 @@ class BatchSecretScanner:
         A rule maps to merged byte spans when its window proof is
         extraction-exact (the host then regexes only those spans); to
         None when it needs the reference's whole-file scan."""
-        import time as _time
-
         from ..obs.trace import phase_span
         if handle["mode"] == "empty":
             return {}
         entries = handle["entries"]
 
         if handle["mode"] == "sharded":
-            t0 = _time.perf_counter()
-            with phase_span("decode", mode="sharded"):
-                file_codes, runs_map = handle["sharded"].decode()
+            # the join (dfa_scan, fetch=True) comes first, the
+            # pool-fanned block decode and the rule choice after it
+            masks, runs = handle["sharded"].fetch()
             handle["device_s"] += handle["sharded"].device_s
-            handle["pack_s"] = handle["sharded"].pack_s
-            handle["decode_s"] = _time.perf_counter() - t0
+            with phase_span("decode", pipeline="secret",
+                            mode="sharded") as sp:
+                file_codes, runs_map = handle["sharded"].decode(
+                    masks, runs)
 
-            def file_runs(fidx) -> set:
-                return runs_map.get(fidx, set())
+                def file_runs(fidx) -> set:
+                    return runs_map.get(fidx, set())
 
-            return self._choose(handle, entries, file_codes,
-                                file_runs)
+                out = self._choose(handle, entries, file_codes,
+                                   file_runs)
+            handle["decode_s"] = sp.duration_s
+            return out
 
         buf = handle["buf"]
         seg_file = handle["seg_file"]
         seg_pos = handle["seg_pos"]
         run_fetch = None
-        t0 = _time.perf_counter()
         if handle["mode"] == "host":
             # the host kernel already ran (and was bracketed) at
             # dispatch; this nonzero walk is plain decode work and
@@ -496,7 +495,8 @@ class BatchSecretScanner:
             # wall actually passes (materializing the jax arrays
             # blocks on the computation) — bracketed as dfa_scan so
             # the timeline counts it as device-busy, not collect work
-            with phase_span("dfa_scan", fetch=True):
+            with phase_span("dfa_scan", pipeline="secret",
+                            fetch=True) as fsp:
                 B = buf.shape[0]
                 K = self.table.n_patterns
                 nhit = int(handle["nhit"])
@@ -525,7 +525,7 @@ class BatchSecretScanner:
                     seg_nz = ridx[rnz]
                     hit_vals = rows[rnz, code_nz]
                 run_fetch = np.asarray(h)[:B]
-        handle["device_s"] += _time.perf_counter() - t0
+            handle["device_s"] += fsp.duration_s
 
         # run-hits decode is lazy: it happens at most once per batch,
         # and only when a run-gated rule survives its keyword gate
@@ -548,7 +548,8 @@ class BatchSecretScanner:
 
         # per file: pattern column → merged list of
         # (segment file-offset, bitmask)
-        with phase_span("decode", mode=handle["mode"]):
+        with phase_span("decode", pipeline="secret",
+                        mode=handle["mode"]) as sp:
             file_codes: dict = {}
             for si, ci, mv in zip(seg_nz.tolist(),
                                   code_nz.tolist(),
@@ -559,8 +560,10 @@ class BatchSecretScanner:
                 fc.setdefault(ci, []).append((seg_pos[si],
                                               int(mv)))
 
-            return self._choose(handle, entries, file_codes,
-                                file_runs)
+            out = self._choose(handle, entries, file_codes,
+                               file_runs)
+        handle["decode_s"] = sp.duration_s
+        return out
 
     def _choose(self, handle: dict, entries: list, file_codes: dict,
                 file_runs) -> dict:
@@ -634,16 +637,13 @@ class BatchSecretScanner:
         specs = tuple(self.plan.run_specs)
         if not specs:
             return {}
-        import time as _time
         from ..ops.runs import make_run_hits, run_hits_host
-        t0 = _time.perf_counter()
         if self.backend == "cpu-ref":
             hits = run_hits_host(buf, specs)
         else:
             B = buf.shape[0]
             hits = np.asarray(
                 make_run_hits(specs)(pad_batch(buf)))[:B]
-        handle["device_s"] += _time.perf_counter() - t0
         out: dict = {}
         for si, sp in zip(*np.nonzero(hits)):
             if seg_file[int(si)] < 0:

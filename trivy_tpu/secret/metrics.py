@@ -94,6 +94,10 @@ class SecretMetrics:
         out["dfa_upload_amortization"] = round(
             out["dfa_dispatches"] / out["dfa_uploads"], 2) \
             if out["dfa_uploads"] else 0.0
+        # the sieve's rows of the phase clock (obs/trace.phase_span:
+        # pack, h2d_upload, dfa_scan, decode, verify), cumulative
+        from ..obs.trace import phase_rows
+        out["phase"] = phase_rows("secret")
         return out
 
 
