@@ -324,3 +324,148 @@ class TestChainRunGates:
         want = _norm([cpu.scan(p, c) for p, c in files
                       if cpu.scan(p, c).findings])
         assert got == want
+
+
+# --- the batch pack (secret/batch.py _pack) against _fill_rows ---
+
+def _cell_sizes(n: int) -> list:
+    """``n`` file sizes read off the fleet-secrets cell's measured
+    ``file_size_quantiles`` at evenly spaced quantiles, as the
+    benchmark's generator reads them."""
+    import json
+    import os
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", "image-fleet-1chip.json")
+    with open(path) as f:
+        q = np.array(json.load(f)["sizes"]["file_size_quantiles"],
+                     float)
+    at = (np.arange(n) + 0.5) / n
+    return np.exp(np.interp(at, q[:, 0], np.log(q[:, 1]))) \
+        .astype(np.int64).tolist()
+
+
+def _pack_sizes(case: str, L: int, overlap: int) -> list:
+    """The batch's file sizes for a case: the size under test sits
+    between two neighbours, whose bytes must not show in its rows."""
+    step, k = L - overlap, 3
+    one = {
+        "1": 1, "L-1": L - 1, "L": L, "L+1": L + 1, "step": step,
+        "k*step": k * step, "k*step+1": k * step + 1,
+        "k*step+overlap-1": k * step + overlap - 1,
+        "k*step+overlap": k * step + overlap,
+        "k*step+overlap+1": k * step + overlap + 1,
+        "1.2MB": 1_200_000,
+    }
+    if case in one:
+        return [777, one[case], L + 9]
+    if case == "empty-among-others":
+        return [500, 0, 2 * L, 0, 40, 0]
+    if case == "one-file":
+        return [3 * L + 17]
+    assert case == "cell-600"
+    return _cell_sizes(600)
+
+
+PACK_CASES = ["1", "L-1", "L", "L+1", "step", "k*step", "k*step+1",
+              "k*step+overlap-1", "k*step+overlap",
+              "k*step+overlap+1", "1.2MB", "empty-among-others",
+              "one-file", "cell-600"]
+
+
+@pytest.fixture(scope="module")
+def packers(mesh8):
+    return {"one-shard": BatchSecretScanner(backend="cpu-ref"),
+            "mesh-layout": BatchSecretScanner(backend="cpu-ref",
+                                              mesh=mesh8)}
+
+
+def _entries(sizes: list, seed: int) -> list:
+    from trivy_tpu.secret.batch import _FileEntry
+    rng = np.random.default_rng(seed)
+    sizes = list(sizes)
+    rng.shuffle(sizes)
+    # bytes 1..255: a zero in a row is a byte the pack did not write
+    return [_FileEntry(path=f"f{i}", index=i,
+                       content=rng.integers(1, 256, n)
+                       .astype(np.uint8).tobytes())
+            for i, n in enumerate(sizes)]
+
+
+@pytest.mark.parametrize("layout", ["one-shard", "mesh-layout"])
+@pytest.mark.parametrize("case", PACK_CASES)
+def test_pack_equals_fill_rows_reference(packers, case, layout):
+    """The packed rows, ``seg_file`` and ``seg_pos`` are those a
+    plain loop of ``_fill_rows`` and a loop a row write."""
+    s = packers[layout]
+    entries = _entries(_pack_sizes(case, s.seg_len, s.overlap),
+                       seed=PACK_CASES.index(case))
+    metas = s._metas(entries)
+    lay = s._layout(metas)
+    step = s.seg_len - s.overlap
+    want = np.zeros((lay["B"], s.seg_len), np.uint8)
+    want_file, want_pos = [-1] * lay["B"], [0] * lay["B"]
+    for row0, mi in lay["layout"]:
+        fe, _n, n_segs = metas[mi]
+        s._fill_rows(want, row0, fe.content, n_segs)
+        for k in range(n_segs):
+            want_file[row0 + k] = fe.index
+            want_pos[row0 + k] = k * step
+    if layout == "mesh-layout" and len(metas) > 1:
+        assert lay["n_shards"] > 1
+
+    buf, seg_file, seg_pos, _occ = s._segment(entries)
+    assert seg_file == want_file and seg_pos == want_pos
+    assert buf.dtype == np.uint8
+    np.testing.assert_array_equal(buf, want)
+
+
+def test_secret_pack_submits_no_pool_task(monkeypatch):
+    """``dispatch_files`` packs on the calling thread: the host pool
+    sees no task and ``pack_tasks`` stays where it was."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    import trivy_tpu.runtime.hostpool as hp
+    from trivy_tpu.detect.metrics import DETECT_METRICS
+
+    class Counting(ThreadPoolExecutor):
+        submitted = 0
+
+        def submit(self, *a, **kw):
+            Counting.submitted += 1
+            return super().submit(*a, **kw)
+
+    pool = Counting(max_workers=2,
+                    thread_name_prefix="trivy-hostpool")
+    monkeypatch.setattr(hp, "_POOL", pool)
+    try:
+        s = BatchSecretScanner(backend="cpu-ref")
+        rng = np.random.default_rng(5)
+        files = [(f"f{i}", rng.integers(32, 127, int(n))
+                  .astype(np.uint8).tobytes())
+                 for i, n in enumerate(rng.integers(1, 3000, 600))]
+        before = DETECT_METRICS.snapshot()["pack_tasks"]
+        handle = s.dispatch_files(files)
+        assert handle["buf"].shape[0] >= 600
+        assert DETECT_METRICS.snapshot()["pack_tasks"] == before
+        assert Counting.submitted == 0
+    finally:
+        pool.shutdown(wait=False)
+
+
+def test_pack_buffer_is_allocated_padded():
+    """The pack allocates at the upload's padded row count: rows B
+    to ``_bucket(B)`` are zero and ``pad_batch`` copies nothing."""
+    from trivy_tpu.ops.keywords import _bucket, pad_batch
+    s = BatchSecretScanner(backend="cpu-ref")
+    entries = _entries([5000] * 100 + [300] * 50, seed=3)
+    padded, seg_file, _pos, _occ = s._pack(entries)
+    B = len(seg_file)
+    assert B == 100 * s._n_segs(5000) + 50
+    assert padded.shape == (_bucket(B), s.seg_len) and B < _bucket(B)
+    assert padded[:B].all(axis=1).sum() >= 100   # full rows written
+    assert not padded[B:].any()
+    assert pad_batch(padded) is padded
+    handle = s._dispatch(entries)
+    assert handle["padded"].shape[0] == _bucket(B)
+    assert np.shares_memory(handle["buf"], handle["padded"])
+    assert handle["buf"].shape[0] == B

@@ -2,10 +2,16 @@
 (docs/performance.md "host/device overlap").
 
 One process-wide pool, sized to the host's spare cores, reserved for
-tasks that NEVER block on scheduler events: segment-buffer packing
-(secret/batch.py), SBOM decode (runtime/batch.py), and the direct
-path's sieve enqueue. Keeping it separate from the scheduler's
-worker pool is load-bearing, not stylistic — the scheduler pool runs
+tasks that NEVER block on scheduler events: the mesh sieve's
+per-shard segment packing and block decode
+(parallel/secret_shard.py), interval wave packing (detect/batch.py),
+SBOM decode (runtime/batch.py), and the direct path's sieve enqueue.
+The one-device secret pack is NOT here: it runs on the thread that
+calls ``dispatch_files`` without letting go of the interpreter
+(secret/batch.py ``_pack``), because a task a file made that thread
+wait for the lock hundreds of times a batch. Keeping the pool
+separate from the scheduler's worker pool is load-bearing, not
+stylistic — the scheduler pool runs
 ``finish`` tasks that wait on patch events only the device thread
 resolves, so routing a pack task there while the device thread
 blocks on its future could deadlock the pipeline. Tasks here are

@@ -39,7 +39,8 @@ from typing import Iterable, Optional
 
 import numpy as np
 
-from ..ops.keywords import MAX_CODE_LEN, N_BLOCKS, pad_batch
+from ..ops.keywords import (MAX_CODE_LEN, N_BLOCKS, _bucket,
+                            pad_batch)
 from ..utils import get_logger
 from .plan import ScanPlan, build_scan_plan
 from .scanner import Scanner
@@ -151,9 +152,11 @@ class BatchSecretScanner:
     def _fill_rows(self, buf: np.ndarray, row0: int, content: bytes,
                    n_segs: int) -> None:
         """Pack one file's overlapping segments into ``buf`` rows
-        [row0, row0+n_segs) with ONE bulk strided copy — the
-        per-chunk slice/copy loop this replaces was the dominant
-        host cost of the sieve dispatch (docs/performance.md)."""
+        [row0, row0+n_segs) with one bulk strided copy. The mesh
+        path packs with it, a shard a pool task
+        (parallel/secret_shard.py); the one-device path packs a
+        whole batch in ``_pack`` instead, and the tests hold that to
+        the rows this writes."""
         L, step = self.seg_len, self.seg_len - self.overlap
         n = len(content)
         arr = np.frombuffer(content, np.uint8)
@@ -189,7 +192,6 @@ class BatchSecretScanner:
         # devices uses fewer shards instead of padding every tiny
         # shard up to a full block (measured 2× sieve inflation on
         # the mesh bench's ~250-segment scheduler batches)
-        from ..ops.keywords import _bucket
         MIN_SHARD_ROWS = 64          # = the pallas tile (TILE_B)
         if n_shards > 1 and len(metas) > 1:
             Bp = _bucket(total, base=4 * MIN_SHARD_ROWS)
@@ -238,9 +240,9 @@ class BatchSecretScanner:
         seg_pos = [0] * B
         for row0, mi in layout:
             fe, _n, n_segs = metas[mi]
-            for k in range(n_segs):
-                seg_file[row0 + k] = fe.index
-                seg_pos[row0 + k] = k * step
+            seg_file[row0:row0 + n_segs] = [fe.index] * n_segs
+            seg_pos[row0:row0 + n_segs] = range(0, n_segs * step,
+                                                step)
         return {"B": B, "layout": layout, "seg_file": seg_file,
                 "seg_pos": seg_pos, "occupancy": occupancy,
                 "n_shards": n_shards,
@@ -250,29 +252,47 @@ class BatchSecretScanner:
         return [(fe, len(fe.content), self._n_segs(len(fe.content)))
                 for fe in files if len(fe.content) > 0]
 
-    def _segment(self, files: list) -> tuple:
-        """Flatten files into [B, L] uint8 with per-file overlap
-        chaining. Returns (buffer, seg_file, seg_pos,
-        shard_occupancy). Row filling is bulk strided copies, fanned
-        over the host pool when the batch is large enough to
-        amortize it. (The sharded-async path packs per shard instead
-        — parallel.secret_shard.)"""
-        from ..runtime.hostpool import map_in_pool
+    def _pack(self, files: list) -> tuple:
+        """Flatten files into [_bucket(B), L] uint8 with per-file
+        overlap chaining. Returns (padded buffer, seg_file, seg_pos,
+        shard_occupancy); the real rows are the first
+        ``len(seg_file)``, the rest are zero, so ``pad_batch`` has
+        nothing to add before the upload.
+
+        The whole pack runs on the calling thread and never lets go
+        of the interpreter: one zeroed allocation, then one
+        buffer-protocol slice copy a row, no numpy call and no pool
+        task. On the scheduler's launch thread every call that drops
+        the lock (a pool future, a numpy copy of over ~500 elements)
+        is a wait of up to the switch interval behind the analyze
+        workers, and that wait, not the copy, was the pack's cost.
+        (The sharded-async path packs a shard a pool task instead:
+        parallel.secret_shard.)"""
+        L, step = self.seg_len, self.seg_len - self.overlap
         metas = self._metas(files)
         if not metas:
-            return (np.zeros((0, self.seg_len), np.uint8), [], [],
-                    [])
+            return np.zeros((0, L), np.uint8), [], [], []
         lay = self._layout(metas)
-        buf = np.zeros((lay["B"], self.seg_len), np.uint8)
+        raw = bytearray(_bucket(lay["B"]) * L)
+        out = memoryview(raw)
+        for row0, mi in lay["layout"]:
+            fe, n, n_segs = metas[mi]
+            src = memoryview(fe.content)
+            at = row0 * L
+            last = (n_segs - 1) * step
+            for off in range(0, last, step):
+                out[at:at + L] = src[off:off + L]
+                at += L
+            out[at:at + n - last] = src[last:]
+        return (np.frombuffer(raw, np.uint8).reshape(-1, L),
+                lay["seg_file"], lay["seg_pos"], lay["occupancy"])
 
-        def fill(task) -> None:
-            row0, mi = task
-            fe, _n, n_segs = metas[mi]
-            self._fill_rows(buf, row0, fe.content, n_segs)
-
-        map_in_pool(fill, lay["layout"])
-        return (buf, lay["seg_file"], lay["seg_pos"],
-                lay["occupancy"])
+    def _segment(self, files: list) -> tuple:
+        """``_pack`` without the pad rows: (buffer [B, L], seg_file,
+        seg_pos, shard_occupancy)."""
+        padded, seg_file, seg_pos, occupancy = self._pack(files)
+        return (padded[:len(seg_file)], seg_file, seg_pos,
+                occupancy)
 
     # --- the public API ---
 
@@ -401,11 +421,12 @@ class BatchSecretScanner:
 
         with phase_span("pack", pipeline="secret",
                         files=len(entries)) as sp:
-            buf, seg_file, seg_pos, occupancy = \
-                self._segment(entries)
+            padded, seg_file, seg_pos, occupancy = \
+                self._pack(entries)
+            buf = padded[:len(seg_file)]
             sp.set("segments", int(buf.shape[0]))
-        handle.update(buf=buf, seg_file=seg_file, seg_pos=seg_pos,
-                      pack_s=sp.duration_s,
+        handle.update(buf=buf, padded=padded, seg_file=seg_file,
+                      seg_pos=seg_pos, pack_s=sp.duration_s,
                       shard_occupancy=occupancy)
         if buf.shape[0] == 0:
             handle["mode"] = "empty"
@@ -436,7 +457,7 @@ class BatchSecretScanner:
         fn = self.table.fused_sieve(specs, platform)
         with phase_span("h2d_upload", pipeline="secret",
                         bytes=int(buf.nbytes)) as usp:
-            dev = jax.device_put(pad_batch(buf))
+            dev = jax.device_put(pad_batch(padded))
         padded_rows = int(dev.shape[0])
         with phase_span("dfa_scan", pipeline="secret",
                         segments=int(buf.shape[0]),
@@ -511,8 +532,9 @@ class BatchSecretScanner:
                     import jax as _jax
                     full = self.table.full_sieve(
                         (), handle["platform"])
-                    m, _ = full(_jax.device_put(pad_batch(buf)),
-                                *handle["tbl"])
+                    m, _ = full(
+                        _jax.device_put(pad_batch(handle["padded"])),
+                        *handle["tbl"])
                     masks = np.asarray(m)[:B, :K]
                     seg_nz, code_nz = np.nonzero(masks)
                     hit_vals = masks[seg_nz, code_nz]
@@ -542,7 +564,7 @@ class BatchSecretScanner:
                             seg_file[int(si)], set()).add(int(sp))
                 else:
                     runs_cache.update(
-                        self._file_runs(buf, seg_file, handle))
+                        self._file_runs(buf, seg_file))
                 runs_ready[0] = True
             return runs_cache.get(fidx, set())
 
@@ -629,21 +651,17 @@ class BatchSecretScanner:
         handle["chain_gated"] = chain_gated
         return out
 
-    def _file_runs(self, buf: np.ndarray, seg_file: list,
-                   handle: dict) -> dict:
+    def _file_runs(self, buf: np.ndarray, seg_file: list) -> dict:
         """file index → set of run-spec indices present somewhere in
-        the file. One elementwise dispatch over the same segment
-        buffer the sieve used; overlap ≥ max runlen keeps it sound."""
+        the file, on the host kernel's path (the fused dispatch
+        brings its run hits along). One elementwise pass over the
+        same segment buffer the sieve used; overlap ≥ max runlen
+        keeps it sound."""
         specs = tuple(self.plan.run_specs)
         if not specs:
             return {}
-        from ..ops.runs import make_run_hits, run_hits_host
-        if self.backend == "cpu-ref":
-            hits = run_hits_host(buf, specs)
-        else:
-            B = buf.shape[0]
-            hits = np.asarray(
-                make_run_hits(specs)(pad_batch(buf)))[:B]
+        from ..ops.runs import run_hits_host
+        hits = run_hits_host(buf, specs)
         out: dict = {}
         for si, sp in zip(*np.nonzero(hits)):
             if seg_file[int(si)] < 0:
