@@ -240,9 +240,10 @@ class TestProgramFault:
         assert "failed to compile" in err
         assert "degraded" not in err
 
-    def test_boot_precompile_lets_compile_errors_through(
-            self, monkeypatch, tmp_path):
+    def test_warm_ladders_lets_compile_errors_through(
+            self, monkeypatch, tmp_path, fleet):
         from trivy_tpu.runtime import aot
+        _paths, cdb, _prefix = fleet
 
         def refuse(*a, **kw):
             raise DeviceProgramError("interval_hits refused")
@@ -250,7 +251,8 @@ class TestProgramFault:
         monkeypatch.setattr(aot, "precompile_interval_shapes",
                             refuse)
         with pytest.raises(DeviceProgramError):
-            aot.boot_precompile(cache_dir=str(tmp_path / "c"))
+            aot.warm_ladders(store=cdb,
+                             cache_dir=str(tmp_path / "c"))
 
     def test_server_exits_nonzero_on_a_latched_fault(self):
         from trivy_tpu.rpc.server import ScanServer, serve_forever

@@ -309,8 +309,11 @@ class TestSchedulerFaults:
             workers=1, flush_timeout_s=0.01))
         sched.fault_injector = inj
         try:
+            # a candidate, so that the request rides a batch (one
+            # with nothing for the device never meets it)
             req = sched.submit(ScanRequest(
                 "inflight", lambda r: AnalyzedWork(
+                    candidates=[("/f", b"x")],
                     finish=lambda f, d: "late"),
                 deadline_s=0.1))
             with pytest.raises(DeadlineExceeded):
@@ -637,7 +640,7 @@ class TestDegradedReports:
         rc = cli.main([
             "image", *paths, "--format", "json",
             "--output", str(out), "--backend", "cpu",
-            "--no-cache", "--security-checks", "vuln",
+            "--no-cache", "--security-checks", "vuln,secret",
             "--fault-spec", "poison-image:poison=img1.tar"])
         assert rc == 0
         docs = json.loads(out.read_text())

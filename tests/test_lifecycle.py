@@ -25,7 +25,7 @@ lifecycle").
   under a join;
 * the AOT compile-cache manifest: key sensitivity, hit/miss
   accounting across boots, corrupt-manifest recovery, and
-  ``boot_precompile`` surviving an unwritable cache dir (compile
+  ``warm_ladders`` surviving an unwritable cache dir (compile
   errors propagate — tests/test_device_runtime.py);
 * the ScanServer lifecycle surface (warming /healthz, token-gated
   /handoff, /prefetch adoption, metrics sections) and the
@@ -578,19 +578,29 @@ class TestAotManifest:
         m3.note("k2", {})       # and writes recover it
         assert _Manifest(str(tmp_path)).seen("k2")
 
+    @staticmethod
+    def _tiny_cdb():
+        from trivy_tpu.db import AdvisoryStore, CompiledDB
+        store = AdvisoryStore()
+        store.put_advisory("alpine 3.16", "musl", "CVE-2026-0001",
+                           {"FixedVersion": "1.2.3-r1"})
+        return CompiledDB.compile(store)
+
     def test_precompile_books_miss_then_hit(self, tmp_path):
         from trivy_tpu.runtime.aot import (COMPILE_CACHE_METRICS,
                                            precompile_interval_shapes)
+        cdb = self._tiny_cdb()
         COMPILE_CACHE_METRICS.reset()
         try:
             out = precompile_interval_shapes(
-                buckets=(8,), cache_dir=str(tmp_path))
+                cdb, buckets=(8,), cache_dir=str(tmp_path))
+            assert out["kernel"] == "interval_resident"
             assert out["shapes"] == [8]
             snap = COMPILE_CACHE_METRICS.snapshot()
             assert snap["misses"] == 1 and snap["hits"] == 0
             assert snap["precompiled"] == 1
             # the next boot finds the keyed shape in the manifest
-            precompile_interval_shapes(buckets=(8,),
+            precompile_interval_shapes(cdb, buckets=(8,),
                                        cache_dir=str(tmp_path))
             snap = COMPILE_CACHE_METRICS.snapshot()
             assert snap["hits"] == 1 and snap["misses"] == 1
@@ -598,15 +608,18 @@ class TestAotManifest:
         finally:
             COMPILE_CACHE_METRICS.reset()
 
-    def test_boot_precompile_survives_a_bad_cache_dir(self, tmp_path):
-        from trivy_tpu.runtime.aot import boot_precompile
+    def test_warm_ladders_survives_a_bad_cache_dir(self, tmp_path):
+        from trivy_tpu.runtime.aot import warm_ladders
+        from trivy_tpu.sched import SchedConfig
         blocker = tmp_path / "file"
         blocker.write_text("x")
-        summary = boot_precompile(
-            cache_dir=str(blocker / "nested"),
-            pair_buckets=(8,))
+        summary = warm_ladders(
+            store=self._tiny_cdb(),
+            config=SchedConfig(max_batch_jobs=64),
+            cache_dir=str(blocker / "nested"))
         assert summary["persistent"] is False
         assert summary["seconds"] >= 0.0
+        assert [k["shapes"] for k in summary["kernels"]] == [[64]]
 
 
 # ---------------------------------------------------------------

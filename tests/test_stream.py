@@ -230,7 +230,7 @@ class TestWarmSkip:
         assert "trivy_tpu_ingest_bytes_skipped_total 1234" in text
         assert "trivy_tpu_ingest_range_resumes_total 2" in text
         # every counter key has a family, with HELP/TYPE lines
-        for key in INGEST_METRICS.snapshot():
+        for key in INGEST_METRICS.counters:
             assert f"trivy_tpu_ingest_{key}_total" in text
             assert f"# TYPE trivy_tpu_ingest_{key}_total counter" \
                 in text

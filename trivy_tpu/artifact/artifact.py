@@ -25,6 +25,7 @@ from ..types import (ArtifactInfo, ArtifactReference, BlobInfo,
 from ..utils import get_logger
 from .cache import calc_key
 from .image import ImageSource, guess_base_layers
+from .metrics import INGEST_METRICS
 from .walker import collect_layer_tar, walk_fs
 
 log = get_logger("artifact")
@@ -166,6 +167,7 @@ class ImageArtifact:
     def inspect(self) -> ArtifactReference:
         img = self.image
         artifact_id, blob_ids, base = self.cache_keys()
+        self._bytes_analyzed = 0
 
         try:
             missing_artifact, missing = self.cache.missing_blobs(
@@ -190,6 +192,11 @@ class ImageArtifact:
                 if prefetch is not None:
                     prefetch(todo)
                 self._inspect_layers(todo, blob_ids, base)
+            # the cache's outcome for this image, where the
+            # benchmark's snapshots read it (artifact/metrics.py)
+            INGEST_METRICS.note_inspect(
+                len(blob_ids), len(todo), self._bytes_analyzed,
+                len(base))
             if missing_artifact and \
                     getattr(self, "_os_found", None) is None:
                 # OS layer may be a cache hit while the artifact
@@ -284,6 +291,7 @@ class ImageArtifact:
                     for path, size, read in files:
                         if self._skipped(path):
                             continue
+                        self._bytes_analyzed += size
                         self.group.analyze_file(result, path, read,
                                                 size)
             add_event("layer_analyzed", layer=i,

@@ -1,0 +1,75 @@
+"""Ingest metrics: what the streaming path fetched and skipped, what
+the blob cache answered, and what the walker and analyzers had to do
+instead (docs/observability.md "The phase clock",
+docs/performance.md §9).
+
+Process-wide by design, mirroring ``detect.metrics.DETECT_METRICS``:
+the numbers an operator watches are cumulative totals over every
+image inspected, whichever runner or cache inspected it. One short
+lock an event; nothing here sits on a per-file path.
+"""
+
+from __future__ import annotations
+
+import threading
+
+
+class IngestMetrics:
+    """Process-wide ingest counters (thread-safe); snapshotted into
+    ``scheduler.stats()`` and ``GET /metrics`` on both sched modes
+    and rendered as ``trivy_tpu_ingest_*_total`` Prometheus
+    families."""
+
+    _KEYS = (
+        # the streaming path (artifact/stream.py)
+        "streams", "layers_fetched", "bytes_fetched",
+        "layers_skipped", "bytes_skipped", "range_resumes",
+        "full_restarts", "warm_probe_outages",
+        "cancelled_fetches", "config_memo_hits",
+        # ImageArtifact.inspect: layers an image asked the cache
+        # for, those it held, and those walked and analyzed instead
+        # (seen = cached + analyzed)
+        "layers_seen", "layers_cached", "layers_analyzed",
+        # file bytes the walker handed the analyzers in those layers
+        "bytes_analyzed",
+        # layers whose secrets the base-image rule leaves out of the
+        # image's report (image.guess_base_layers), cached or not
+        "base_layers_skipped",
+    )
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.counters = {k: 0 for k in self._KEYS}
+
+    def inc(self, name: str, n: int = 1) -> None:
+        with self._lock:
+            # lint: disable=unbounded-label-cardinality -- counter
+            # names are code-literal call sites, never
+            # request-derived strings
+            self.counters[name] = self.counters.get(name, 0) + n
+
+    def note_inspect(self, layers: int, analyzed: int,
+                     nbytes: int, base: int) -> None:
+        with self._lock:
+            c = self.counters
+            c["layers_seen"] += layers
+            c["layers_cached"] += layers - analyzed
+            c["layers_analyzed"] += analyzed
+            c["bytes_analyzed"] += nbytes
+            c["base_layers_skipped"] += base
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            out = dict(self.counters)
+        # the walker's and analyzers' row of the phase clock
+        # (obs/trace.phase_span: layer_analyze), cumulative
+        from ..obs.trace import phase_rows
+        out["phase"] = phase_rows("ingest")
+        return out
+
+    def reset(self) -> None:
+        with self._lock:
+            self.counters = {k: 0 for k in self._KEYS}
+
+
+INGEST_METRICS = IngestMetrics()

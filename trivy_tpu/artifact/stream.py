@@ -62,44 +62,12 @@ from ..guard.safetar import _ARCHIVE_ERRORS, GZIP_MAGIC
 from ..obs.trace import activate_or_null, current_span
 from ..utils import get_logger
 from .image import LayerRef
+from .metrics import INGEST_METRICS
 from .registry import DistributionClient, _display_repo
 
 log = get_logger("artifact.stream")
 
 _CHUNK = 1 << 16               # safetar's bounded-inflate chunk size
-
-
-class IngestMetrics:
-    """Process-wide streaming-ingest counters (thread-safe);
-    snapshotted into ``GET /metrics`` on both sched modes and
-    rendered as ``trivy_tpu_ingest_*_total`` Prometheus families."""
-
-    _KEYS = ("streams", "layers_fetched", "bytes_fetched",
-             "layers_skipped", "bytes_skipped", "range_resumes",
-             "full_restarts", "warm_probe_outages",
-             "cancelled_fetches", "config_memo_hits")
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.counters = {k: 0 for k in self._KEYS}
-
-    def inc(self, name: str, n: int = 1) -> None:
-        with self._lock:
-            # lint: disable=unbounded-label-cardinality -- counter
-            # names are code-literal call sites, never
-            # request-derived strings
-            self.counters[name] = self.counters.get(name, 0) + n
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            return dict(self.counters)
-
-    def reset(self) -> None:
-        with self._lock:
-            self.counters = {k: 0 for k in self._KEYS}
-
-
-INGEST_METRICS = IngestMetrics()
 
 # Digest-addressed memo of image CONFIG blobs. Configs are the one
 # blob the warm-layer probe itself needs (cache keys derive from

@@ -87,6 +87,14 @@ class DetectMetrics:
         # h2d_upload, device_compute, finish, gc), cumulative
         from ..obs.trace import phase_rows
         out["phase"] = phase_rows("detect")
+        # the findings memo's outcomes (memo/metrics.py), beside the
+        # memo_lookup and memo_store rows above: queries asked, those
+        # answered, and layers served whole
+        from ..memo.metrics import MEMO_METRICS
+        memo = MEMO_METRICS.snapshot()
+        out["memo"] = {"lookups": memo["hits"] + memo["misses"],
+                       "hits": memo["hits"],
+                       "layer_hits": memo["layer_hits"]}
         return out
 
 

@@ -292,7 +292,7 @@ def render_prometheus(stats: dict, phase_hists=None,
                  "hits/misses, resident-DB uploads).")
         for k in sorted(detect):
             if k.endswith(("_rate", "_ratio", "amortization")) \
-                    or k in ("db_upload_bytes", "phase"):
+                    or k in ("db_upload_bytes", "phase", "memo"):
                 continue     # derived gauges / byte totals below —
                 # a byte count inside an event-count family would
                 # poison any sum() over it
@@ -349,11 +349,12 @@ def render_prometheus(stats: dict, phase_hists=None,
                  "DFA-table dispatches served per HBM upload.",
                  secret.get("dfa_upload_amortization"))
 
+    ingest = stats.get("ingest") or {}
     _phase_rows(w, {"sched": stats.get("phase"),
                     "detect": detect.get("phase"),
-                    "secret": secret.get("phase")})
+                    "secret": secret.get("phase"),
+                    "ingest": ingest.get("phase")})
 
-    ingest = stats.get("ingest") or {}
     if ingest:
         # streaming-ingest counters (docs/performance.md §9):
         # per-key scalars so the warm-skip and resume behavior are
@@ -382,7 +383,17 @@ def render_prometheus(stats: dict, phase_hists=None,
                  "budget trip."),
                 ("config_memo_hits",
                  "Image config blobs served from the digest memo "
-                 "without a GET.")):
+                 "without a GET."),
+                ("layers_seen",
+                 "Layers images asked the blob cache for."),
+                ("layers_cached", "Layers the blob cache held."),
+                ("layers_analyzed",
+                 "Layers walked and analyzed on a cache miss."),
+                ("bytes_analyzed",
+                 "File bytes handed to the analyzers in analyzed "
+                 "layers."),
+                ("base_layers_skipped",
+                 "Base-image layers left out of the secret scan.")):
             w.scalar(f"{_PREFIX}_ingest_{k}_total", "counter",
                      help_, ingest.get(k))
 

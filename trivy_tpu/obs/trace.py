@@ -118,8 +118,12 @@ def annotation(name: str):
     return ann(name) if ann is not None else _NO_ANNOTATION
 
 
-def _book_phase(pipeline: str, phase: str, busy_s: float,
-                cpu_s: float) -> None:
+def book_phase(pipeline: str, phase: str, busy_s: float,
+               cpu_s: float = 0.0) -> None:
+    """One row booked by hand, for a wait that starts on one thread
+    and ends on another and so has no ``with`` to stand in (the
+    scheduler's ``hit_wait``). Everything else uses
+    :func:`phase_span`."""
     key = (pipeline, phase)
     with _PHASE_LOCK:
         row = _PHASE_ROWS.get(key)
@@ -203,8 +207,8 @@ class _PhaseSpanCtx:
         self.cpu_s = max(0.0, min(cpu, self.duration_s))
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        _book_phase(self.pipeline, self.name, self.duration_s,
-                    self.cpu_s)
+        book_phase(self.pipeline, self.name, self.duration_s,
+                   self.cpu_s)
 
 
 def phase_span(name: str, *, pipeline: str, **attrs) -> _PhaseSpanCtx:

@@ -377,20 +377,6 @@ class DfaTable(ResidentTables):
         when a batch overflows the compaction capacity."""
         return self._fn(("full", run_specs, platform))
 
-    def precompile(self, run_specs: tuple = (), buckets=None,
-                   cache_dir: str = "", platform: str = "") -> dict:
-        """Warm this table's fused sieve over the segment ladder
-        into the persistent compilation cache, keyed on
-        ``rules_hash`` (docs/serving.md "Elastic lifecycle") —
-        stages the resident arrays as a side effect. A boot-time
-        hook: the first real batch after a scale-up neither traces
-        nor uploads."""
-        from ..runtime.aot import (DEFAULT_SEG_BUCKETS,
-                                   precompile_dfa_shapes)
-        return precompile_dfa_shapes(
-            self, run_specs, buckets or DEFAULT_SEG_BUCKETS,
-            cache_dir, platform)
-
     def mesh_sieve(self, mesh, run_specs: tuple, platform: str):
         """Mesh variant: the segment rows shard over EVERY chip
         (flat — masks are row-elementwise, no collective needed),

@@ -316,9 +316,16 @@ class ScanServer:
         self.hot = HotSet()
         self._warming = False
         self.compile_cache: dict = {}
-        if compile_cache_dir:
-            from ..runtime.aot import boot_precompile
-            self.compile_cache = boot_precompile(
+        from ..runtime.aot import warm_ladders
+        from ..runtime.device import on_accelerator
+        if compile_cache_dir or (self._owns_scheduler
+                                 and on_accelerator()):
+            # thin clients sieve on their own hosts, so the interval
+            # ladder against the resident table is all there is
+            self.compile_cache = warm_ladders(
+                store=self.store,
+                config=self.scheduler.config
+                if self.scheduler is not None else None,
                 cache_dir=compile_cache_dir)
         if prewarm_members and self.memo is not None:
             self._warming = True
@@ -666,10 +673,11 @@ class ScanServer:
             from ..memo.metrics import MEMO_METRICS
             out["memo"] = MEMO_METRICS.snapshot()
         if "ingest" not in out:
-            # streaming-ingest counters (layers fetched/warm-skipped,
-            # Range resumes, cancelled fetches — docs/performance.md
-            # §9), identical section shape on both sched modes
-            from ..artifact.stream import INGEST_METRICS
+            # ingest counters (layers fetched/warm-skipped, Range
+            # resumes, cancelled fetches — docs/performance.md §9;
+            # layers cached and analyzed), identical section shape
+            # on both sched modes
+            from ..artifact.metrics import INGEST_METRICS
             out["ingest"] = INGEST_METRICS.snapshot()
         if self.memo is not None:
             out["memo"] = self.memo.stats()

@@ -13,13 +13,14 @@ cheap one:
   of the same (jax version, backend) is deserialized instead of
   rebuilt. Every entry point calls it through
   ``runtime.device.resolve_device``.
-* :func:`precompile_interval_shapes` / :func:`precompile_dfa_shapes`
-  walk the SAME shape ladders the serving path buckets into
-  (``ops/keywords._bucket`` for segment buffers,
-  ``detect/batch._job_bucket`` for pair rows) and execute each
-  jitted kernel once on zero inputs — populating the in-process jit
-  cache (the first real request never traces) AND the persistent
-  cache (the next replica's boot never rebuilds).
+* :func:`warm_ladders` walks the SAME shape ladders the scheduled
+  path buckets into (``ops/keywords._bucket`` for segment buffers,
+  ``detect/batch._job_bucket`` for a wave's pair rows), whole, up
+  to what the scheduler's batch budgets can produce, and executes
+  the fused sieve and the resident interval kernel once a rung on
+  zero inputs — populating the in-process jit cache (the first
+  real request never traces) AND the persistent cache (the next
+  replica's boot never rebuilds).
 * a JSON **manifest** in the cache dir, keyed by
   ``sha256(jax version | backend | kind | shape | table hash)``,
   records which keyed shapes earlier boots compiled — the
@@ -41,21 +42,13 @@ import json
 import os
 import threading
 import time
-from typing import Iterable, Optional, Tuple
+from typing import Iterable
 
 from ..utils import get_logger
 
 log = get_logger("runtime.aot")
 
 MANIFEST_NAME = "trivy_tpu_aot_manifest.json"
-
-# default ladder rungs warmed at boot: the small end, where first
-# requests actually land (a cold fleet's first scans are small
-# batches; the big rungs amortize their own compile once traffic
-# exists to fill them)
-DEFAULT_PAIR_BUCKETS = (64, 128, 256)
-DEFAULT_SEG_BUCKETS = (256, 512)
-
 
 class CompileCacheMetrics:
     """Cumulative compile-cache counters, one singleton per
@@ -260,58 +253,91 @@ def _warm_call(fn, args, key: str, manifest: _Manifest,
     return dt
 
 
-def precompile_interval_shapes(
-        buckets: Iterable[int] = DEFAULT_PAIR_BUCKETS,
-        cache_dir: str = "") -> dict:
-    """Warm the classic interval kernel over the pair-row ladder
-    (``detect/batch._job_bucket`` rungs). Zero rows are inert
-    (flags=0 ⇒ not vulnerable), so execution is a no-op
-    semantically; the value is the populated jit + persistent
-    caches."""
+def sieve_rungs(max_batch_bytes: int, seg_len: int,
+                overlap: int) -> tuple:
+    """Every ``ops.keywords._bucket`` rung the segment buffer of one
+    scheduled batch can take. The coalescer closes a batch within
+    ``max_batch_bytes`` of candidates and gives a request over the
+    budget a batch of its own; rows are bytes over the segment step
+    plus one a file at the most, so twice the budget over the step
+    bounds a full batch of small files and one image of up to about
+    twice the budget alike. A larger image compiles its rung when it
+    comes, as before."""
+    from ..ops.keywords import _bucket
+    top = _bucket(2 * max_batch_bytes // (seg_len - overlap))
+    return _rungs(_bucket, top)
+
+
+def interval_rungs(max_batch_jobs: int) -> tuple:
+    """Every ``detect.batch._job_bucket`` rung one wave of a
+    scheduled batch can take: a batch holds ``max_batch_jobs`` at
+    the most and is launched in waves of ``_WAVE_ROWS``."""
+    from ..detect.batch import _WAVE_ROWS, _job_bucket
+    return _rungs(_job_bucket,
+                  _job_bucket(min(max_batch_jobs, _WAVE_ROWS)))
+
+
+def _rungs(bucket, top: int) -> tuple:
+    out, b = [], bucket(1)
+    while b <= top:
+        out.append(b)
+        b = bucket(b + 1)
+    return tuple(out)
+
+
+def precompile_interval_shapes(cdb, buckets: Iterable[int],
+                               cache_dir: str = "") -> dict:
+    """Warm the resident interval kernel, the one the scheduler's
+    waves and ``scan_boms`` run, over the pair-row ladder
+    (``detect/batch._job_bucket`` rungs) against ``cdb``'s resident
+    tables, staging them as a side effect. The gather operands are
+    uploaded as a wave uploads them, so the program's signature is
+    the wave's; zero rows gather the table's first row and the
+    result is dropped."""
+    import jax
     import numpy as np
 
-    from ..ops.intervals import MAX_INTERVALS, interval_hits
+    from ..ops.intervals import interval_hits_resident_donated
     manifest = _Manifest(cache_dir)
-    out = {"kernel": "interval", "shapes": [], "seconds": 0.0}
+    out = {"kernel": "interval_resident", "shapes": [],
+           "seconds": 0.0}
+    tables = cdb.device_tables()
+    sig = "x".join(str(d) for d in tables[0].shape)
     for p in sorted(set(int(b) for b in buckets if int(b) > 0)):
-        rank = np.zeros(p, np.int32)
-        iv = np.zeros((p, MAX_INTERVALS), np.int32)
-        flags = np.zeros(p, np.int32)
-        key = cache_key("interval", f"P{p}xM{MAX_INTERVALS}")
-        dt = _warm_call(interval_hits,
-                        (rank, iv, iv, iv, iv, flags),
-                        key, manifest,
-                        {"kernel": "interval", "P": p})
+        # the kernel donates its gather operands: fresh ones a rung
+        args = (jax.device_put(np.zeros(p, np.int32)),
+                jax.device_put(np.zeros(p, np.int32))) + tuple(tables)
+        key = cache_key("interval_resident", f"P{p}xT{sig}")
+        dt = _warm_call(interval_hits_resident_donated, args, key,
+                        manifest,
+                        {"kernel": "interval_resident", "P": p})
         out["shapes"].append(p)
         out["seconds"] += dt
     out["seconds"] = round(out["seconds"], 4)
     return out
 
 
-def precompile_dfa_shapes(table, run_specs: tuple = (),
-                          buckets: Iterable[int] =
-                          DEFAULT_SEG_BUCKETS,
-                          cache_dir: str = "",
-                          platform: str = "") -> dict:
-    """Warm the DFA fused sieve over the segment-buffer ladder
-    (``ops/keywords._bucket`` rungs × SEG_LEN columns), staging the
-    table's resident arrays as a side effect — exactly the prewarm
-    staging order a joining replica wants. Keyed on the table's
-    ``rules_hash`` so a custom rule set misses into its own
-    entries."""
+def precompile_dfa_shapes(scanner, buckets: Iterable[int],
+                          cache_dir: str = "") -> dict:
+    """Warm ``scanner``'s (a ``BatchSecretScanner``) fused sieve over
+    the segment-buffer ladder (``ops/keywords._bucket`` rungs × its
+    ``seg_len`` columns), staging the table's resident arrays as a
+    side effect — exactly the prewarm staging order a joining
+    replica wants. Keyed on the table's ``rules_hash`` so a custom
+    rule set misses into its own entries."""
     import jax
     import numpy as np
 
-    from ..secret.batch import SEG_LEN
-    platform = platform or jax.default_backend()
+    table, seg_len = scanner.table, scanner.seg_len
     manifest = _Manifest(cache_dir)
     out = {"kernel": "dfa_fused", "shapes": [], "seconds": 0.0}
     tbl = table.device_tables()
-    fn = table.fused_sieve(tuple(run_specs), platform)
+    fn = table.fused_sieve(tuple(scanner.plan.run_specs),
+                           jax.default_backend())
     for b in sorted(set(int(x) for x in buckets if int(x) > 0)):
         # the sieve donates its segment buffer; hand it a fresh one
-        seg = jax.device_put(np.zeros((b, SEG_LEN), np.uint8))
-        key = cache_key("dfa_fused", f"B{b}xL{SEG_LEN}",
+        seg = jax.device_put(np.zeros((b, seg_len), np.uint8))
+        key = cache_key("dfa_fused", f"B{b}xL{seg_len}",
                         table.rules_hash)
         dt = _warm_call(fn, (seg,) + tuple(tbl), key, manifest,
                         {"kernel": "dfa_fused", "B": b,
@@ -322,18 +348,31 @@ def precompile_dfa_shapes(table, run_specs: tuple = (),
     return out
 
 
-def boot_precompile(cache_dir: str = "",
-                    dfa_table=None,
-                    run_specs: tuple = (),
-                    pair_buckets: Optional[Tuple[int, ...]] = None,
-                    seg_buckets: Optional[Tuple[int, ...]] = None,
-                    ) -> dict:
-    """The boot-time glue the server calls once (``--compile-cache``):
-    place the persistent cache, then warm the interval and (when a
-    table is supplied) DFA ladders, the manifest beside the cache
-    entries. A cache directory that cannot be written costs compile
-    time, not the boot; a kernel that does not compile is a program
-    fault and propagates (``ops.program.DeviceProgramError``)."""
+def warm_ladders(secret_scanner=None, store=None, config=None,
+                 cache_dir: str = "") -> dict:
+    """Run every program a scheduled batch can meet once, on inert
+    inputs, before the first request: the fused sieve at every
+    :func:`sieve_rungs` rung of ``secret_scanner`` and the resident
+    interval kernel at every :func:`interval_rungs` rung against
+    ``store`` (a ``CompiledDB``, or a holder whose ``acquire`` gives
+    one), with ``config``'s (a ``SchedConfig``) batch budgets. Which
+    rungs a run meets depends on how requests fall into batches, so
+    the ladders are warmed whole: a fleet whose images are mostly
+    cached closes batches of one to several small images, and each
+    rung met cold would cost a compile in the middle of the scan.
+    Called where a scheduler is built for an accelerator
+    (``BatchScanRunner``, ``ScanServer``) and by ``server
+    --compile-cache DIR``. The persistent cache is placed first
+    (``cache_dir``, see :func:`configure_compile_cache`); a
+    directory that cannot be written costs compile time, not the
+    boot; a kernel that does not compile is a program fault and
+    propagates (``ops.program.DeviceProgramError``). A sharded sieve
+    (a scanner with a mesh) and the cpu-ref engine have no program
+    here and are left alone."""
+    from ..db import CompiledDB
+    if config is None:
+        from ..sched import SchedConfig
+        config = SchedConfig()
     t0 = time.monotonic()
     summary = {"cache_dir": cache_dir, "persistent": False,
                "kernels": []}
@@ -345,14 +384,29 @@ def boot_precompile(cache_dir: str = "",
         log.warning("persistent compile cache unavailable: %r", e)
         summary["error"] = repr(e)
         cache_dir = ""
-    summary["kernels"].append(precompile_interval_shapes(
-        pair_buckets or DEFAULT_PAIR_BUCKETS, cache_dir))
-    if dfa_table is not None:
+    if secret_scanner is not None \
+            and getattr(secret_scanner, "mesh", None) is None \
+            and getattr(secret_scanner, "backend", "") != "cpu-ref":
         summary["kernels"].append(precompile_dfa_shapes(
-            dfa_table, run_specs,
-            seg_buckets or DEFAULT_SEG_BUCKETS, cache_dir))
+            secret_scanner,
+            sieve_rungs(config.max_batch_bytes,
+                        secret_scanner.seg_len,
+                        secret_scanner.overlap),
+            cache_dir))
+    release = None
+    if hasattr(store, "acquire") and hasattr(store, "release"):
+        store, release = store.acquire(), store.release
+    try:
+        if isinstance(store, CompiledDB):
+            summary["kernels"].append(precompile_interval_shapes(
+                store, interval_rungs(config.max_batch_jobs),
+                cache_dir))
+    finally:
+        if release is not None:
+            release()
     summary["seconds"] = round(time.monotonic() - t0, 4)
-    log.info("boot precompile: %d kernels in %.2fs "
-             "(persistent=%s)", len(summary["kernels"]),
+    log.info("warm ladders: %s in %.2fs (persistent=%s)",
+             ", ".join(f"{k['kernel']} {k['shapes']}"
+                       for k in summary["kernels"]) or "nothing",
              summary["seconds"], summary["persistent"])
     return summary

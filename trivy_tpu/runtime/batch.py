@@ -144,6 +144,14 @@ class BatchScanRunner:
                 tracer=self.tracer)
             self._scheduler.fault_injector = self.fault_injector
             self._owns_scheduler = True
+            from .aot import warm_ladders
+            from .device import on_accelerator
+            if self.backend != "cpu-ref" and self.mesh is None \
+                    and on_accelerator():
+                # every shape a batch can take compiles now, or
+                # comes from the persistent cache, and not in the
+                # middle of the fleet
+                warm_ladders(self.secret_scanner, self.store, cfg)
         return self._scheduler
 
     def close(self) -> None:

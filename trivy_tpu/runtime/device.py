@@ -53,6 +53,15 @@ def device_identity() -> dict:
     return asdict(_resolved or DeviceInfo())
 
 
+def on_accelerator() -> bool:
+    """True in a process whose :func:`resolve_device` found another
+    platform than the CPU: where a new shape's compile costs seconds
+    (5.7 to 6.0 s a sieve rung on a TPU v5e, PERF.md) and not the
+    tens of milliseconds XLA:CPU takes. Never initialises a
+    backend."""
+    return (_resolved or DeviceInfo()).platform not in ("", "cpu")
+
+
 def _cpu_requested() -> bool:
     first = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
     return first.strip().lower() == "cpu"

@@ -136,6 +136,9 @@ class SchedMetrics:
             "submitted": 0, "completed": 0, "failed": 0,
             "rejected": 0, "rate_limited": 0, "timed_out": 0,
             "cancelled": 0, "batches": 0,
+            # requests that resolved with nothing dispatched for
+            # them: no secret candidate, every query a memo hit
+            "no_device_work": 0,
         }
         self.hist = {p: LatencyHistogram() for p in self.PHASES}
         # coalescer accounting
@@ -341,6 +344,13 @@ class SchedMetrics:
         # totals are what an operator watches on /metrics
         from ..guard.budget import GUARD_METRICS
         out["guard"] = GUARD_METRICS.snapshot()
+        # ingest counters (artifact/metrics.py): the streaming
+        # path's fetches and skips, layers the blob cache answered,
+        # layers and bytes analyzed instead, base layers left out of
+        # the secret scan, and the walker's layer_analyze row of the
+        # phase clock
+        from ..artifact.metrics import INGEST_METRICS
+        out["ingest"] = INGEST_METRICS.snapshot()
         # dispatch-path counters (docs/performance.md): job dedup,
         # constraint/purl cache hit rates, resident-DB upload
         # amortization — process-wide, like the guard totals

@@ -116,6 +116,9 @@ class ScanRequest:
         # in the cache — other requests sharing a layer blob wait on
         # it before their final secret merge
         self.patched_event = threading.Event()
+        # set by the scheduler when analyze left nothing for the
+        # device: the monotonic time its wait for a result began
+        self.no_device_since: Optional[float] = None
         self._done = threading.Event()
         self._result = None
         self._error: Optional[BaseException] = None

@@ -56,7 +56,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 from ..obs.cost import COST_LEDGER, parse_budget_config
-from ..obs.trace import get_tracer, trace_cause
+from ..obs.trace import book_phase, get_tracer, trace_cause
 from ..ops.program import DeviceProgramError
 from ..utils import get_logger
 from .coalescer import Batch, Coalescer, SchedConfig
@@ -409,6 +409,14 @@ class ScanScheduler:
         if req.set_result(result):
             latency = time.monotonic() - req.submitted_at
             self.metrics.inc("completed")
+            since = req.no_device_since
+            if since is not None:
+                # resolved with nothing dispatched: counted, and its
+                # wait from analyzed to resolved booked as a phase
+                # of its own (sched.phase.hit_wait)
+                self.metrics.inc("no_device_work")
+                book_phase("sched", "hit_wait",
+                           time.monotonic() - since)
             self.metrics.observe("request", latency,
                                  trace_id=req.trace_id or "")
             COST_LEDGER.charge(getattr(req, "tenant", "") or "",
@@ -483,18 +491,22 @@ class ScanScheduler:
     def _analyze(self, req: ScanRequest) -> None:
         t0 = self.metrics.host_begin()
         sp = self.tracer.child(req.span_root, "analyze")
+        no_device = False
         try:
             if not self._sweep(req):
                 with sp.activate():
                     req.work = req.analyze(req)
                 req.work.group = req.work.group or req.group
                 sp.end()
-                # the coalesce span opens BEFORE the request is
-                # published to the device thread, which closes it
-                # when the batch flushes
-                req.span_coalesce = self.tracer.child(
-                    req.span_root, "coalesce")
-                self.coalescer.add(req)
+                if req.work.candidates or req.work.jobs:
+                    # the coalesce span opens BEFORE the request is
+                    # published to the device thread, which closes
+                    # it when the batch flushes
+                    req.span_coalesce = self.tracer.child(
+                        req.span_root, "coalesce")
+                    self.coalescer.add(req)
+                else:
+                    no_device = True
             else:
                 sp.end("error")
         except Exception as e:       # noqa: BLE001
@@ -515,6 +527,17 @@ class ScanScheduler:
             with self._cv:
                 self._analyzing -= 1
                 self._cv.notify_all()
+        if no_device:
+            # a request whose layers the cache held and whose every
+            # query the memo answered brings nothing for the device:
+            # it rides no batch, waits for no flush timer, holds no
+            # ring slot and queues behind no other request's
+            # analysis: this worker goes straight on to its finish
+            # (which still waits for the secret patches of requests
+            # it shares a layer with)
+            req.no_device_since = time.monotonic()
+            req.patched_event.set()
+            self._finish(req, [], [])
 
     # --- stage 2: device executor ---
 
