@@ -5,9 +5,10 @@ Process-wide by design, like ``guard.budget.GUARD_METRICS``: the
 constraint-interval cache and the purl parse cache are process
 singletons, DB uploads happen once per (generation, mesh), and the
 numbers an operator watches on ``/metrics`` are the cumulative
-totals. Counter updates take one short lock; nothing here sits on a
-per-byte hot path (per-job costs are batched by the dispatchers
-before they land here).
+totals. Counter updates take no lock: the purl memo counts a lookup
+a component from eight pool threads (80,000 a pass of 2,000 SBOMs),
+and one shared lock there convoyed the whole decode
+(docs/performance.md "SBOM decode and the lock convoy").
 """
 
 from __future__ import annotations
@@ -16,7 +17,18 @@ import threading
 
 
 class DetectMetrics:
-    """Cumulative counters for the interval-dispatch hot path."""
+    """Cumulative counters for the interval-dispatch hot path.
+
+    Every thread adds into a cell of its own, keyed by its ident, and
+    ``snapshot`` folds the cells: a cell is written only by the one
+    live thread that has its ident (a later thread that is handed a
+    dead one's ident carries its cell on), so no add is lost and none
+    takes a lock. Rests on the interpreter lock making ``dict.get``,
+    ``dict.setdefault``, ``d[k] = v`` and ``dict.copy`` each atomic
+    for ``int`` and ``str`` keys. Totals are exact once the adding
+    threads are done; a snapshot taken while they run may be one add
+    a thread behind, and sees the two counters of a ``note_*`` pair
+    one after the other."""
 
     _KEYS = (
         # dispatch_jobs: jobs submitted vs unique after dedup
@@ -36,49 +48,51 @@ class DetectMetrics:
     )
 
     def __init__(self):
-        self._lock = threading.Lock()
-        self._c = {k: 0 for k in self._KEYS}
+        self._cells: dict = {}
 
     def inc(self, name: str, n: int = 1) -> None:
-        with self._lock:
-            # lint: disable=unbounded-label-cardinality -- counter
-            # names are code-literal call sites, never
-            # request-derived strings
-            self._c[name] = self._c.get(name, 0) + n
+        ident = threading.get_ident()
+        cell = self._cells.get(ident)
+        if cell is None:
+            cell = self._cells.setdefault(ident, {})
+        # lint: disable=unbounded-label-cardinality -- counter
+        # names are code-literal call sites, never
+        # request-derived strings
+        cell[name] = cell.get(name, 0) + n
 
     def note_dispatch(self, jobs_in: int, jobs_unique: int) -> None:
-        with self._lock:
-            self._c["jobs_in"] += jobs_in
-            self._c["jobs_unique"] += jobs_unique
+        self.inc("jobs_in", jobs_in)
+        self.inc("jobs_unique", jobs_unique)
 
     def note_wave(self, rows: int) -> None:
-        with self._lock:
-            self._c["device_waves"] += 1
-            self._c["device_rows"] += rows
+        self.inc("device_waves")
+        self.inc("device_rows", rows)
 
     def note_db_upload(self, nbytes: int) -> None:
-        with self._lock:
-            self._c["db_uploads"] += 1
-            self._c["db_upload_bytes"] += nbytes
+        self.inc("db_uploads")
+        self.inc("db_upload_bytes", nbytes)
 
     def reset(self) -> None:
         """Test hook — production code never calls this."""
-        with self._lock:
-            for k in self._c:
-                self._c[k] = 0
+        self._cells.clear()
 
     def snapshot(self) -> dict:
-        with self._lock:
-            out = dict(self._c)
+        out = dict.fromkeys(self._KEYS, 0)
+        for cell in list(self._cells.values()):
+            for k, v in cell.copy().items():
+                out[k] = out.get(k, 0) + v
         jobs_in = out["jobs_in"]
         out["dedup_ratio"] = round(
             1.0 - out["jobs_unique"] / jobs_in, 4) if jobs_in else 0.0
-        ic = out["interval_cache_hits"] + out["interval_cache_misses"]
-        out["interval_cache_hit_rate"] = round(
-            out["interval_cache_hits"] / ic, 4) if ic else 0.0
-        pc = out["purl_cache_hits"] + out["purl_cache_misses"]
-        out["purl_cache_hit_rate"] = round(
-            out["purl_cache_hits"] / pc, 4) if pc else 0.0
+        # how often each memo engages, and how often it pays: a hit
+        # share near 0 under some traffic says the memo is pure cost
+        # there (detect/ccache.py)
+        for memo in ("interval_cache", "purl_cache"):
+            hits = out[f"{memo}_hits"]
+            lookups = hits + out[f"{memo}_misses"]
+            out[f"{memo}_lookups"] = lookups
+            out[f"{memo}_hit_rate"] = round(
+                hits / lookups, 4) if lookups else 0.0
         out["upload_amortization"] = round(
             out["resident_dispatches"] / out["db_uploads"], 2) \
             if out["db_uploads"] else 0.0

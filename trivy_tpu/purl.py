@@ -190,18 +190,18 @@ def _unq(x: str) -> str:
     return unquote(x) if "%" in x else x
 
 
-_parse_lru = None
+_parse_memo = None
 
 
 def _parse_cache():
     """Lazy so the module import stays light (purl is imported by
     the types layer; detect.ccache pulls in the metrics module)."""
-    global _parse_lru
-    if _parse_lru is None:
-        from .detect.ccache import KeyedLRU
-        _parse_lru = KeyedLRU(65536, "purl_cache_hits",
-                              "purl_cache_misses")
-    return _parse_lru
+    global _parse_memo
+    if _parse_memo is None:
+        from .detect.ccache import KeyedMemo
+        _parse_memo = KeyedMemo(65536, "purl_cache_hits",
+                                "purl_cache_misses")
+    return _parse_memo
 
 
 def from_string(s: str) -> PackageURL:
@@ -213,7 +213,7 @@ def from_string(s: str) -> PackageURL:
     scale (docs/performance.md). Callers MUTATE the returned object
     (``file_path``, qualifier lists), so every call hands out a
     fresh shallow copy, never the cached instance. Parse errors are
-    cached too and re-raised fresh (detect.ccache.KeyedLRU)."""
+    cached too and re-raised fresh (detect.ccache.KeyedMemo)."""
     p = _parse_cache().lookup(s, _from_string_uncached)
     return PackageURL(
         type=p.type, namespace=p.namespace, name=p.name,

@@ -909,7 +909,9 @@ StreamingImageSource`: layer blobs decompress into the scan as they
         # spare cores in ≥64-doc slabs — per-doc tasks made pool
         # dispatch overhead the visible cost in the hostpool stats —
         # and repeated purl strings short-circuit in the purl parse
-        # cache (docs/performance.md). A malformed document still
+        # memo, which takes no lock: eight slabs at once ask it
+        # 80,000 times a pass (docs/performance.md "SBOM decode and
+        # the lock convoy"). A malformed document still
         # fails only its own slot. Each slab books a decode_task on
         # its pool thread: cpu_s over busy_s there is how much of a
         # task's wall the thread really ran.

@@ -17,6 +17,15 @@ resolves, so routing a pack task there while the device thread
 blocks on its future could deadlock the pipeline. Tasks here are
 pure compute with no cross-task waits, so the pool can be saturated
 safely from any thread.
+
+The pool's threads share one interpreter, so they overlap only what
+lets go of it (hashing, numpy copies), and what a task touches once
+an ITEM must take no lock of its own: the purl parse memo did,
+80,000 times a pass of 2,000 SBOMs, and eight decode tasks convoyed
+on it for two thirds of the pass (docs/performance.md "SBOM decode
+and the lock convoy"). Without that lock the SBOM decode neither
+gains nor loses by the pool in a CPU rehearsal (PERF.md section 7
+keeps the question).
 """
 
 from __future__ import annotations
