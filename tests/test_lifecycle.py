@@ -432,7 +432,8 @@ class TestSimLifecycle:
         try:
             LIFECYCLE_METRICS.reset()
             router = ScanRouter([(s.name, s.url) for s in sims])
-            for d in _keys(60, "books"):
+            keys = _keys(60, "books")
+            for d in keys:
                 assert _route_scan(router, d)[0] == 200
             router.mark_draining("rh2")
             summary = run_handoff(router, "rh2")
@@ -446,6 +447,15 @@ class TestSimLifecycle:
             assert snap["handoff_published"] \
                 == snap["handoff_prefetched"] \
                 + snap["handoff_abandoned"]
+            # the working set moved with the keys: once the victim
+            # leaves the ring, every digest of the warmed set is a
+            # memo hit on its new owner
+            router.remove_replica("rh2")
+            for d in keys:
+                status, doc = _route_scan(router, d)
+                assert status == 200
+                assert doc["replica"] != "rh2"
+                assert doc["memo_hit"] is True, d
         finally:
             LIFECYCLE_METRICS.reset()
             for s in sims:

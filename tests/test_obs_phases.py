@@ -140,7 +140,7 @@ def test_sharded_sieve_busy_span_at_join():
 
 def test_disabled_tracer_records_nothing(tmp_path):
     """Without an active span phase_span opens no tracer span — the
-    untraced arm stays untraced (the obs bench's differential); the
+    untraced arm stays untraced (what ``--trace 0`` compares with); the
     phase clock books its rows all the same (below)."""
     from trivy_tpu.obs import Tracer
     from trivy_tpu.runtime import BatchScanRunner

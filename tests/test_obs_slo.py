@@ -13,6 +13,7 @@ import pytest
 
 from trivy_tpu.obs.slo import (SLO, SloEngine, default_slos,
                                parse_slo_config)
+from trivy_tpu.utils.synth import tiny_fleet
 
 pytestmark = pytest.mark.obs
 
@@ -198,18 +199,9 @@ class TestTripDumps:
         assert ts and all(0 <= t < 60e6 for t in ts), ts
 
 
-def _fleet(tmp_path, n):
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import make_fleet, make_store
-    return make_fleet(str(tmp_path), n), make_store()
-
-
 class TestSchedulerWiring:
     def test_deadline_storm_trips_fast_window_and_dumps(
-            self, tmp_path):
+            self, tmp_path, larger_fleet):
         """The acceptance drill end-to-end: a deadline storm mass-
         expires scheduled requests; the fast burn window trips,
         GET /slo reports the violation with exemplar trace ids, and
@@ -222,7 +214,7 @@ class TestSchedulerWiring:
         from trivy_tpu.sched import SchedConfig
         from trivy_tpu.types import ScanOptions
 
-        paths, store = _fleet(tmp_path, 4)
+        paths, store = larger_fleet(4)
         tracer = Tracer(recorder=FlightRecorder())
         tracer.recorder.dump_dir = str(tmp_path / "dumps")
         runner = BatchScanRunner(store=store, backend="cpu-ref",
@@ -272,7 +264,7 @@ class TestSchedulerWiring:
         from trivy_tpu.runtime import BatchScanRunner
         from trivy_tpu.sched import SchedConfig
 
-        paths, store = _fleet(tmp_path, 3)
+        paths, store = tiny_fleet(str(tmp_path), 3)
         runner = BatchScanRunner(store=store, backend="cpu-ref",
                                  sched=SchedConfig(workers=2))
         try:

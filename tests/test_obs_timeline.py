@@ -9,7 +9,6 @@ tree-wide now, not just ``obs/`` (tests/test_analysis.py)."""
 
 from __future__ import annotations
 
-import os
 from collections import namedtuple
 
 import numpy as np
@@ -324,22 +323,14 @@ class TestClockDiscipline:
     # sched/, watch/, memo/ now carry the same discipline obs/ did.
 
 
-def _fleet(tmp_path, n):
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import make_fleet, make_store
-    return make_fleet(str(tmp_path), n), make_store()
-
-
 class TestEndToEnd:
     @pytest.mark.parametrize("sched", ["on", "off"])
-    def test_fleet_reconstruction(self, tmp_path, sched):
+    def test_fleet_reconstruction(self, larger_fleet, sched):
         from trivy_tpu.obs import FlightRecorder, Tracer
         from trivy_tpu.runtime import BatchScanRunner
         from trivy_tpu.sched import SchedConfig
 
-        paths, store = _fleet(tmp_path, 6)
+        paths, store = larger_fleet(6)
         tracer = Tracer(recorder=FlightRecorder(capacity=64))
         kw = {"sched": SchedConfig(workers=2)} if sched == "on" \
             else {}

@@ -3,7 +3,7 @@ that the hot dispatch path keeps its shape — job dedup folds
 duplicates, bulk segment packing is byte-identical to the naive
 packer, pair rows pad to the bucket ladder, the constraint/purl
 caches hit, and the balanced shard layout stays sound. A regression
-here fails tests immediately instead of waiting for a bench run
+here fails tier-1 at once, before any chip run could show it
 (docs/performance.md)."""
 
 import numpy as np
@@ -381,8 +381,8 @@ def test_db_generation_and_invalidation():
 
 
 def test_sched_off_stats_carry_dedup(tmp_path):
-    """The direct image path reports per-batch dedup numbers (the
-    bench writes them into the BENCH json)."""
+    """The direct image path reports per-batch dedup numbers in
+    ``last_stats``."""
     from trivy_tpu.runtime import BatchScanRunner
     from trivy_tpu.utils.synth import tiny_fleet
     paths, store = tiny_fleet(str(tmp_path), n_images=2)

@@ -457,8 +457,8 @@ class TestSBOMScan:
 
 
 class TestBatchSBOMScan:
-    """BatchScanRunner.scan_boms — the fleet path bench config #4
-    rides (one interval dispatch for N SBOMs)."""
+    """BatchScanRunner.scan_boms — the fleet path the benchmark's
+    ``sbom-batch`` cell rides (one interval dispatch for N SBOMs)."""
 
     def _store(self, tmp_path):
         from trivy_tpu.db import AdvisoryStore, load_fixtures

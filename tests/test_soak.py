@@ -421,12 +421,15 @@ def _tiny_scenario():
         )))
 
 
+def _run_tiny():
+    return run_soak(_tiny_scenario(), replicas=2, epoch_s=0.3,
+                    service_ms=2.0, slo_availability=0.995)
+
+
 class TestSoakRunnerE2E:
     @pytest.fixture(scope="class")
     def report(self):
-        return run_soak(_tiny_scenario(), replicas=2,
-                        epoch_s=0.3, service_ms=2.0,
-                        slo_availability=0.995)
+        return _run_tiny()
 
     def test_books_balance_fleet_wide(self, report):
         st = report["stable"]
@@ -501,6 +504,11 @@ class TestSoakRunnerE2E:
         assert {"rss_bytes", "threads", "watch_backlog",
                 "cursor_ack_window"} <= gated
         assert not audit["series"]["registry_index"]["gated"]
+        assert report["stable"]["audit_ok"], audit
+
+    def test_same_seed_run_is_byte_identical_in_the_stable_slice(
+            self, report):
+        assert stable_view(_run_tiny()) == stable_view(report)
 
     def test_cli_parser_accepts_soak(self):
         from trivy_tpu.cli import build_parser

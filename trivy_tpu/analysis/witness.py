@@ -103,8 +103,8 @@ class OrderGraph:
 
 class LockWitness:
     """The process-wide recorder: per-thread held stacks, the site
-    graph, and the acquisition counters the bench overhead gate
-    multiplies out."""
+    graph, and the acquisition counters that say how much
+    traffic the witness saw."""
 
     EXCLUDE_MODULES = ("trivy_tpu.obs.profiler",)
     PREFIXES = ("trivy_tpu",)
@@ -119,7 +119,7 @@ class LockWitness:
         # plain (GIL-approximate) counters: the acquire fast path
         # must not serialize every wrapped lock in the process on
         # one global lock — under-counting a storm by a few is
-        # fine, a 20% contention tax is not (bench-gated <2%)
+        # fine, a contention tax on every lock is not
         self.acquisitions = 0
         self.nested = 0
         self.wrapped = 0
@@ -221,7 +221,7 @@ class _WitnessLock:
             # hot path: threading.local's per-thread __dict__ is
             # one lookup instead of getattr+setattr descriptor
             # round-trips (this wrapper rides every lock in the
-            # witnessed process — bench-gated <2% attributed)
+            # witnessed process)
             d = self._local.__dict__
             n = d.get("n", 0)
             d["n"] = n + 1

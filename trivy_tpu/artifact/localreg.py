@@ -1,5 +1,5 @@
-"""Loopback OCI registry serving docker-save tars — the bench's and
-test suite's registry leg.
+"""Loopback OCI registry serving docker-save tars — the test
+suite's registry leg.
 
 The streaming-ingest pipeline (docs/performance.md §9) needs a real
 HTTP registry to pull from: chunked blob bodies, ``Range`` resume
@@ -16,10 +16,10 @@ converts docker-save tarballs into Distribution-API content —
 * a schema-2 image manifest references both, served under the tag
   and under its own sha256 digest.
 
-Serving knobs drive the bench arms: ``range_support=False`` makes
+Serving knobs drive the test cases: ``range_support=False`` makes
 the registry reject resume (the client must fall back to an offset-0
-rewrite), and ``throttle_bps`` caps per-response bandwidth so the
-cold-pull arm has a network wall worth hiding host work behind.
+rewrite), and ``throttle_bps`` caps per-response bandwidth so a
+cold pull has a network wall worth hiding host work behind.
 Counters (``blob_gets``, ``bytes_served``, ``range_requests``) give
 tests an exact zero-GET assertion for the warm-layer skip.
 """

@@ -38,8 +38,8 @@ def collect_layer_tar(tf: tarfile.TarFile,
     opq_dirs: list = []
     wh_files: list = []
     # hot-loop setup: hoist the limits and keep the per-entry guard
-    # cost to an increment plus gated (mostly-false) cheap checks —
-    # measured <2% on a clean fleet vs --no-ingest-guards
+    # cost to an increment plus gated (mostly-false) cheap checks
+    # (pytest -m hostile holds clean slots byte-identical either way)
     lim = budget.limits if budget is not None else None
     max_file = lim.max_file_bytes if lim is not None else 0
     # every path component costs ≥2 name bytes ("a/"), so a name

@@ -614,7 +614,7 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=["off", "sim", "subprocess"],
                     help="SLO-driven autoscaler: 'subprocess' "
                     "spawns sim replicas as child processes "
-                    "(bench/demo); production wires its own "
+                    "(tests/demo); production wires its own "
                     "ReplicaController")
     rt.add_argument("--scaler-min", type=int, default=1)
     rt.add_argument("--scaler-max", type=int, default=8)
@@ -785,7 +785,7 @@ def _profiled(profile_dir: str, max_seconds: float = 0.0,
     tracing row) plus the host profiler's collapsed stacks
     (host_profile.folded). The trace opens in TensorBoard/Perfetto;
     phase-level host/device timings live in
-    BatchScanRunner.last_stats and the bench JSON. The single
+    BatchScanRunner.last_stats and the metrics snapshots. The single
     jax-trace wrapper lives in obs.profiler.device_trace; a process
     that owns no device (``device=False``: thin clients, cpu-ref)
     writes the host profile only."""

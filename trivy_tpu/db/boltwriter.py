@@ -1,9 +1,9 @@
-"""Minimal BoltDB file writer — fixture/bench generator.
+"""Minimal BoltDB file writer — fixture generator.
 
 Produces structurally valid bbolt files (meta pages, leaf/branch
 pages, inline buckets, overflow pages) so the pure-Python reader
-(boltdb.py) and the advisory-ingest path can be exercised and
-benchmarked without a Go toolchain. This is a fixture generator, not
+(boltdb.py) and the advisory-ingest path can be exercised
+without a Go toolchain. This is a fixture generator, not
 a database: no freelist management, no transactions, write-once.
 """
 

@@ -305,7 +305,7 @@ class ResidentTables:
         self._note_invalidation()
 
     def device_stats(self) -> dict:
-        """Upload-amortization numbers for bench/metrics: how many
+        """Upload-amortization numbers for metrics: how many
         dispatches each HBM upload served."""
         with self._device_lock:
             out = dict(self._device_stats)

@@ -111,7 +111,7 @@ class _RankSpace:
 
 
 # device-kernel wall time of the most recent dispatch_jobs call,
-# for the host/device split in bench + tracing. Callers that
+# for the host/device split in stats + tracing. Callers that
 # dispatch from several threads (the sched device executor) pass
 # their own ``stats`` sink instead of sharing this module global.
 last_dispatch_stats: dict = {"device_s": 0.0, "dispatch_s": 0.0}

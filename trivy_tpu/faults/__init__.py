@@ -7,8 +7,8 @@ domains consult). It exists to exercise the robustness machinery it
 ships next to — the circuit-broken cache fallback
 (``artifact/resilient.py``), poison-image quarantine in the
 scheduler, degraded-mode reports, idempotent RPC retries, graceful
-drain — under reproducible failure, from pytest (``-m faults``), the
-CLI (``--fault-spec``), and the bench (``faults`` config).
+drain — under reproducible failure, from pytest (``-m faults``) and
+the CLI (``--fault-spec``).
 """
 
 from .hostile import (BUILDERS as HOSTILE_BUILDERS, EXPECTED_STATUS,

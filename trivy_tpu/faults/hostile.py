@@ -45,7 +45,7 @@ proportionally so tests stay fast.
 Wired into ``--fault-spec`` (scenario ``hostile-ingest``, or any
 spec carrying ``hostile=<builder;builder;...>``): the multi-target
 image path appends the materialized corpus to the scanned fleet —
-the bench's mixed clean+hostile configuration. In pytest, the
+a mixed clean+hostile run. In pytest, the
 ``hostile_corpus`` fixture (tests/conftest.py) builds the same
 corpus into a tmp dir.
 

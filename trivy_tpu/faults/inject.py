@@ -252,7 +252,7 @@ class FaultInjector:
     def replica_kill_due(self, forwards: int) -> bool:
         """replica-kill scenario: True exactly once, the first time
         the router's forward count reaches the seeded instant — the
-        HARNESS (bench kill arm, tests) then kills the victim
+        HARNESS (the soak's kill step, tests) then kills the victim
         replica's process; the spec only carries when."""
         spec = self.spec
         if not spec.replica_kill_after:

@@ -3,11 +3,10 @@
 A :class:`FaultSpec` is the declarative half of the fault layer — a
 seeded description of which failure domains misbehave and how hard.
 Scenarios are the named presets the docs (docs/robustness.md), the
-``--fault-spec`` CLI flag, the bench ``faults`` config, and the
-pytest fixture all share, so "cache-outage" means the same thing in
-a unit test and in a bench run. Every stochastic decision draws from
-one seeded RNG: the same spec against the same workload injects the
-same faults.
+``--fault-spec`` CLI flag and the pytest fixture share, so
+"cache-outage" means the same thing in a unit test and in a CLI
+run. Every stochastic decision draws from one seeded RNG: the same
+spec against the same workload injects the same faults.
 
 Spec strings::
 
@@ -102,8 +101,8 @@ class FaultSpec:
 
     # -- router fleet (docs/serving.md "Scan router & autoscaling"):
     #    replica_kill_after kills a backend replica mid-storm after
-    #    the router has forwarded N requests (the harness — bench
-    #    kill arm, tests — does the killing; the spec carries the
+    #    the router has forwarded N requests (the harness — the soak's
+    #    kill step, tests — does the killing; the spec carries the
     #    seeded instant, and replica_kill optionally names the
     #    victim, else the harness picks the busiest).
     #    replica_flaky_every drops every Nth forwarded response at
@@ -117,7 +116,7 @@ class FaultSpec:
 
     # -- tenant flood (docs/serving.md "Multi-tenant QoS"): like
     #    deadline-storm, the spec only carries the storm's shape —
-    #    the harness (bench.py adversarial-tenant arm, tests) runs
+    #    the harness runs
     #    an open-loop submitter AS this tenant at this rate while
     #    compliant tenants keep their normal traffic; the tenancy
     #    layer must shed the flood as 429s while compliant p99 holds
@@ -153,7 +152,7 @@ class FaultSpec:
         return bool(self.blob_drop_first)
 
 
-# Named presets. ``standard-outage`` is the bench/acceptance scenario:
+# Named presets. ``standard-outage`` is the acceptance scenario:
 # a cache outage long enough to trip the breaker and recover, one
 # poisoned image per 64 (callers name it via poison=...), and one
 # transient device error.

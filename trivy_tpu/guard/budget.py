@@ -61,7 +61,7 @@ class ResourceLimits:
     ingest_deadline_s: float = 300.0           # per-stage wall clock
 
     def scaled(self, scale: float) -> "ResourceLimits":
-        """Proportionally smaller limits (tests/bench use miniature
+        """Proportionally smaller limits (tests use miniature
         corpora; deadline and ratio are kept as-is)."""
         return replace(
             self,
@@ -155,8 +155,8 @@ class ResourceBudget:
 
     # global-metrics flush batching: the per-entry counters would
     # otherwise take the process-wide metrics lock once per tar
-    # entry across every worker thread — measured ~8% on a clean
-    # ingest-only fleet, vs <1% with batched flushes
+    # entry across every worker thread; batched, they take it once
+    # per _FLUSH_ENTRIES entries or _FLUSH_BYTES bytes
     _FLUSH_ENTRIES = 64
     _FLUSH_BYTES = 4 << 20
 

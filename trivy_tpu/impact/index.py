@@ -131,9 +131,9 @@ class ImpactIndex:
     """One replica's slice of the fleet-wide inverted index.
 
     All state lives under one re-entrant lock; maintenance calls are
-    O(entry postings) — they ride the scan/finish path, so the <2%
-    overhead budget (bench ``--config impact``) is the design
-    constraint, not an afterthought."""
+    O(entry postings) — they ride the scan/finish path, so staying a small
+    share of a warm scan (``maintenance_s`` in IMPACT_METRICS) is
+    the design constraint, not an afterthought."""
 
     def __init__(self, store=None, owns=None, name: str = "",
                  pusher=None):

@@ -4,7 +4,7 @@ push re-scans").
 Process-wide singleton like ``memo.metrics.MEMO_METRICS``: one
 impact index serves every scanner in a replica, and the numbers an
 operator watches (update/query/rebuild counters, cumulative
-maintenance wall time for the <2% write-through overhead budget) are
+maintenance wall time, the write-through overhead's numerator) are
 totals on ``GET /metrics`` — JSON and Prometheus text alike.
 """
 

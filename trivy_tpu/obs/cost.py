@@ -12,8 +12,7 @@ across the batch's requests proportionally to each request's work
 volume (candidate bytes + interval jobs), so the books BALANCE by
 construction: the per-tenant attributed device-seconds sum to the
 scheduler's measured per-dispatch device-time integral (an identity
-the ``pytest -m cost`` suite and the ``bench.py cost`` arm assert
-within ±2%).
+the ``pytest -m cost`` suite asserts within ±2%).
 
 The ledger keeps two books under one lock:
 
@@ -207,8 +206,7 @@ class CostLedger:
     """Per-tenant resource-vector books; every method thread-safe.
 
     ``enabled=False`` turns every ``charge`` into an immediate
-    return — the ``bench.py cost`` arm measures metering overhead
-    as the ips delta between the two settings."""
+    return, so an unmetered run books nothing."""
 
     def __init__(self, max_tenants: int = MAX_COST_TENANTS,
                  clock=time.monotonic):
@@ -221,7 +219,7 @@ class CostLedger:
         self.charges = 0            # charge() calls booked
 
     def reset(self) -> None:
-        """Fresh books (tests and the bench's per-arm isolation)."""
+        """Fresh books (tests' per-case isolation)."""
         with self._lock:
             self._cum.clear()
             self._ring.clear()

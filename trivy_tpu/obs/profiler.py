@@ -13,10 +13,9 @@ seconds" from a server that never had profiling "switched on".
 Overhead is bounded three ways and *measured*: the sampler skips its
 own thread, distinct-stack cardinality folds into ``<overflow>``
 past ``max_stacks`` per bucket, and the cumulative sampling CPU time
-is tracked in ``stats()["overhead_s"]`` — the ``timeline`` bench
-config gates attributed profiler+timeline overhead under 2% of
-fleet wall, and asserts findings stay byte-identical with the
-profiler on vs off.
+is tracked in ``stats()["overhead_s"]``, so a run can attribute
+the profiler's share of its own wall (``pytest -m obs``,
+tests/test_obs_profiler.py).
 
 The optional **device** trace rides :func:`device_trace`: an opt-in
 ``jax.profiler`` hook behind ``--profile-out DIR`` (the host

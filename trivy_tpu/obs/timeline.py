@@ -1,13 +1,11 @@
 """Per-device busy/idle timeline reconstruction with typed idle
 attribution (docs/observability.md "Idle attribution").
 
-ROADMAP item 4 (true async runtime) is gated on ``dispatch/device
-<= 1`` — but the raw phase totals (``interval_dispatch_s`` 2x
-``interval_device_s`` on the 512-image bench) say only THAT the
-device idles, not WHY. This module rebuilds the device's busy/idle
-timeline from the span trees the tracer already records and
-attributes **every** idle instant to a typed cause, so the
-async-runtime refactor lands against a measured baseline:
+The raw phase totals (``interval_dispatch_s`` against
+``interval_device_s``) say only THAT the device idles, not WHY.
+This module rebuilds the device's busy/idle timeline from the span
+trees the tracer already records and attributes **every** idle
+instant to a typed cause:
 
 ==================  =================================================
 cause               the device was idle because ...
@@ -48,8 +46,8 @@ cause               the device was idle because ...
 ``queue_empty``        no request was open at all — the scanner was
                        genuinely idle
 ``unknown``            a request was open but nothing tracked was
-                       running (the honesty bucket; the bench gates
-                       it below 5% of idle)
+                       running (the honesty bucket; ``pytest -m
+                       obs`` bounds it on a small fleet)
 ==================  =================================================
 
 Causes can overlap (the host packs batch N+1 while requests queue);
@@ -170,8 +168,8 @@ class Timeline:
     """One reconstruction over a list of finished spans.
 
     ``attribute()`` returns the partitioned idle breakdown;
-    ``report()`` the JSON-able summary the bench and ``/metrics``
-    carry. The input spans only need ``name``, ``start_mono``,
+    ``report()`` the JSON-able summary ``/metrics`` carries. The
+    input spans only need ``name``, ``start_mono``,
     ``end_mono`` and ``attrs`` — a real ``obs.trace.Span``, or any
     duck-typed stand-in (the property tests use a namedtuple)."""
 
@@ -315,10 +313,11 @@ class Timeline:
             out, key=lambda b: (b is None, b))]
 
     def report(self, per_batch: bool = False) -> dict:
-        """The JSON-able breakdown BENCH json and ``/metrics``
-        carry. ``coverage`` is the share of idle wall attributed to
-        a KNOWN cause (1 - unknown/idle); the bench gates it at
-        >= 95% so the taxonomy cannot silently rot."""
+        """The JSON-able breakdown ``/metrics`` carries.
+        ``coverage`` is the share of idle wall attributed to a
+        KNOWN cause (1 - unknown/idle); tests/test_obs_timeline.py
+        holds a floor under it so the taxonomy cannot silently
+        rot."""
         attr = self.attribute()
         idle = self.idle_s
         out = {
@@ -346,7 +345,7 @@ class Timeline:
 
 def from_recorder(recorder, window=None) -> Timeline:
     """Timeline over every span in the flight-recorder ring — the
-    fleet-run entry the bench uses (a fleet's traces all complete
+    fleet-run entry (a fleet's traces all complete
     into the ring; size the ring to the fleet)."""
     spans = [s for _, trace in recorder.traces() for s in trace]
     return Timeline(spans, window=window)

@@ -19,8 +19,8 @@ A :class:`Tracer` is one tracing domain. The module-level default
 (:func:`get_tracer`) is what the scheduler, the batch runner and the
 RPC server share unless a test injects its own; disabling a tracer
 (``Tracer(enabled=False)``) turns every ``start_span`` into a shared
-no-op span, which is the differential arm the ``obs`` bench measures
-overhead against.
+no-op span: the untraced arm that ``--trace 0`` of the benchmark
+and ``pytest -m obs`` compare a traced run with.
 
 Everything here is import-light on purpose: no trivy_tpu imports at
 module scope, so the logging layer and the guard/fault seams can

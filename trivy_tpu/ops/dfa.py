@@ -62,7 +62,7 @@ from jax import lax
 # slot-reuse contract); the uint16 mask output cannot alias the
 # uint8 segment input, so XLA's "Some donated buffers were not
 # usable" aliasing advisory is expected. Filtered at the
-# application level (cli/bench/pytest.ini), never here — see
+# application level (cli/benchmark/pytest.ini), never here — see
 # ops/intervals.py.
 
 from ..db.compiled import ResidentTables

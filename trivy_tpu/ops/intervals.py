@@ -30,7 +30,7 @@ from .program import DeviceProgram
 # contract of the async runtime). The hit output is bool while the
 # payloads are int32, so XLA can never ALIAS input to output and
 # emits its "Some donated buffers were not usable" advisory — that
-# advisory is expected here, not a bug. The CLI/bench entry points
+# advisory is expected here, not a bug. The CLI/benchmark entry points
 # (and pytest.ini) filter it at the APPLICATION level; this library
 # module deliberately does not mutate the process-global warning
 # filters, so embedders keep the signal for their own jax code.

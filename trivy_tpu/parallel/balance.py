@@ -4,11 +4,11 @@ The mesh's data axis splits the leading batch dimension into equal
 CONTIGUOUS chunks, so whatever order the host packs rows in IS the
 device assignment. Packing files in arrival order lets one fat image
 pile its segments into a single chunk while the tail chunks carry
-mostly padding — the per-device occupancy skew the round-5 mesh
+mostly padding — the per-device occupancy skew an early mesh
 curve surfaced. This module assigns items to shards by measured byte
 volume (greedy LPT: heaviest item to the lightest shard) so every
 chunk carries near-equal real work, and reports the per-shard
-occupancy the metrics/bench layers surface.
+occupancy the metrics layer surfaces.
 """
 
 from __future__ import annotations

@@ -33,9 +33,8 @@ The contract has three pieces, each testable without TPU hardware:
 CI cannot reach a pod, so the contract ships with a multi-process
 *simulation* mode (``trivy_tpu/parallel/simhost.py``): N spawned
 subprocesses on the CPU backend, each believing it is process k of
-P, each scanning exactly its layout slice — the bench's mesh config
-and ``pytest -m async_rt`` gate layout parity and findings
-byte-identity through it.
+P, each scanning exactly its layout slice — ``pytest -m async_rt``
+gates layout parity and findings byte-identity through it.
 """
 
 from __future__ import annotations
@@ -171,7 +170,7 @@ def host_shard_layout(volumes: list, num_processes: int) -> list:
     deterministic: every host derives the identical global layout
     from the shared fleet spec, which is what makes "no coordinator
     traffic per item" safe. Layout parity across processes is gated
-    by the mesh bench's multi-process sim arm."""
+    by ``pytest -m async_rt``'s two simulated hosts."""
     from .balance import balance_by_volume
     return balance_by_volume([int(v) for v in volumes],
                              max(1, int(num_processes)))

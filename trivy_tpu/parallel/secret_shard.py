@@ -4,8 +4,7 @@ The round-5 sieve built ONE global segment buffer on the host thread,
 dispatched one mesh-wide ``shard_map`` kernel, and decoded the whole
 mask array serially — so ``secret_batch_s`` was host-bound and GREW
 with device count (every added shard added padding, packing and
-decode to the same host thread; BENCH_r05: 0.392 s @ 1 device →
-0.574 s @ 8).
+decode to the same host thread).
 
 This module replaces that with an async sharded submission:
 

@@ -7,8 +7,8 @@ the global LPT shard layout exactly like a real pod process would
 (:func:`trivy_tpu.parallel.multihost.host_shard_layout` — a pure
 function of the fleet, so no coordination traffic), scans only the
 slice it owns on a process-local CPU mesh, and writes its layout +
-normalized reports. The parent (bench mesh arm, ``pytest -m
-async_rt``) spawns P of these with ``TRIVY_TPU_PROCESS_ID=0..P-1``
+normalized reports. The parent (``pytest -m async_rt``, ``pytest -m
+fleetobs``) spawns P of these with ``TRIVY_TPU_PROCESS_ID=0..P-1``
 and gates two invariants the real pod depends on:
 
 * **layout parity** — every process reports the identical global

@@ -14,7 +14,7 @@ consumes the federated ``fleet.slo_ok`` burn-rate verdicts.
 """
 
 # Lazy exports (PEP 562): ``python -m trivy_tpu.router.sim`` — the
-# subprocess replica the controllers and bench spawn per fleet
+# subprocess replica the controllers spawn per fleet
 # member — must execute this package __init__ without paying for the
 # rpc/server import chain that core.py needs. Attribute access from
 # normal code resolves identically.

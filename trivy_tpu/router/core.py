@@ -8,7 +8,7 @@ digest (``blob_ids[0]`` — the base layer, the most widely shared blob
 replica whose memo/cache tier is already warm for them), with the
 bounded-load spill keeping a hot digest from melting one shard.
 
-Failure semantics (the robustness contract, bench-gated):
+Failure semantics (the robustness contract, ``pytest -m router``):
 
 * a connection failure or lost response mid-request records a
   breaker failure and REPLAYS the identical raw body — same
@@ -25,7 +25,7 @@ Failure semantics (the robustness contract, bench-gated):
   (the per-tenant 429 must land on the offending tenant, not turn
   into a router retry storm);
 * every ACCEPTED request is booked into exactly one terminal outcome
-  counter — the books-balance invariant the kill-mid-storm bench
+  counter — the books-balance invariant the kill-mid-storm test
   asserts.
 
 Health is an overlay on membership: the ring only changes on

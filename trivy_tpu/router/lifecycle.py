@@ -1,12 +1,12 @@
 """Elastic warm-state lifecycle: prewarm planning and drain handoff
 (docs/serving.md "Elastic lifecycle").
 
-Scale events used to be availability events. PR 15's bench measured a
-post-reshard warm hit rate of exactly the surviving owners' share
-(0.655 on a 4→3 fleet): a joining or leaving replica contributed
-nothing warm, so every key that moved paid a cold fault. This module
-closes that gap with two pure planning functions plus the HTTP
-orchestration that drives them:
+Scale events used to be availability events: the post-reshard warm
+hit rate was exactly the surviving owners' share
+(tests/test_router.py TestReshardWarmth), because a joining or
+leaving replica contributed nothing warm, so every key that moved
+paid a cold fault. This module closes that gap with two pure
+planning functions plus the HTTP orchestration that drives them:
 
 * **prewarm** — ring placement is a deterministic cross-process
   function (``router/ring.py`` hashes with blake2b), so a replica

@@ -6,7 +6,7 @@ router front per process, and the numbers an operator pages on —
 ring-churn event counter, per-replica in-flight gauges — are
 cumulative totals on the router's ``GET /metrics``.
 
-Books-balance invariant (test- and bench-enforced): every ACCEPTED
+Books-balance invariant (``pytest -m router`` enforces it): every ACCEPTED
 request increments exactly one of the terminal outcome counters
 (``ok``/``degraded``/``timeout``/``rate_limited``/``unavailable``/
 ``failed``), so ``accepted == sum(terminal)`` at quiesce — a replica
@@ -54,7 +54,7 @@ class RouterMetrics:
         self._c = {k: 0 for k in self._KEYS}
         # end-to-end router wall time vs time spent waiting on the
         # upstream replica: the difference, summed, is the attributed
-        # router overhead the bench gates at < 2%
+        # router overhead
         self._hist = {"route_latency": LatencyHistogram(),
                       "upstream_latency": LatencyHistogram()}
         self._gauges: dict = {}      # replica -> inflight (bounded

@@ -21,7 +21,7 @@ failover.
 The actuation surface is a pluggable :class:`ReplicaController`;
 :class:`SimReplicaController` (in-process) and
 :class:`SubprocessReplicaController` (``python -m
-trivy_tpu.router.sim`` per replica) ship for tests and bench, a
+trivy_tpu.router.sim`` per replica) ship for tests and the soak, a
 production deployment implements the same three methods against its
 orchestrator (k8s Deployment scale, an ASG, …).
 """
@@ -165,7 +165,7 @@ class SimReplicaController(ReplicaController):
 
 class SubprocessReplicaController(ReplicaController):
     """One OS process per replica via ``python -m
-    trivy_tpu.router.sim`` — the bench fleet, and the template a
+    trivy_tpu.router.sim`` — the soak's subprocess fleet, and the template a
     real deployment's controller follows (start/drain/stop against
     its own orchestrator)."""
 
@@ -242,7 +242,7 @@ class SubprocessReplicaController(ReplicaController):
             proc.wait(timeout=5.0)
 
     def kill(self, name: str) -> None:
-        """Hard-kill (no drain) — the bench's replica-death lever."""
+        """Hard-kill (no drain) — a harness's replica-death lever."""
         proc = self.procs.pop(name, None)
         self.urls.pop(name, None)
         if proc is not None:

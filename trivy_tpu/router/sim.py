@@ -1,4 +1,4 @@
-"""Simulated scan replica for router tests and bench
+"""Simulated scan replica for router tests and the soak
 (docs/serving.md "Scan router & autoscaling").
 
 A stdlib-only stand-in for ``trivy-tpu server`` that speaks exactly
@@ -10,12 +10,12 @@ that matter for fleet behavior:
 
 * bounded concurrency (``max_concurrent`` semaphore): a replica has
   finite parallelism, so aggregate throughput should scale with the
-  replica count — the bench's ≥ 0.8×N gate is meaningless against an
+  replica count — a scaling measurement is meaningless against an
   infinitely parallel sleep;
 * per-replica warm state: the recency-ordered book of layer digests
   this replica has seen; a repeat of a known base digest answers
-  ``memo_hit: true`` — the signal the post-reshard warm-hit bench
-  measures;
+  ``memo_hit: true`` — the signal the post-reshard warm-hit tests
+  read (``pytest -m router``, ``pytest -m lifecycle``);
 * the elastic lifecycle (docs/serving.md "Elastic lifecycle"): with
   ``memo_dir`` the replica write-throughs every digest it warms into
   a shared directory (the sim stand-in for the redis/s3 memo tier);
@@ -41,8 +41,8 @@ that matter for fleet behavior:
 
 IMPORTANT: keep this module importable with stdlib only (no jax, no
 trivy_tpu heavyweight imports) — ``python -m trivy_tpu.router.sim``
-is the subprocess replica the SubprocessReplicaController and the
-bench spawn, and its startup cost is fleet-bringup cost. The twirp
+is the subprocess replica the SubprocessReplicaController
+spawns, and its startup cost is fleet-bringup cost. The twirp
 path constants are restated here (protocol literals, same values as
 ``rpc/server.py``) for exactly that reason. The obs imports below
 are lazy and land in ``trivy_tpu.obs.slo``/``procstats`` — both
@@ -280,8 +280,8 @@ class SimReplica:
                     exceeded = True
                     break
                 if self.prewarm_delay_ms:
-                    # simulated memo-tier fetch latency (the bench's
-                    # degraded-tier arm drives the deadline with it)
+                    # simulated memo-tier fetch latency (a degraded
+                    # tier drives the deadline with it)
                     time.sleep(self.prewarm_delay_ms / 1000.0)
                 self._touch_warm([d])
                 staged += 1
@@ -647,7 +647,7 @@ def _make_handler(sim: SimReplica):
         protocol_version = "HTTP/1.1"
 
         def log_message(self, fmt, *args):
-            pass                    # quiet: bench spawns fleets
+            pass                    # quiet: harnesses spawn fleets
 
         def _reply(self, code: int, payload: dict,
                    headers=None) -> None:

@@ -94,8 +94,8 @@ class BatchScanRunner:
         self.backend = backend
         self.mesh = mesh
         # tracer: trivy_tpu.obs.Tracer — per-request span trees on
-        # both execution paths (docs/observability.md); the bench's
-        # differential arm passes Tracer(enabled=False)
+        # both execution paths (docs/observability.md); an untraced
+        # run passes Tracer(enabled=False)
         self.tracer = tracer if tracer is not None else get_tracer()
         if secret_scanner is None:
             from ..secret.batch import BatchSecretScanner
@@ -106,7 +106,7 @@ class BatchScanRunner:
         self.artifact_option = artifact_option
         # fault_injector: trivy_tpu.faults.FaultInjector (or None) —
         # threads into the scheduler's device dispatch and this
-        # runner's host phases (--fault-spec / bench faults config)
+        # runner's host phases (--fault-spec / pytest -m faults)
         self.fault_injector = fault_injector
         # sched: "off" = the direct single-batch ladder below;
         # "on"/SchedConfig/ScanScheduler = continuous batching with
@@ -236,7 +236,8 @@ StreamingImageSource`: layer blobs decompress into the scan as they
         arrive, warm layers skip their GET entirely, and the per-layer
         pipeline overlaps the fleet's device work on both execution
         paths. ``streaming=False`` is the materialize-first baseline
-        (``DistributionClient.pull``) the bench compares against."""
+        (``DistributionClient.pull``) ``pytest -m stream`` compares
+        against."""
         from ..artifact.registry import DistributionClient
         from ..artifact.stream import stream_image
         if client is None:
@@ -903,9 +904,9 @@ StreamingImageSource`: layer blobs decompress into the scan as they
         # wall time; the stats keys are sums of their durations.
 
         # ---- phase 1: decode + blob (host, pooled) ----
-        # decode is the dominant host phase at fleet scale (BENCH_r05:
-        # 4.2s of the 7.99s SBOM bench): json parse + purl decode per
-        # component. The host pool spreads document decodes over the
+        # decode is the dominant host phase at fleet scale (PERF.md
+        # §5, sbom-batch): json parse + purl decode per component.
+        # The host pool spreads document decodes over the
         # spare cores in ≥64-doc slabs — per-doc tasks made pool
         # dispatch overhead the visible cost in the hostpool stats —
         # and repeated purl strings short-circuit in the purl parse

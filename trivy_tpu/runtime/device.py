@@ -10,7 +10,7 @@ CPU explicitly (the test suite's setting), and an explicit request
 is not a fallback.
 
 Every entry point that dispatches device work (CLI scan commands,
-``server``, ``watch``, ``bench.py`` children, ``chip_smoke.py``
+``server``, ``watch``, ``benchmark/run.py``, ``chip_smoke.py``
 stages) calls :func:`resolve_device` first thing: it places the
 persistent compile cache (``runtime.aot.configure_compile_cache``),
 initialises the backend, checks it, and logs platform, device kind

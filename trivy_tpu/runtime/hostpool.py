@@ -83,10 +83,10 @@ def map_in_pool(fn, items: list, chunk: int = 1,
     its own errors — a raising task would abandon the batch.
 
     ``chunk > 1`` batches that many items per pool task. Per-item
-    submission made task-dispatch overhead the visible cost of the
-    10k-document SBOM decode (BENCH_r05 ``decode_s``): a worker did
-    ~0.4 ms of json parsing per ~hop. Decode callers pass 64 so
-    every hop amortizes over a real slab of work.
+    submission made task-dispatch overhead the visible cost of a
+    fleet-scale SBOM decode: a worker did less json parsing per
+    hop than the hop cost. Decode callers pass 64 so every hop
+    amortizes over a real slab of work.
 
     ``around`` (optional) makes a context manager that brackets
     each pool task (a slab, or the whole inline loop) on the thread

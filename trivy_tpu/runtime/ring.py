@@ -3,8 +3,8 @@
 
 The synchronous ladder — pack → upload → compute → collect — leaves
 the device idle while the host packs the next batch and leaves the
-host idle while the device computes (the r05 ``interval_dispatch_s``
-≈ 2× ``interval_device_s`` defect). The ring splits every dispatch
+host idle while the device computes (``interval_dispatch_s`` a
+multiple of ``interval_device_s``). The ring splits every dispatch
 into a LAUNCH half (pack + ``jax.device_put`` into a fresh slot's
 buffers + non-blocking jitted enqueue, run on the submitting thread)
 and a COLLECT half (block on the lazy arrays, decode, fan results

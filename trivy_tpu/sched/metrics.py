@@ -11,8 +11,8 @@ brackets every kernel batch with ``device_begin``/``device_end`` and
 every host worker brackets its work with ``host_begin``/``host_end``;
 an accumulator integrates the wall-clock during which the device was
 busy AND at least one host worker was busy. ``overlap_ratio =
-that / device_busy`` — 0 means the strict host→device ladder the
-round-5 mesh curve flattened on, 1 means the device never waited
+that / device_busy`` — 0 means the strict host→device ladder of the
+direct path, 1 means the device never waited
 alone. "Device busy" here is the host's view: the union of the
 launch-to-collect windows of the dispatches in flight
 (``device_busy_s``), which under steady load is nearly the wall. The

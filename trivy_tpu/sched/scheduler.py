@@ -17,7 +17,7 @@ The device executor owns ALL kernel dispatch, so device work is
 serialized (one XLA stream, no interleaved compilation); the worker
 pool runs every host phase. While the device chews batch N, the pool
 analyzes batch N+1 and assembles batch N-1 — the host/device overlap
-the round-5 mesh curve lacked. Iteration-level scheduling à la
+the direct path lacks. Iteration-level scheduling à la
 Orca/vLLM: requests join whichever batch is forming when their host
 analysis lands, not the batch they arrived with.
 

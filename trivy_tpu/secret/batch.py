@@ -29,7 +29,7 @@ the rows across every chip (so the sieve computes while the caller
 squashes layers, preps interval jobs, and packs the next batch), and
 per-shard result decode fans back over the pool — the host thread
 never serializes the whole sieve, which is what used to make
-``secret_batch_s`` GROW with device count (BENCH_r05).
+``secret_batch_s`` GROW with device count.
 """
 
 from __future__ import annotations
@@ -190,8 +190,8 @@ class BatchSecretScanner:
         # total padded rows, and shards are carved out of that same
         # total in ≥ MIN_SHARD_ROWS blocks — so a small batch on 8
         # devices uses fewer shards instead of padding every tiny
-        # shard up to a full block (measured 2× sieve inflation on
-        # the mesh bench's ~250-segment scheduler batches)
+        # shard up to a full block (which pads a scheduler batch of
+        # a few hundred segments with rows the sieve scans for nothing)
         MIN_SHARD_ROWS = 64          # = the pallas tile (TILE_B)
         if n_shards > 1 and len(metas) > 1:
             Bp = _bucket(total, base=4 * MIN_SHARD_ROWS)
@@ -305,7 +305,7 @@ class BatchSecretScanner:
         path-based attribution misassigns findings across them.
 
         ``self.stats`` afterwards holds the sieve selectivity and the
-        host/device time split for this call (bench + tracing)."""
+        host/device time split for this call (stats + tracing)."""
         return self.collect(self.dispatch_files(files))
 
     def dispatch_files(self, files: Iterable):

@@ -20,8 +20,8 @@ Four pieces, composable and all seeded:
   every long-lived bounded structure, sampled per epoch; any series
   that grows without bound fails the run.
 
-Surface: ``trivy-tpu soak``, ``bench.py --config soak`` (full) and
-``--config soak-smoke`` (tier-1-safe), ``pytest -m soak``.
+Surface: ``trivy-tpu soak --scenario soak`` (full) and ``--scenario
+soak-smoke`` (minutes), ``pytest -m soak`` (seconds, tier-1).
 """
 
 from .audit import ResourceAudit

@@ -1,14 +1,14 @@
 """Seeded synthetic registry: a content-addressed layer graph with
 realistic reuse, scaled to 10⁵–10⁶ *distinct* layer identities.
 
-PR 9's warm-fleet builder (``bench.py make_warm_fleet``) materializes
-tarballs where ~80% of layers are drawn from a shared pool — the
-reuse pattern that makes content-addressed memoization pay. That
-works to a few hundred images; a million-image registry cannot touch
-disk. This generator keeps the same reuse *shape* but is index-bound:
-every manifest is a pure function of ``(seed, image index)``, layer
-digests are derived identities, and nothing exists until the run
-asks for it — corpus size costs an integer, not a filesystem.
+A fleet builder that materializes tarballs where ~80% of layers are
+drawn from a shared pool has the reuse pattern that makes
+content-addressed memoization pay, and works to a few hundred
+images; a million-image registry cannot touch disk. This generator
+keeps the same reuse *shape* but is index-bound: every manifest is
+a pure function of ``(seed, image index)``, layer digests are
+derived identities, and nothing exists until the run asks for it —
+corpus size costs an integer, not a filesystem.
 
 The outputs speak the tree's existing protocols verbatim:
 
@@ -193,7 +193,7 @@ class SyntheticRegistry:
     def scan_body(self, manifest: dict,
                   idempotency_key: str = "") -> dict:
         """The twirp ``Scan`` body for one manifest — same shape as
-        the router bench's requests, so route keys, sim warm state
+        the router tests' requests, so route keys, sim warm state
         and idempotent replay behave identically."""
         body = {"idempotency_key": idempotency_key,
                 "target": f"{manifest['repository']}:"

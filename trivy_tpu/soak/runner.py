@@ -703,8 +703,7 @@ class SoakRunner:
     def _sustained_ips(self) -> dict:
         """Goodput (ok) and offered (accepted) rates over epochs
         wholly outside every disruption window — the steady-state
-        throughput the full-soak bench gates against the direct
-        router storm at equivalent N."""
+        throughput a full soak reports next to its verdict."""
         windows = self._disruption_windows()
         total_dt = total_ok = total_acc = 0.0
         for (t_a, ok_a, acc_a), (t_b, ok_b, acc_b) in zip(

@@ -9,8 +9,8 @@ def defer_gc():
     """Suspend generational GC around allocation-heavy fleet loops.
 
     With the compiled advisory DB resident (48k+ Python row tuples),
-    every young-generation collection walks that long-lived heap;
-    measured on the 10k-SBOM bench this made decode 2.4x slower.
+    every young-generation collection walks that long-lived heap
+    while a fleet-scale SBOM decode allocates.
     Objects created inside the block are collected by the explicit
     collect() on exit, so cycles cannot accumulate across batches.
     That collection walks the whole heap while every thread waits:

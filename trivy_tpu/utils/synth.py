@@ -1,5 +1,5 @@
-"""Synthetic image-tarball builders shared by the bench, the driver
-dry run, and tests.
+"""Synthetic image-tarball builders shared by the driver dry run
+and tests.
 
 The reference's integration suite runs against canned image tarballs
 pulled from a registry (SURVEY.md §4); this environment has no egress,

@@ -64,8 +64,8 @@ class WatchConfig:
     tenant: str = "watch"
     priority: int = 0
     checkpoint_path: str = ""
-    # keep the latest BatchScanResult per digest (bench/tests use it
-    # for the byte-identity gate; servers leave it off)
+    # keep the latest BatchScanResult per digest (tests use it for
+    # the byte-identity gate; servers leave it off)
     keep_results: bool = False
 
     @property

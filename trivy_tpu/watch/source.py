@@ -10,7 +10,7 @@ plus ``exhausted`` — so the loop never cares where events come from:
   registry is configured to POST on every push);
 * :class:`SyntheticSource` — a seeded Poisson arrival schedule over a
   fleet of image tarballs, with duplicate-tag bursts, for tests and
-  ``bench.py --config watch``;
+  ``watch --watch-source synthetic``;
 * :class:`TraceSource` — replays a recorded event list verbatim.
 
 Every event carries a monotonically increasing per-source ``seq``;
@@ -265,7 +265,7 @@ class SyntheticSource(EventSource):
     Poisson gaps at ``rate`` events/s, with ``dup_rate`` of events
     followed by a burst of duplicate pushes of the same digest (the
     tag-repush pattern debounce exists for). ``paced=False`` replays
-    the same schedule as fast as the loop pulls — bench arms pace,
+    the same schedule as fast as the loop pulls — the CLI paces,
     unit tests don't."""
 
     def __init__(self, paths: list, rate: float = 10.0,
@@ -334,11 +334,11 @@ def make_event_storm(spec, paths: list) -> list:
     of ``storm_events`` raw notification envelopes over
     ``storm_digests`` distinct digests (duplicate-tag repushes
     included), with ``storm_malformed`` malformed envelopes
-    interleaved. The harness (tests, bench) feeds these through
-    ``WebhookSource.push_notification`` — debounce must collapse the
-    duplicates, malformed envelopes must be counted and dropped, and
-    scheduler backpressure must shed via the existing 429/503 paths
-    without ever crashing the loop."""
+    interleaved. The harness (tests, ``watch --fault-spec``) feeds
+    these through ``WebhookSource.push_notification`` — debounce
+    must collapse the duplicates, malformed envelopes must be
+    counted and dropped, and scheduler backpressure must shed via
+    the existing 429/503 paths without ever crashing the loop."""
     import hashlib
     import random
     rng = random.Random(spec.seed)
