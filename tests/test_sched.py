@@ -208,6 +208,29 @@ class TestQueue:
 # ---------------------------------------------------------------
 
 class TestScheduler:
+    def test_full_collections_are_spaced_while_a_scheduler_runs(self):
+        """A running scheduler holds ``utils.sparse_full_gc``: the
+        interpreter's third threshold is raised from start to close,
+        once however many schedulers run, and the last close puts
+        back what the first start found."""
+        import gc
+
+        from trivy_tpu.utils import _FULL_GC_EVERY
+        found = gc.get_threshold()
+        held = (found[0], found[1], max(found[2], _FULL_GC_EVERY))
+        a = ScanScheduler(config=SchedConfig(workers=1)).start()
+        b = ScanScheduler(config=SchedConfig(workers=1)).start()
+        try:
+            assert gc.get_threshold() == held
+            a.start()               # running already: not counted twice
+            a.close()
+            a.close()               # closed already: not released twice
+            assert gc.get_threshold() == held
+        finally:
+            a.close()
+            b.close()
+        assert gc.get_threshold() == found
+
     def test_deadline_expiry_fails_fast_not_hang(self):
         """A request whose deadline passes mid-pipeline resolves
         with DeadlineExceeded — it must never hang."""

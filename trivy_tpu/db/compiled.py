@@ -11,7 +11,10 @@ time into
   - int32 interval tables [N, MAX_INTERVALS] in a doubled rank space
     (bound = 2·rank + grammar band offset, exclusivity = ±1);
   - a host-side name-join index bucket → package → row span;
-  - per-row metadata for DetectedVulnerability assembly;
+  - per-row metadata for DetectedVulnerability assembly, kept as
+    columns of integers over one table of strings (``_RowTable``):
+    the table holds no Python object a row, and a row's
+    ``(bucket, package, Advisory)`` is built when it is read;
   - host-fallback rows for constraints the interval form can't carry
     (> MAX_INTERVALS alternatives, parse errors, npm prereleases).
 
@@ -23,14 +26,14 @@ dispatch (ops.intervals.interval_hits_resident) evaluates every
 reused across scans; ``SwappableStore`` double-buffers them for hot
 swaps (reference: pkg/rpc/server/listen.go:71-80).
 
-Persistence: ``save``/``load`` round-trip the arrays plus the
-indexes/universes as ONE npz file whose ``meta`` member is tagged
-JSON — a data-only format (no pickle: a compiled DB may arrive over
-the network in the reference's trivy-db workflow, and the server
-hot-swaps whatever appears at the watched path, so deserialization
-must not be code execution), written to a temp name and atomically
-renamed so the hot-swap watcher can never observe a half-written
-pair.
+Persistence: ``save``/``load`` round-trip the arrays, the row
+columns and the universes as ONE npz file whose ``strings`` and
+``meta`` members are (tagged) JSON — a data-only format (no pickle:
+a compiled DB may arrive over the network in the reference's
+trivy-db workflow, and the server hot-swaps whatever appears at the
+watched path, so deserialization must not be code execution),
+written to a temp name and atomically renamed so the hot-swap
+watcher can never observe a half-written pair.
 """
 
 from __future__ import annotations
@@ -38,15 +41,19 @@ from __future__ import annotations
 import contextlib
 
 import json
+from array import array
 import os
 import threading
 from bisect import bisect_left
+from collections.abc import Sequence
 from dataclasses import dataclass, field
+from itertools import accumulate
 from typing import Optional
 
 import numpy as np
 
 from ..ops.intervals import MAX_INTERVALS, NEG_INF, POS_INF
+from ..types import DataSource
 from ..utils import get_logger
 import datetime as _dt
 
@@ -123,6 +130,157 @@ class _Row:
     sec_ivs: list = field(default_factory=list)
     flags: int = 0
 
+
+# an Advisory's six list-valued fields, in the order a row's items
+# are stored
+_LIST_FIELDS = ("vulnerable_versions", "patched_versions",
+                "unaffected_versions", "arches", "vendor_ids",
+                "content_sets")
+
+
+class _RowTable(Sequence):
+    """The table's per-row metadata as columns: ``table[row]`` is
+    ``(bucket, package, Advisory)``, built when the row is read.
+
+    A million rows kept as ``(bucket, pkg, Advisory)`` tuples are
+    eight collector-tracked objects a row, and every full collection
+    walks all of them (``utils.defer_gc`` runs one a ``scan_boms``
+    pass). Here a row is seven integers of one ``int32`` array and
+    nothing else, so the table holds a number of tracked objects that
+    does not grow with its rows:
+
+    - ``strings``: every distinct string of the table, once, in a
+      tuple: the collector stops tracking a tuple of strings the
+      first time it meets it, where it would walk a list of them
+      in every full collection;
+    - ``cols`` ``[N, 7]``: a row's bucket, package, vulnerability id,
+      fixed and affected version (indices into ``strings``), its
+      form (an index into ``forms``) and where its items start;
+    - ``forms`` ``[K, 10]``: the distinct combinations of severity,
+      data source (id, name, url in ``strings``; -1 for none) and the
+      lengths of the six list fields (``_LIST_FIELDS``): most rows
+      share a handful, since most leave arches, vendor ids and
+      content sets empty;
+    - ``items`` ``[M]``: the elements of every row's lists, row after
+      row, as indices into ``strings``.
+
+    Each read gives a fresh ``Advisory`` equal to the one compiled
+    in; mutating it changes nothing here. The columns may come from
+    a file (``CompiledDB.load``), so they are range-checked once, on
+    the way in."""
+
+    __slots__ = ("strings", "cols", "forms", "items", "_forms")
+
+    def __init__(self, strings: list, rows: np.ndarray,
+                 forms: np.ndarray, items: np.ndarray):
+        rows = np.asarray(rows, np.int32).reshape(-1, 6)
+        forms = np.asarray(forms, np.int32).reshape(-1, 10)
+        items = np.asarray(items, np.int32).reshape(-1)
+        n_str, n_forms = len(strings), len(forms)
+
+        def within(a, lo, hi):
+            return a.size == 0 or (a.min() >= lo and a.max() < hi)
+
+        ok = within(rows[:, :5], 0, n_str) \
+            and within(rows[:, 5], 0, n_forms) \
+            and within(items, 0, n_str) \
+            and within(forms[:, 1:4], -1, n_str) \
+            and within(forms[:, 4:], 0, len(items) + 1)
+        if ok:      # items a row, by its form
+            per_row = forms[:, 4:].sum(axis=1, dtype=np.int64)[
+                rows[:, 5]]
+            ok = int(per_row.sum()) == len(items)
+        if not ok:
+            raise ValueError("compiled db: row columns out of range "
+                             "— rebuild with 'db build'")
+        self.strings = tuple(strings)
+        self.cols = np.empty((len(rows), 7), np.int32)
+        self.cols[:, :6] = rows
+        self.cols[:, 6] = np.cumsum(per_row) - per_row
+        self.forms = forms
+        self.items = items
+        # forms decoded once: (severity, data source or None, where
+        # each list ends among the row's items or None for no items)
+        self._forms = []
+        for sev, sid, sname, surl, *counts in forms.tolist():
+            ends = tuple(accumulate(counts))
+            self._forms.append((
+                sev,
+                (strings[sid], strings[sname], strings[surl])
+                if min(sid, sname, surl) >= 0 else None,
+                ends if ends[-1] else None))
+
+    @classmethod
+    def build(cls, rows) -> "_RowTable":
+        """From an iterable of ``(bucket, package, Advisory)``, which
+        is read once and not kept."""
+        strings: dict = {}
+        forms: dict = {}
+        cols, items = array("i"), array("i")
+
+        def s(v: str) -> int:
+            return strings.setdefault(v, len(strings))
+
+        for bucket, pkg, adv in rows:
+            ds = adv.data_source
+            lists = [getattr(adv, f) for f in _LIST_FIELDS]
+            form = (adv.severity,
+                    *((s(ds.id), s(ds.name), s(ds.url))
+                      if ds is not None else (-1, -1, -1)),
+                    *map(len, lists))
+            cols.extend((s(bucket), s(pkg), s(adv.vulnerability_id),
+                         s(adv.fixed_version),
+                         s(adv.affected_version),
+                         forms.setdefault(form, len(forms))))
+            for values in lists:
+                items.extend(map(s, values))
+        return cls(list(strings),
+                   np.frombuffer(cols, np.int32).copy(),
+                   np.array(list(forms), np.int32),
+                   np.frombuffer(items, np.int32).copy())
+
+    def __len__(self) -> int:
+        return len(self.cols)
+
+    def __getitem__(self, row: int) -> tuple:
+        strings = self.strings
+        bucket, pkg, vid, fixed, affected, form, start = \
+            self.cols[row].tolist()
+        severity, source, ends = self._forms[form]
+        if ends is None:
+            vulnerable, patched, unaffected, arches, vendor_ids, \
+                content_sets = [], [], [], [], [], []
+        else:
+            a, b, c, d, e, end = ends
+            its = [strings[i] for i in
+                   self.items[start:start + end].tolist()]
+            vulnerable, patched, unaffected, arches, vendor_ids, \
+                content_sets = (its[:a], its[a:b], its[b:c],
+                                its[c:d], its[d:e], its[e:])
+        return (strings[bucket], strings[pkg], Advisory(
+            strings[vid], strings[fixed], strings[affected],
+            vulnerable, patched, unaffected, arches, severity,
+            vendor_ids,
+            DataSource(*source) if source is not None else None,
+            content_sets))
+
+    def runs(self):
+        """``(bucket, package, range of rows)`` for each run of rows
+        that share both, in row order."""
+        n = len(self.cols)
+        if not n:
+            return
+        keys = self.cols[:, :2]
+        cuts = np.flatnonzero(
+            (keys[1:] != keys[:-1]).any(axis=1)) + 1
+        starts = [0] + cuts.tolist()
+        strings = self.strings
+        for (b, p), lo, hi in zip(keys[starts].tolist(), starts,
+                                  starts[1:] + [n]):
+            yield strings[b], strings[p], range(lo, hi)
+
+
+_NO_ROWS = range(0)
 
 _GENERATION_LOCK = threading.Lock()
 _GENERATION_SEQ = [0]
@@ -321,11 +479,12 @@ class CompiledDB(ResidentTables):
     ``compile`` / ``load``."""
 
     def __init__(self):
-        self.rows_meta: list = []       # per row: (bucket, pkg, Advisory)
-        self.row_grammar: list = []
+        # per row: (bucket, pkg, Advisory), built when read
+        self.rows_meta = _RowTable([], (), (), ())
+        self.row_grammar: tuple = ()    # per row: grammar name
         self.v_lo = self.v_hi = self.s_lo = self.s_hi = None
         self.flags = None               # np.int32 [N]
-        self.index: dict = {}           # bucket → {pkg → [row ids]}
+        self.index: dict = {}           # bucket → {pkg → range of rows}
         self.universe: dict = {}        # grammar → (keys list, base)
         self.vulnerabilities: dict = {}
         self.data_sources: dict = {}
@@ -337,9 +496,10 @@ class CompiledDB(ResidentTables):
 
     @classmethod
     def compile(cls, store: AdvisoryStore) -> "CompiledDB":
-        # millions of long-lived row/interval objects make the cyclic
-        # collector quadratic-ish (2.3x at 1M advisories); nothing
-        # cyclic is created here
+        # the million ``_Row`` and interval objects that live until
+        # the columns are built make the cyclic collector
+        # quadratic-ish (2.3x at 1M advisories); nothing cyclic is
+        # created here, and none of them outlives the call
         with gc_paused():
             return cls._compile(store)
 
@@ -390,11 +550,9 @@ class CompiledDB(ResidentTables):
             for j, iv in enumerate(row.sec_ivs):
                 self.s_lo[i, j], self.s_hi[i, j] = \
                     self._encode(row.grammar, iv)
-        self.rows_meta = [(r.bucket, r.pkg, r.advisory) for r in rows]
-        self.row_grammar = [r.grammar for r in rows]
-        for i, row in enumerate(rows):
-            self.index.setdefault(row.bucket, {}) \
-                .setdefault(row.pkg, []).append(i)
+        self._set_rows(_RowTable.build(
+            (r.bucket, r.pkg, r.advisory) for r in rows))
+        self.row_grammar = tuple(r.grammar for r in rows)
 
         self.stats = {
             "rows": N,
@@ -407,6 +565,21 @@ class CompiledDB(ResidentTables):
                  "(%.3f%%)", N, n_host,
                  100.0 * self.stats["host_fallback_rate"])
         return self
+
+    def _set_rows(self, table: _RowTable) -> None:
+        """Install the row columns and the name-join index over
+        them. Rows are appended bucket by bucket and package by
+        package, so a package's rows are one run and the index holds
+        a ``range`` a package, not a list."""
+        self.rows_meta = table
+        self.index = {}
+        for bucket, pkg, rows in table.runs():
+            pkgs = self.index.setdefault(bucket, {})
+            if pkg in pkgs:
+                raise ValueError(
+                    f"compiled db: rows of {bucket!r} {pkg!r} are "
+                    f"not contiguous — rebuild with 'db build'")
+            pkgs[pkg] = rows
 
     def _compile_row(self, bucket: str, pkg: str, adv: Advisory,
                      grammar: Optional[str]) -> _Row:
@@ -514,8 +687,10 @@ class CompiledDB(ResidentTables):
         self._parse_cache[(grammar, version)] = r
         return r
 
-    def candidate_rows(self, bucket: str, pkg: str) -> list:
-        return self.index.get(bucket, {}).get(pkg, [])
+    def candidate_rows(self, bucket: str, pkg: str) -> Sequence:
+        pkgs = self.index.get(bucket)
+        return pkgs.get(pkg, _NO_ROWS) if pkgs is not None \
+            else _NO_ROWS
 
     def _prefix_index(self) -> dict:
         """ecosystem prefix ("pip::") → bucket list, built lazily so
@@ -535,7 +710,7 @@ class CompiledDB(ResidentTables):
             buckets = [b for b in self.index if b.startswith(prefix)]
         out = []
         for bucket in buckets:
-            out.extend(self.index[bucket].get(pkg, []))
+            out.extend(self.index[bucket].get(pkg, _NO_ROWS))
         return out
 
     def host_eval(self, row: int, version: str) -> bool:
@@ -615,9 +790,21 @@ class CompiledDB(ResidentTables):
                       self.flags):
                 if a is not None:
                     h.update(np.ascontiguousarray(a).tobytes())
-            h.update(json.dumps(
-                [[b, p, _adv_enc(a)] for b, p, a in self.rows_meta],
-                sort_keys=True, default=_json_default).encode())
+            # the bytes of json.dumps over the list of every row's
+            # [bucket, pkg, record], a few thousand rows at a time:
+            # the value a table of row tuples gave, with no million
+            # records alive at once
+            n = len(self.rows_meta)
+            h.update(b"[")
+            with gc_paused():       # nothing cyclic; see compile
+                for lo in range(0, n, 4096):
+                    part = json.dumps(
+                        [[b, p, _adv_enc(a)] for b, p, a in map(
+                            self.rows_meta.__getitem__,
+                            range(lo, min(lo + 4096, n)))])
+                    h.update((", " if lo else "").encode()
+                             + part[1:-1].encode())
+            h.update(b"]")
             fp = self._content_fp = h.hexdigest()[:32]
         return fp
 
@@ -636,29 +823,43 @@ class CompiledDB(ResidentTables):
     def save(self, path: str) -> None:
         """Write ``path + ".npz"`` atomically (temp file + rename).
 
-        Everything non-array rides in the ``meta`` member as tagged
-        JSON (see ``_enc_key``); a single file means the DBWorker's
-        mtime check can never pair new arrays with stale metadata."""
+        The row columns ride as array members of their own (``rows``,
+        ``forms``, ``items``, ``row_grammar``) beside the interval
+        tables, their strings as the JSON list ``strings``;
+        universes, vulnerability details, data sources and stats ride
+        in ``meta`` as tagged JSON (see ``_enc_key``). A single file
+        means the DBWorker's mtime check can never pair new arrays
+        with stale metadata."""
+        table = self.rows_meta
+        grammars = sorted(set(self.row_grammar))
+        if len(grammars) > 256:         # a uint8 a row
+            raise ValueError("compiled db: more than 256 grammars")
+        code = {g: i for i, g in enumerate(grammars)}
         meta = {
-            "rows_meta": [(b, p, _adv_enc(a))
-                          for b, p, a in self.rows_meta],
-            "row_grammar": self.row_grammar,
-            "index": self.index,
+            "grammars": grammars,
             "universe": {g: [[_enc_key(k) for k in keys], base]
                          for g, (keys, base) in self.universe.items()},
             "vulnerabilities": self.vulnerabilities,
             "data_sources": self.data_sources,
             "stats": self.stats,
         }
-        blob = np.frombuffer(
-            json.dumps(meta, default=_json_default).encode(),
-            np.uint8)
+
+        def blob(obj, **kw) -> np.ndarray:
+            return np.frombuffer(json.dumps(obj, **kw).encode(),
+                                 np.uint8)
+
         tmp = path + ".npz.tmp"
         with open(tmp, "wb") as f:
             np.savez_compressed(
                 f, v_lo=self.v_lo, v_hi=self.v_hi,
                 s_lo=self.s_lo, s_hi=self.s_hi, flags=self.flags,
-                meta=blob)
+                rows=np.ascontiguousarray(table.cols[:, :6]),
+                forms=table.forms, items=table.items,
+                row_grammar=np.fromiter(
+                    map(code.__getitem__, self.row_grammar),
+                    np.uint8, len(self.row_grammar)),
+                strings=blob(table.strings),
+                meta=blob(meta, default=_json_default))
         os.replace(tmp, path + ".npz")
 
     @classmethod
@@ -674,10 +875,28 @@ class CompiledDB(ResidentTables):
                 f"'db build' (pre-data-only-format file?)")
         d = json.loads(arrs["meta"].tobytes().decode(),
                        object_hook=_json_hook)
-        self.rows_meta = [(b, p, _adv_dec(a))
-                          for b, p, a in d["rows_meta"]]
-        self.row_grammar = d["row_grammar"]
-        self.index = d["index"]
+        if "rows_meta" in d:
+            # the format before the row columns: a JSON record a row
+            # in ``meta``, converted here once a load
+            log.warning("%s.npz is in the old compiled-db format "
+                        "(a JSON record a row); it loads, slowly — "
+                        "rebuild with 'db build'", path)
+            with gc_paused():
+                table = _RowTable.build(
+                    (b, p, _adv_dec(a)) for b, p, a in d["rows_meta"])
+            self.row_grammar = tuple(d["row_grammar"])
+        else:
+            table = _RowTable(
+                json.loads(arrs["strings"].tobytes().decode()),
+                arrs["rows"], arrs["forms"], arrs["items"])
+            grammars = d["grammars"]
+            self.row_grammar = tuple(
+                grammars[i] for i in arrs["row_grammar"].tolist())
+        if len(self.row_grammar) != len(table) \
+                or len(self.flags) != len(table):
+            raise ValueError(f"{path}.npz: row columns and interval "
+                             f"tables differ in length")
+        self._set_rows(table)
         self.universe = {g: ([_dec_key(k) for k in keys], base)
                          for g, (keys, base) in d["universe"].items()}
         self.vulnerabilities = d["vulnerabilities"]
@@ -758,7 +977,6 @@ def _adv_enc(a: Advisory) -> list:
 
 
 def _adv_dec(v: list) -> Advisory:
-    from ..types import DataSource
     ds = DataSource(id=v[9][0], name=v[9][1], url=v[9][2]) \
         if v[9] is not None else None
     return Advisory(

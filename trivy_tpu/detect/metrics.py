@@ -43,6 +43,9 @@ class DetectMetrics:
         # device-resident advisory tables (db/compiled.py)
         "db_uploads", "db_upload_bytes", "db_invalidations",
         "resident_dispatches",
+        # advisory rows the name join built from the table's columns
+        # (scan/local._vuln_jobs, added once a join)
+        "table_rows_decoded",
         # host packing pool (runtime/hostpool.py)
         "pack_tasks",
     )

@@ -17,6 +17,7 @@ from ..detect.batch import (PairJob, ResidentPairJob, detect_pairs,
 from ..detect.enrich import fill_info
 from ..detect.library import _TYPES as LIB_TYPES
 from ..detect.library import _fixed_versions, normalize_pkg_name
+from ..detect.metrics import DETECT_METRICS
 from ..detect.ospkg.drivers import DRIVERS, format_src_version
 from ..types import (OS, DetectedVulnerability, Result, ResultClass,
                      Vulnerability)
@@ -232,6 +233,7 @@ class LocalScanner:
             from ..memo.findings import MemoQuery
 
         cdb = self.compiled
+        decoded = 0     # rows read from the compiled table
         if "os" in options.vuln_type and detail.os is not None \
                 and detail.packages:
             driver = DRIVERS.get(detail.os.family)
@@ -243,8 +245,10 @@ class LocalScanner:
                     installed = driver.installed(pkg)
                     qstart = len(jobs)
                     if cdb is not None:
-                        for row in cdb.candidate_rows(
-                                bucket, driver.src_name(pkg)):
+                        rows = cdb.candidate_rows(
+                            bucket, driver.src_name(pkg))
+                        decoded += len(rows)
+                        for row in rows:
                             adv = cdb.rows_meta[row][2]
                             if not driver.adv_match(
                                     detail.os.name, pkg, adv):
@@ -286,8 +290,10 @@ class LocalScanner:
                     name = normalize_pkg_name(eco, lib.name)
                     qstart = len(jobs)
                     if cdb is not None:
-                        for row in cdb.candidate_rows_prefix(
-                                f"{eco}::", name):
+                        rows = cdb.candidate_rows_prefix(
+                            f"{eco}::", name)
+                        decoded += len(rows)
+                        for row in rows:
                             adv = cdb.rows_meta[row][2]
                             jobs.append(ResidentPairJob(
                                 cdb=cdb, row=row, grammar=grammar,
@@ -307,6 +313,8 @@ class LocalScanner:
                             installed=lib.version,
                             report_unfixed=True, pkg=lib,
                             start=qstart, end=len(jobs)))
+        if decoded:
+            DETECT_METRICS.inc("table_rows_decoded", decoded)
         return jobs, eosl
 
     def _vuln_results(self, target: str, detail,

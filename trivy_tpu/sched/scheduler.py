@@ -58,7 +58,7 @@ from typing import Optional
 from ..obs.cost import COST_LEDGER, parse_budget_config
 from ..obs.trace import book_phase, get_tracer, trace_cause
 from ..ops.program import DeviceProgramError
-from ..utils import get_logger
+from ..utils import get_logger, sparse_full_gc
 from .coalescer import Batch, Coalescer, SchedConfig
 from .metrics import SchedMetrics
 from .queue import (DeadlineExceeded, QueueFullError,
@@ -163,24 +163,33 @@ class ScanScheduler:
     # --- lifecycle ---
 
     def start(self) -> "ScanScheduler":
-        with self._lock:
-            if self._running:
-                return self
-            if self.queue.closed:
-                # a closed scheduler never revives — restarting the
-                # threads against a permanently closed queue would
-                # only leak them
-                raise SchedulerClosed("scheduler is closed")
-            self._running = True
-            self._pool = ThreadPoolExecutor(
-                max_workers=max(1, self.config.workers),
-                thread_name_prefix="sched-host")
-            for name, fn in (("sched-intake", self._intake_loop),
-                             ("sched-device", self._device_loop)):
-                t = threading.Thread(target=fn, name=name,
-                                     daemon=True)
-                t.start()
-                self._threads.append(t)
+        # requests keep what they make until their caller lets go of
+        # it (utils.sparse_full_gc); taken before this scheduler's
+        # lock, since it takes one of its own
+        release_gc = sparse_full_gc()
+        try:
+            with self._lock:
+                if self._running:
+                    return self
+                if self.queue.closed:
+                    # a closed scheduler never revives — restarting
+                    # the threads against a permanently closed queue
+                    # would only leak them
+                    raise SchedulerClosed("scheduler is closed")
+                self._running = True
+                self._release_gc, release_gc = release_gc, None
+                self._pool = ThreadPoolExecutor(
+                    max_workers=max(1, self.config.workers),
+                    thread_name_prefix="sched-host")
+                for name, fn in (("sched-intake", self._intake_loop),
+                                 ("sched-device", self._device_loop)):
+                    t = threading.Thread(target=fn, name=name,
+                                         daemon=True)
+                    t.start()
+                    self._threads.append(t)
+        finally:
+            if release_gc is not None:      # not started after all
+                release_gc()
         return self
 
     def close(self, wait: bool = True) -> None:
@@ -218,6 +227,7 @@ class ScanScheduler:
         for t in self._threads:
             t.join(timeout=5 if wait else 0)
         self._threads = []
+        self._release_gc()
 
     def drain(self, timeout_s: float = 30.0) -> bool:
         """Graceful shutdown: refuse new admissions (submit raises
