@@ -26,7 +26,7 @@ from ..utils import get_logger
 from .cache import calc_key
 from .image import ImageSource, guess_base_layers
 from .metrics import INGEST_METRICS
-from .walker import collect_layer_tar, walk_fs
+from .walker import collect_layer_tar, iter_fs
 
 log = get_logger("artifact")
 
@@ -351,6 +351,50 @@ class ImageArtifact:
 class LocalFSArtifact:
     """Directory tree → ONE blob (reference: artifact/local/fs.go)."""
 
+    def _stream_secrets(self, result: AnalysisResult, files,
+                        stream) -> list:
+        """The walk with its secret candidates cut into parts
+        (``stream.cutter``, a ``secret.batch.PartCutter``) and each
+        part handed on as it closes: ``stream.emit`` waits while as
+        many parts are in flight as the stream allows, so the tree's
+        bytes held are those parts' and no more; ``stream.finish``
+        waits for the last and returns the Secrets in the files'
+        order. ``ingest.tree_walk`` books the walk's own seconds,
+        reading and analyzing up to a part's close, and nothing of
+        a wait in ``emit``."""
+        from ..obs.trace import phase_span
+        cutter = stream.cutter
+        n_files = n_candidates = n_bytes = 0
+        walking = True
+        while walking:
+            parts = []
+            with phase_span("tree_walk", pipeline="ingest"):
+                for path, size, read in files:
+                    n_files += 1
+                    self.group.analyze_file(result, path, read, size)
+                    if not result.secret_candidates:
+                        continue
+                    for p, content in result.secret_candidates:
+                        n_candidates += 1
+                        n_bytes += len(content)
+                        parts.append(cutter.add(p, content))
+                    result.secret_candidates = []
+                    if any(parts):
+                        break
+                else:
+                    walking = False
+                    parts.append(cutter.flush())
+            # popped, and never bound to a name here, so that nothing
+            # in the walk keeps a part's bytes once the stream has
+            # let them go
+            while parts:
+                if parts[0]:
+                    stream.emit(parts.pop(0))
+                else:
+                    parts.pop(0)
+        INGEST_METRICS.note_tree(n_files, n_candidates, n_bytes)
+        return stream.finish()
+
     def __init__(self, root: str, cache,
                  option: Optional[ArtifactOption] = None):
         self.root = root
@@ -360,17 +404,25 @@ class LocalFSArtifact:
             disabled=_effective_disabled(self.opt),
             file_patterns=self.opt.file_patterns)
 
-    def inspect(self) -> ArtifactReference:
+    def inspect(self, stream=None) -> ArtifactReference:
+        """``stream`` (the batch runner's, for a tree that rides the
+        scheduler): where the secret candidates go, a part at a
+        time and while the walk goes on, instead of all of them to
+        one ``scan_files`` call at its end. The other analyzers run
+        in the walk either way, and the blob is the same."""
         result = AnalysisResult()
-        files = walk_fs(self.root, skip_dirs=self.opt.skip_dirs,
+        files = iter_fs(self.root, skip_dirs=self.opt.skip_dirs,
                         skip_files=self.opt.skip_files)
-        for path, size, read in files:
-            self.group.analyze_file(result, path, read, size)
-
-        if result.secret_candidates and self.opt.scan_secrets:
-            scanner = _secret_scanner(self.opt)
-            result.secrets = [s for _, s in scanner.scan_files(
-                [(p, c) for p, c in result.secret_candidates])]
+        if stream is not None and self.opt.scan_secrets:
+            result.secrets = self._stream_secrets(result, files,
+                                                  stream)
+        else:
+            for path, size, read in files:
+                self.group.analyze_file(result, path, read, size)
+            if result.secret_candidates and self.opt.scan_secrets:
+                scanner = _secret_scanner(self.opt)
+                result.secrets = [s for _, s in scanner.scan_files(
+                    [(p, c) for p, c in result.secret_candidates])]
 
         blob = result.to_blob_info()
         post_handle(blob)

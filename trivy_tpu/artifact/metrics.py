@@ -35,6 +35,12 @@ class IngestMetrics:
         # layers whose secrets the base-image rule leaves out of the
         # image's report (image.guess_base_layers), cached or not
         "base_layers_skipped",
+        # LocalFSArtifact's streamed walk (a tree through
+        # runtime/batch.submit_tree): regular files the walk met,
+        # those of them that are no secret candidate (the secret
+        # analyzer's skips: directory, name, extension, size, a NUL
+        # in the head), and the bytes of those that are
+        "tree_files", "tree_files_skipped", "tree_bytes",
     )
 
     def __init__(self):
@@ -58,11 +64,19 @@ class IngestMetrics:
             c["bytes_analyzed"] += nbytes
             c["base_layers_skipped"] += base
 
+    def note_tree(self, files: int, candidates: int,
+                  nbytes: int) -> None:
+        with self._lock:
+            c = self.counters
+            c["tree_files"] += files
+            c["tree_files_skipped"] += files - candidates
+            c["tree_bytes"] += nbytes
+
     def snapshot(self) -> dict:
         with self._lock:
             out = dict(self.counters)
-        # the walker's and analyzers' row of the phase clock
-        # (obs/trace.phase_span: layer_analyze), cumulative
+        # the walkers' and analyzers' rows of the phase clock
+        # (obs/trace.phase_span: layer_analyze, tree_walk), cumulative
         from ..obs.trace import phase_rows
         out["phase"] = phase_rows("ingest")
         return out

@@ -168,18 +168,24 @@ def _clean_skip(paths) -> set:
     return out
 
 
-def walk_fs(root: str, skip_dirs: list = (),
+def iter_fs(root: str, skip_dirs: list = (),
             skip_files: list = (),
-            budget: Optional[ResourceBudget] = None) -> list:
-    """Directory walk → [(rel_path, size, read_fn)] (reference:
+            budget: Optional[ResourceBudget] = None):
+    """Directory walk → (rel_path, size, read_fn), one at a time, so
+    that a caller that streams (``LocalFSArtifact``) has its first
+    files before the tree is walked to its end (reference:
     walker/fs.go; shared skip logic walk.go:47-62). Skip lists match
     both the cwd-relative walked path (reference behavior for
     relative scan roots) and the root-relative path (convenience).
-    Symlinks are never followed (``os.walk`` default + the islink
-    filter below), so a link farm cannot pull the walk outside
-    ``root``; a budget additionally bounds file count, per-file
-    size, and wall clock."""
-    out = []
+    Symlinks are never followed, to a directory or to a file, so a
+    link farm cannot pull the walk outside ``root``; a budget
+    additionally bounds file count, per-file size, and wall clock.
+    The order is ``os.walk``'s (a directory's files, names sorted,
+    then its directories as the file system lists them); the
+    directory's own listing says what is a regular file, so a file
+    costs one ``lstat`` for its size where ``os.walk`` and
+    ``os.path`` cost three (a tree of 40,000 files on a slow file
+    system is walked in seconds of system calls)."""
     skip_dirs = _clean_skip(skip_dirs)
     skip_files = _clean_skip(skip_files)
     root_prefix = posixpath.normpath(
@@ -189,31 +195,54 @@ def walk_fs(root: str, skip_dirs: list = (),
         return rel in skips or \
             posixpath.join(root_prefix, rel) in skips
 
-    for dirpath, dirnames, filenames in os.walk(root):
-        rel_dir = os.path.relpath(dirpath, root).replace(os.sep, "/")
-        if rel_dir == ".":
-            rel_dir = ""
-        dirnames[:] = [
-            d for d in dirnames
-            if not skipped(posixpath.join(rel_dir, d), skip_dirs)]
-        for name in sorted(filenames):
-            rel = posixpath.join(rel_dir, name)
+    def walk(dirpath: str, rel_dir: str):
+        try:
+            with os.scandir(dirpath) as it:
+                entries = list(it)
+        except OSError:
+            return      # as os.walk: what cannot be listed is passed
+        files = [e for e in entries
+                 if e.is_file(follow_symlinks=False)]
+        for e in sorted(files, key=lambda e: e.name):
+            rel = posixpath.join(rel_dir, e.name)
             if skipped(rel, skip_files):
                 continue
-            full = os.path.join(dirpath, name)
-            if not os.path.isfile(full) or os.path.islink(full):
-                continue
-            size = os.path.getsize(full)
+            size = e.stat(follow_symlinks=False).st_size
             if budget is not None:
                 budget.check_deadline()
                 budget.charge_entry()
                 budget.check_file_size(size, rel)
-            out.append((rel, size, _file_reader(full)))
-    return out
+            yield rel, size, _file_reader(e.path, size)
+        for e in entries:
+            if e.is_dir(follow_symlinks=False):
+                rel = posixpath.join(rel_dir, e.name)
+                if not skipped(rel, skip_dirs):
+                    yield from walk(e.path, rel)
+
+    yield from walk(root, "")
 
 
-def _file_reader(full: str) -> Callable:
+def _file_reader(full: str, size: int) -> Callable:
+    """The file's bytes by ``os.read`` to the end of the file: the
+    first read asks for a byte more than the listing's ``size``, so
+    a file that has not grown is read in one call and the empty read
+    that says it ended (four system calls where a buffered
+    ``open().read()`` makes six). A read may come back short before
+    the end (one call gives 0x7ffff000 bytes at the most; a network
+    or FUSE file system gives what it has), so only an empty read
+    ends the loop."""
     def read() -> bytes:
-        with open(full, "rb") as f:
-            return f.read()
+        fd = os.open(full, os.O_RDONLY)
+        try:
+            chunks = []
+            want = size + 1
+            while True:
+                data = os.read(fd, want)
+                if not data:
+                    break
+                chunks.append(data)
+                want = max(want - len(data), 1 << 20)
+            return b"".join(chunks)
+        finally:
+            os.close(fd)
     return read

@@ -2118,7 +2118,6 @@ def _run_image_batch(args, targets: list) -> int:
     """``image a.tar b.tar ...``: the fleet path — every target
     routes through the continuous-batching scheduler (``--sched off``
     keeps the direct single-batch ladder for differential runs)."""
-    from .runtime import BatchScanRunner
     if getattr(args, "server", ""):
         print("error: multi-image batch scan is local-only; scan "
               "one target at a time against --server",
@@ -2134,7 +2133,6 @@ def _run_image_batch(args, targets: list) -> int:
     checks = [c for c in args.security_checks.split(",") if c]
     store = _store(args) if "vuln" in checks else AdvisoryStore()
     opt = _artifact_option(args)
-    backend = args.backend
     injector = _fault_injector(args)
     cache = _cache(args)
     if injector is not None:
@@ -2165,16 +2163,8 @@ def _run_image_batch(args, targets: list) -> int:
     if rc:
         return rc
     _resolve_device(args)
-    runner = BatchScanRunner(
-        store=store, cache=cache, backend=backend,
-        secret_scanner=opt.secret_scanner,
-        sched=("on" if args.sched == "on" else "off"),
-        sched_config=sched_config,
-        artifact_option=opt,
-        fault_injector=injector,
-        dispatch_depth=getattr(args, "dispatch_depth", 0) or 0,
-        memo=_memo(args, cache, option=opt, injector=injector)
-        if "vuln" in checks else None)
+    runner = _batch_runner(args, store, opt, cache, sched_config,
+                           injector)
     options = _scan_options(args)
     if injector is not None and injector.spec.deadline_s > 0:
         # deadline-storm scenario: the spec carries the per-request
@@ -2199,6 +2189,25 @@ def _run_image_batch(args, targets: list) -> int:
               f"({get_tracer().n_exported} total this process)",
               file=sys.stderr)
     return _finish_many(args, results)
+
+
+def _batch_runner(args, store, opt, cache, sched_config,
+                  injector=None, warm: bool = True):
+    """The fleet runner as the command's flags configure it: for
+    several images (``--sched`` chooses its path) and for a tree."""
+    from .runtime import BatchScanRunner
+    checks = [c for c in args.security_checks.split(",") if c]
+    return BatchScanRunner(
+        store=store, cache=cache, backend=args.backend,
+        secret_scanner=opt.secret_scanner,
+        sched=("on" if args.sched == "on" else "off"),
+        sched_config=sched_config,
+        artifact_option=opt,
+        fault_injector=injector,
+        dispatch_depth=getattr(args, "dispatch_depth", 0) or 0,
+        warm=warm,
+        memo=_memo(args, cache, option=opt, injector=injector)
+        if "vuln" in checks else None)
 
 
 def _process_stats(stats: dict) -> dict:
@@ -2394,6 +2403,14 @@ def run_fs(args) -> int:
               file=sys.stderr)
         return 1
     _resolve_device(args)
+    checks = args.security_checks.split(",")
+    if not getattr(args, "server", "") and \
+            ("secret" in checks or "vuln" in checks):
+        return _run_fs_scheduled(args)
+    # the direct path: a --server client, which owns no device and
+    # pushes its blob to the server's cache, and a scan with nothing
+    # for the device (``config``, licences alone), which needs no
+    # scheduler
     cache = _cache(args)
     artifact = LocalFSArtifact(args.target, cache,
                                option=_artifact_option(args))
@@ -2413,6 +2430,46 @@ def run_fs(args) -> int:
         results=results,
     )
     return _finish(args, report)
+
+
+def _run_fs_scheduled(args) -> int:
+    """``fs``/``rootfs`` with secret or vulnerability checks, on
+    the scheduler: the tree is one request of
+    ``BatchScanRunner.scan_trees``, whose secret candidates stream
+    through the sieve in parts while the walk goes on
+    (``submit_tree``); the report is the direct path's."""
+    checks = [c for c in args.security_checks.split(",") if c]
+    store = _store(args) if "vuln" in checks else AdvisoryStore()
+    cache = _cache(args)
+    try:
+        sched_config = _sched_config(args)
+    except ValueError as e:
+        print(f"error: --tenant-config/--tenant-budget: {e}",
+              file=sys.stderr)
+        return 2
+    # one tree and the process ends: the rungs it meets compile (or
+    # come from the persistent cache) as it meets them, as on the
+    # direct path before; warming the whole ladder first would cost
+    # every push's scan seconds it cannot win back
+    runner = _batch_runner(args, store, _artifact_option(args),
+                           cache, sched_config, warm=False)
+    try:
+        res = runner.scan_trees([args.target],
+                                _scan_options(args))[0]
+        stats = runner.last_stats
+    finally:
+        runner.close()
+    if getattr(args, "sched_stats", False):
+        print(json.dumps(_process_stats(stats["sched"]), indent=2),
+              file=sys.stderr)
+    if res.error:
+        print(f"error: {res.name}: {res.error}", file=sys.stderr)
+        return 1
+    if res.status == "degraded":
+        causes = "; ".join(f"{c.stage}/{c.kind}" for c in res.causes)
+        print(f"warning: {res.name}: degraded ({causes})",
+              file=sys.stderr)
+    return _finish(args, res.report)
 
 
 if __name__ == "__main__":

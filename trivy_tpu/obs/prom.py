@@ -393,7 +393,14 @@ def render_prometheus(stats: dict, phase_hists=None,
                  "File bytes handed to the analyzers in analyzed "
                  "layers."),
                 ("base_layers_skipped",
-                 "Base-image layers left out of the secret scan.")):
+                 "Base-image layers left out of the secret scan."),
+                ("tree_files",
+                 "Regular files met by streamed tree walks."),
+                ("tree_files_skipped",
+                 "Of them, files that were no secret candidate."),
+                ("tree_bytes",
+                 "Candidate bytes streamed to the sieve from "
+                 "trees.")):
             w.scalar(f"{_PREFIX}_ingest_{k}_total", "counter",
                      help_, ingest.get(k))
 

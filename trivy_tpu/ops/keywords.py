@@ -300,6 +300,15 @@ def _bucket(n: int, base: int = 256, cap: int = 4096) -> int:
     return ((n + cap - 1) // cap) * cap
 
 
+# One part of a streamed tree (runtime/batch.submit_tree): candidate
+# files are cut, whole, into sieve batches of at most this many segment
+# rows, the ladder's top rung that ``runtime/aot.warm_ladders`` warms
+# (about 16 MB of text), so a tree of any size dispatches shapes the
+# ladder already has. A file with more rows rides alone at the
+# ``cap``-steps above it.
+PART_ROWS = 8192
+
+
 def pad_batch(segments: np.ndarray) -> np.ndarray:
     B = segments.shape[0]
     Bp = _bucket(B)

@@ -262,7 +262,10 @@ def sieve_rungs(max_batch_bytes: int, seg_len: int,
     plus one a file at the most, so twice the budget over the step
     bounds a full batch of small files and one image of up to about
     twice the budget alike. A larger image compiles its rung when it
-    comes, as before."""
+    comes, as before. A streamed tree's parts are cut to
+    ``ops.keywords.PART_ROWS`` rows, the top rung at the default
+    budget; a scheduler configured with a smaller one leaves a
+    tree's full parts to compile when they come."""
     from ..ops.keywords import _bucket
     top = _bucket(2 * max_batch_bytes // (seg_len - overlap))
     return _rungs(_bucket, top)
@@ -330,7 +333,8 @@ def precompile_dfa_shapes(scanner, buckets: Iterable[int],
 
     table, seg_len = scanner.table, scanner.seg_len
     manifest = _Manifest(cache_dir)
-    out = {"kernel": "dfa_fused", "shapes": [], "seconds": 0.0}
+    out = {"kernel": "dfa_fused", "shapes": [], "full_shapes": [],
+           "seconds": 0.0}
     tbl = table.device_tables()
     fn = table.fused_sieve(tuple(scanner.plan.run_specs),
                            jax.default_backend())
@@ -344,6 +348,23 @@ def precompile_dfa_shapes(scanner, buckets: Iterable[int],
                          "rules_hash": table.rules_hash})
         out["shapes"].append(b)
         out["seconds"] += dt
+    # a rung above SIEVE_CAP can bring more hit rows than the
+    # compacted fetch holds, and the whole mask then comes back by
+    # the full variant (secret/batch._decode): of a real source
+    # tree's parts most do (PERF.md section 4), so it is warmed too
+    from ..ops.dfa import SIEVE_CAP
+    full = table.full_sieve((), jax.default_backend())
+    for b in out["shapes"]:
+        if b <= SIEVE_CAP:
+            continue
+        seg = jax.device_put(np.zeros((b, seg_len), np.uint8))
+        key = cache_key("dfa_full", f"B{b}xL{seg_len}",
+                        table.rules_hash)
+        out["seconds"] += _warm_call(
+            full, (seg,) + tuple(tbl), key, manifest,
+            {"kernel": "dfa_full", "B": b,
+             "rules_hash": table.rules_hash})
+        out["full_shapes"].append(b)
     out["seconds"] = round(out["seconds"], 4)
     return out
 

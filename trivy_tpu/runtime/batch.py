@@ -25,7 +25,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional
 
-from ..artifact.artifact import ArtifactOption, ImageArtifact
+from ..artifact.artifact import (ArtifactOption, ImageArtifact,
+                                 LocalFSArtifact)
 from ..artifact.cache import MemoryCache
 from ..artifact.image import load_image
 from ..db import AdvisoryStore
@@ -76,7 +77,7 @@ class BatchScanRunner:
                  secret_scanner=None, sched="off",
                  sched_config=None, artifact_option=None,
                  fault_injector=None, tracer=None, memo=None,
-                 dispatch_depth: int = 0):
+                 dispatch_depth: int = 0, warm: bool = True):
         from ..obs.trace import get_tracer
         from .ring import resolve_dispatch_depth
         self.store = store or AdvisoryStore()
@@ -112,6 +113,11 @@ class BatchScanRunner:
         # "on"/SchedConfig/ScanScheduler = continuous batching with
         # pipelined host/device overlap (trivy_tpu.sched)
         self.sched_config = sched_config
+        # warm: run the pad ladders' programs before the first
+        # request (runtime/aot.warm_ladders). A process that scans
+        # one target and exits (``cli fs``) says False and compiles
+        # only the rungs its target meets, when it meets them
+        self.warm = warm
         self._scheduler = None
         self._owns_scheduler = False
         if hasattr(sched, "submit"):       # a ScanScheduler
@@ -146,8 +152,8 @@ class BatchScanRunner:
             self._owns_scheduler = True
             from .aot import warm_ladders
             from .device import on_accelerator
-            if self.backend != "cpu-ref" and self.mesh is None \
-                    and on_accelerator():
+            if self.warm and self.backend != "cpu-ref" \
+                    and self.mesh is None and on_accelerator():
                 # every shape a batch can take compiles now, or
                 # comes from the persistent cache, and not in the
                 # middle of the fleet
@@ -389,6 +395,113 @@ StreamingImageSource`: layer blobs decompress into the scan as they
                                 trace_id=trace_id,
                                 parent_span_id=parent_span_id))
 
+    # --- a directory tree: one request that streams ---
+
+    def scan_trees(self, paths: list,
+                   options: Optional[ScanOptions] = None) -> list:
+        """Scan directory trees (``fs``, ``rootfs``), one report
+        each, as ``scan_paths`` scans images. A tree always rides
+        the scheduler (:meth:`submit_tree`); a tree that cannot be
+        scanned fails its own slot."""
+        options = options or ScanOptions(backend=self.backend)
+        reqs = [self.submit_tree(p, options, block=True)
+                for p in paths]
+        out, stats = [], []
+        for path, req in zip(paths, reqs):
+            try:
+                out.append(req.result())
+            except Exception as e:       # noqa: BLE001 (the slot's)
+                out.append(_failed_slot(path, e,
+                                        trace_id=req.trace_id,
+                                        tracer=self.tracer))
+            stats.append(req.tree_stats)
+        self.last_stats = {"trees": stats,
+                           "sched": self.scheduler.stats()}
+        return out
+
+    def submit_tree(self, root: str,
+                    options: Optional[ScanOptions] = None,
+                    tenant: str = "", priority: int = 0,
+                    block: bool = False):
+        """Enqueue ONE directory tree and return its ScanRequest
+        future, as :meth:`submit_path` does for an image. The
+        request walks the tree once, on a host worker, and yields
+        its device work in parts while it walks: the secret
+        candidates are cut, whole files, into sieve batches of one
+        warmed rung (``secret.batch.PartCutter``), each launched
+        through the scheduler's ring as it closes
+        (``ScanScheduler.submit_part``), so the walk and pack of
+        part k+1 overlap the sieve of part k and the verify of part
+        k-1, and the tree's bytes held on the host are those of the
+        parts in flight: one slot of the ring each, one more
+        waiting for a slot, and the one the walk is filling. The
+        other analyzers run in the walk; the tree's interval jobs
+        ride the scheduler's waves once the blob is written, as an
+        image's do; the report is built once, from one blob."""
+        options = options or ScanOptions(backend=self.backend)
+        sched = self.scheduler
+        return sched.submit(
+            self._tree_request(sched, root, options, tenant=tenant,
+                               priority=priority), block=block)
+
+    def _tree_request(self, sched, root: str, options,
+                      tenant: str = "", priority: int = 0):
+        from ..sched import AnalyzedWork, ScanRequest
+
+        scan_secrets = "secret" in options.security_checks
+
+        def analyze(req):
+            db = self._hold_store(req)
+            opt = self._image_opt(scan_secrets)
+            stream = _TreeStream(sched, req, self.secret_scanner) \
+                if opt.scan_secrets else None
+            req.tree_stats = stream.stats if stream else {}
+            ref = LocalFSArtifact(root, self.cache, opt).inspect(
+                stream)
+            scanner = LocalScanner(self.cache, db, memo=self.memo)
+            prepared = scanner.prepare(
+                ScanTarget(name=ref.name, artifact_id=ref.id,
+                           blob_ids=ref.blob_ids), options)
+
+            def finish(found, detected):
+                results, os_found = scanner.finish(prepared,
+                                                   detected)
+                return BatchScanResult(
+                    name=root,
+                    report=Report(artifact_name=root,
+                                  artifact_type="filesystem",
+                                  metadata=Metadata(os=os_found),
+                                  results=results))
+
+            return AnalyzedWork(jobs=prepared.jobs, finish=finish)
+
+        req = ScanRequest(name=root, analyze=analyze,
+                          deadline_s=getattr(options, "deadline_s",
+                                             0.0) or 0.0,
+                          tenant=tenant, priority=priority)
+        req.tree_stats = {}
+        return req
+
+    def _hold_store(self, req):
+        """The advisory store for one request's analyze. A
+        SwappableStore's reader is held from here to the request's
+        resolution, so a DB hot swap waits for this scan (the
+        server's acquire/release contract): chained AFTER any
+        caller-provided on_done, released exactly once at whatever
+        resolution path fires first."""
+        db, release = self._store_view()
+        if release is not None:
+            prev = req.on_done
+
+            def _done(r, _prev=prev, _rel=release):
+                try:
+                    if _prev is not None:
+                        _prev(r)
+                finally:
+                    _rel()
+            req.on_done = _done
+        return db
+
     def _image_request(self, sched, name: str, image, options,
                        tenant: str = "", priority: int = 0,
                        trace_id: str = "", parent_span_id: str = "",
@@ -404,22 +517,7 @@ StreamingImageSource`: layer blobs decompress into the scan as they
                 # slot only; a slow-host stall eats into the deadline
                 inj.on_host_analyze(name)
                 inj.on_image_load(name)
-            db, release = self._store_view()
-            if release is not None:
-                # the reader is held from analyze to resolution so a
-                # DB hot swap waits for this scan (the server's
-                # acquire/release contract); chained AFTER any
-                # caller-provided on_done, released exactly once at
-                # whatever resolution path fires first
-                prev = req.on_done
-
-                def _done(r, _prev=prev, _rel=release):
-                    try:
-                        if _prev is not None:
-                            _prev(r)
-                    finally:
-                        _rel()
-                req.on_done = _done
+            db = self._hold_store(req)
             budget = self._ingest_budget(name)
             # loader: registry seam (scan_registry_refs) — builds a
             # StreamingImageSource (or a pulled one) instead of
@@ -842,17 +940,7 @@ StreamingImageSource`: layer blobs decompress into the scan as they
 
         def analyze(req):
             from ..artifact.sbom import decode_to_blob
-            db, release = self._store_view()
-            if release is not None:
-                prev = req.on_done
-
-                def _done(r, _prev=prev, _rel=release):
-                    try:
-                        if _prev is not None:
-                            _prev(r)
-                    finally:
-                        _rel()
-                req.on_done = _done
+            db = self._hold_store(req)
             # a malformed document fails its own slot, never the
             # fleet (ValueError resolves this request only)
             atype, decoded, blob, blob_id = decode_to_blob(data)
@@ -994,6 +1082,91 @@ StreamingImageSource`: layer blobs decompress into the scan as they
             if jobs_in else 0.0,
         }
         return ordered
+
+
+class _TreeStream:
+    """One tree's secret candidates on their way through the
+    scheduler in parts: what ``LocalFSArtifact.inspect`` streams
+    into. ``emit`` hands a closed part to
+    ``ScanScheduler.submit_part`` and returns at once unless as many
+    parts are unresolved as the ring has slots and one more (the
+    one waiting for a slot); with the part the walk is filling,
+    those are all of the tree's bytes the host holds. A part's
+    bytes are let go when its findings are delivered. A part that
+    fails fails the tree: the error is raised where the walk next
+    meets the stream."""
+
+    def __init__(self, sched, req, scanner):
+        import threading
+
+        from ..secret.batch import PartCutter
+        self.sched, self.req = sched, req
+        self.cutter = PartCutter(scanner)
+        self.in_flight = max(1, sched.config.dispatch_depth) + 1
+        self._free = threading.Semaphore(self.in_flight)
+        self._lock = threading.Lock()
+        self._found: list = []       # (seq, Secret)
+        self._error: Optional[BaseException] = None
+        self._held = 0               # bytes of unresolved parts
+        # peak_held_bytes: the most candidate bytes the tree held
+        # at once, unresolved parts and the open one together
+        self.stats = {"parts": 0, "files": 0, "bytes": 0,
+                      "peak_held_bytes": 0}
+
+    def _take(self) -> None:
+        """One unresolved part's place, waited for; a tree whose
+        deadline passes or that is cancelled stops waiting."""
+        from ..sched import DeadlineExceeded, RequestCancelled
+        while not self._free.acquire(timeout=0.5):
+            if self.req.cancelled:
+                raise RequestCancelled(
+                    f"scan {self.req.name!r}: cancelled")
+            if self.req.expired():
+                raise DeadlineExceeded(
+                    f"scan {self.req.name!r}: deadline exceeded")
+        if self._error is not None:
+            raise self._error
+
+    def emit(self, part: list) -> None:
+        seqs = [seq for seq, _, _ in part]
+        files = [(path, content) for _, path, content in part]
+        del part
+        nbytes = sum(len(c) for _, c in files)
+        with self._lock:
+            st = self.stats
+            st["parts"] += 1
+            st["files"] += len(files)
+            st["bytes"] += nbytes
+            st["peak_held_bytes"] = max(
+                st["peak_held_bytes"],
+                self._held + nbytes + self.cutter.open_bytes)
+        self._take()
+        with self._lock:
+            self._held += nbytes
+
+        def deliver(found: list) -> None:
+            self._found.extend((seqs[j], secret)
+                               for j, secret in found)
+            del files[:]             # the part's bytes go here
+
+        def on_done(part_req) -> None:
+            try:
+                part_req.result(timeout=0)
+            except Exception as e:   # noqa: BLE001 (the tree's now)
+                if self._error is None:
+                    self._error = e
+            with self._lock:
+                self._held -= nbytes
+            self._free.release()
+
+        self.sched.submit_part(self.req, files, deliver, on_done)
+
+    def finish(self) -> list:
+        """Every part back: the Secrets, in the files' order."""
+        for _ in range(self.in_flight):
+            self._take()
+        self._found.sort(key=lambda found: found[0])
+        return [secret for _, secret in self._found]
 
 
 class _CollectingImageArtifact(ImageArtifact):

@@ -136,6 +136,8 @@ class Coalescer:
                 nbytes, njobs = self._volume(reqs)
                 if (nbytes >= cfg.max_batch_bytes
                         or njobs >= cfg.max_batch_jobs
+                        # rides alone: nothing to wait for
+                        or reqs[0].work.alone
                         or len(reqs) >= cfg.max_batch_items
                         or now - self._oldest[group]
                         >= cfg.flush_timeout_s
@@ -160,7 +162,9 @@ class Coalescer:
                 if batch.requests and (
                         batch.candidate_bytes + rb
                         > cfg.max_batch_bytes
-                        or batch.jobs + rj > cfg.max_batch_jobs):
+                        or batch.jobs + rj > cfg.max_batch_jobs
+                        or r.work.alone
+                        or batch.requests[0].work.alone):
                     break
                 reqs.pop(0)
                 batch.requests.append(r)

@@ -67,6 +67,10 @@ class AnalyzedWork:
     finish: Optional[Callable] = None  # (found, detected)->result
     deps: list = field(default_factory=list)  # events to await
     group: str = ""                    # batch-compatibility key
+    # one part of a request that yields its device work in parts
+    # (ScanScheduler.submit_part): cut to a rung of the sieve's
+    # ladder already, so it shares its batch with nothing
+    alone: bool = False
 
     @property
     def candidate_bytes(self) -> int:
@@ -119,6 +123,11 @@ class ScanRequest:
         # set by the scheduler when analyze left nothing for the
         # device: the monotonic time its wait for a result began
         self.no_device_since: Optional[float] = None
+        # the request this one is a part of (submit_part), or None.
+        # A part was never admitted: it holds no queue slot and no
+        # tenant quota and is booked under no outcome; its parent,
+        # whose analyze waits for it, is
+        self.part_of: Optional["ScanRequest"] = None
         self._done = threading.Event()
         self._result = None
         self._error: Optional[BaseException] = None
@@ -163,7 +172,8 @@ class ScanRequest:
 
     @property
     def cancelled(self) -> bool:
-        return self._cancelled
+        return self._cancelled or (self.part_of is not None
+                                   and self.part_of.cancelled)
 
     @property
     def done(self) -> bool:

@@ -61,7 +61,7 @@ from ..ops.program import DeviceProgramError
 from ..utils import get_logger, sparse_full_gc
 from .coalescer import Batch, Coalescer, SchedConfig
 from .metrics import SchedMetrics
-from .queue import (DeadlineExceeded, QueueFullError,
+from .queue import (AnalyzedWork, DeadlineExceeded, QueueFullError,
                     RequestCancelled, ScanRequest, SchedulerClosed)
 from .tenant import RateLimitedError, TenantQueue
 
@@ -309,6 +309,41 @@ class ScanScheduler:
         the metrics object."""
         return self.metrics.in_flight()
 
+    def submit_part(self, parent: ScanRequest, candidates: list,
+                    deliver, on_done=None) -> ScanRequest:
+        """One part of a request that yields its device work in
+        parts. Called from ``parent``'s analyze, any number of
+        times, before it returns: ``candidates`` (``[(path,
+        bytes)]``, cut by the caller to one rung of the sieve's
+        ladder) go to the device as an ordinary sieve batch, which
+        they share with nothing, while the parent's analyze goes on
+        (a tree's walk: ``runtime/batch.submit_tree``). On the
+        collecting thread ``deliver(found)`` gets the part's
+        ``(index, Secret)`` pairs; ``on_done(part)`` runs when the
+        part is resolved either way, after which ``part.result(0)``
+        returns or raises. Bisect, quarantine and the host fallback
+        see a part as they see a request, and a fault the part
+        survived is its parent's. The part is never admitted: the
+        caller bounds how many it keeps in flight, and waits for all
+        of them before the parent's analyze returns."""
+        part = ScanRequest(
+            name=f"{parent.name}#part", analyze=None,
+            group=parent.group, on_done=on_done,
+            trace_id=parent.trace_id or "", tenant=parent.tenant,
+            priority=parent.priority)
+        part.part_of = parent
+        part.deadline = parent.deadline
+        part.span_root = self.tracer.child(parent.span_root, "part")
+        part.work = AnalyzedWork(candidates=candidates,
+                                 patch=deliver, alone=True,
+                                 group=parent.group)
+        part.span_coalesce = self.tracer.child(part.span_root,
+                                               "coalesce")
+        self.coalescer.add(part)
+        with self._cv:
+            self._cv.notify_all()
+        return part
+
     def stats(self) -> dict:
         out = self.metrics.snapshot()
         out["config"] = {
@@ -438,6 +473,12 @@ class ScanScheduler:
 
     def _fail(self, req: ScanRequest, err: BaseException) -> None:
         self._clear_blob_writes(req)
+        if req.part_of is not None:
+            # the parent's analyze meets the error where it waits
+            # for the part, and the parent is what gets booked
+            if req.set_error(err):
+                self._end_trace(req, "failed", err)
+            return
         if req.set_error(err):
             latency = time.monotonic() - req.submitted_at
             if isinstance(err, DeadlineExceeded):
@@ -848,6 +889,10 @@ class ScanScheduler:
         results = {id(r): (found_by.get(i, []),
                            detected_by.get(i, []))
                    for i, r in enumerate(reqs)}
+        # the ring's drain thread keeps its last slot until the
+        # next one comes: the packed buffer and the files' bytes
+        # are not kept with it
+        slot["sieve"] = None
         self._resolve_safe(reqs, results)
 
     def _sync_fallback(self, reqs: list, group: str,
@@ -911,6 +956,14 @@ class ScanScheduler:
             except Exception as e:   # noqa: BLE001
                 log.warning("patch %r failed: %r", r.name, e)
                 self._fail(r, e)
+                continue
+            if r.part_of is not None:
+                # a part ends here, on the collecting thread: its
+                # findings are with its parent, which also takes
+                # over what the part survived
+                r.part_of.faults.extend(r.faults)
+                if r.set_result(None):
+                    self._end_trace(r, "ok")
                 continue
             r.patched_event.set()
             self._clear_blob_writes(r)

@@ -39,8 +39,8 @@ from typing import Iterable, Optional
 
 import numpy as np
 
-from ..ops.keywords import (MAX_CODE_LEN, N_BLOCKS, _bucket,
-                            pad_batch)
+from ..ops.keywords import (MAX_CODE_LEN, N_BLOCKS, PART_ROWS,
+                            _bucket, pad_batch)
 from ..utils import get_logger
 from .plan import ScanPlan, build_scan_plan
 from .scanner import Scanner
@@ -95,6 +95,52 @@ def rules_fingerprint(scanner=None) -> str:
     except AttributeError:
         pass
     return fp
+
+
+class PartCutter:
+    """Cuts a stream of candidate files, whole, into sieve batches
+    of at most ``PART_ROWS`` segment rows (``ops.keywords``: the
+    ladder's top warmed rung): ``add`` returns the part a file
+    closed, or None. A file with more rows than a part is a part of
+    its own, at the ladder's steps above the rung, and is counted.
+    A file is ``(seq, path, content)``: parts come back in the order
+    they closed, which an oversize file makes another order than the
+    files', and ``seq`` brings the findings home."""
+
+    def __init__(self, scanner: "BatchSecretScanner"):
+        self.scanner = scanner
+        self.part_rows = PART_ROWS
+        self._files: list = []
+        self._rows = 0
+        self._seq = 0
+        self.open_bytes = 0          # of the part still open
+
+    def add(self, path: str, content: bytes) -> Optional[list]:
+        from .metrics import SECRET_METRICS
+        item = (self._seq, path, content)
+        self._seq += 1
+        rows = self.scanner._n_segs(len(content))
+        if rows > self.part_rows:
+            SECRET_METRICS.inc("tree_oversize_files")
+            SECRET_METRICS.inc("tree_parts")
+            return [item]
+        closed = None
+        if self._rows + rows > self.part_rows:
+            closed = self.flush()
+        self._files.append(item)
+        self._rows += rows
+        self.open_bytes += len(content)
+        return closed
+
+    def flush(self) -> Optional[list]:
+        """The part still open, or None."""
+        from .metrics import SECRET_METRICS
+        if not self._files:
+            return None
+        closed, self._files, self._rows = self._files, [], 0
+        self.open_bytes = 0
+        SECRET_METRICS.inc("tree_parts")
+        return closed
 
 
 @dataclass
@@ -524,6 +570,8 @@ class BatchSecretScanner:
                 cm = handle["cm"]
                 h = handle["h"]
                 if nhit > min(cm.shape[0], handle["padded_rows"]):
+                    from .metrics import SECRET_METRICS
+                    SECRET_METRICS.inc("sieve_full_fetches")
                     # fetch the full mask array; run hits (h) were
                     # already computed by the fused dispatch. The
                     # fused dispatch DONATED its segment buffer

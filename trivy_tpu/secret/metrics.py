@@ -37,6 +37,13 @@ class SecretMetrics:
         "dfa_invalidations",
         # async sharded submission (parallel/secret_shard.py)
         "shards_dispatched", "decode_tasks",
+        # dispatches whose hit rows passed SIEVE_CAP: the whole mask
+        # fetched by a second dispatch (secret/batch._decode)
+        "sieve_full_fetches",
+        # a streamed tree (runtime/batch.submit_tree): the parts its
+        # candidates were cut into, and the files with more rows
+        # than a part, each of which rode alone
+        "tree_parts", "tree_oversize_files",
     )
 
     def __init__(self):
