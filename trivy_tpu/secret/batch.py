@@ -18,6 +18,9 @@ pkg/fanal/secret/scanner.go:341):
      rule resolves fully on-device); for rules whose regex is provably
      anchor-bounded (rx.anchor), a preliminary regex over small
      windows around anchor hits decides whether the rule can match;
+     a rule whose only unbounded parts are whitespace runs
+     (rx.anchor.space_elastic) gets the regions round its chain's
+     and keyword's hits, where its exact verify then runs;
   4. files with surviving rules get a CPU-exact scan restricted to
      those rules — byte-identical findings, because every rule that
      could contribute findings (or censoring) survives the sieve.
@@ -43,6 +46,7 @@ from ..ops.keywords import (MAX_CODE_LEN, N_BLOCKS, PART_ROWS,
                             _bucket, pad_batch)
 from ..utils import get_logger
 from .plan import ScanPlan, build_scan_plan
+from .rx.anchor import SPACE_1TO1
 from .scanner import Scanner
 
 log = get_logger("secret.batch")
@@ -378,7 +382,7 @@ class BatchSecretScanner:
         candidates = self._decode(handle)
 
         results = []
-        rules_verified = windowed = wholefile = 0
+        rules_verified = windowed = wholefile = verify_bytes = 0
         # the verify tail is a collect-side host phase: the timeline
         # attributes device idle under it to collect_bound
         with phase_span("verify", pipeline="secret",
@@ -397,13 +401,15 @@ class BatchSecretScanner:
                                   regions=regions)
                 # count AFTER the scan: multibyte files silently
                 # fall back whole-file inside Scanner.scan
-                if getattr(sub, "used_regions", False):
-                    windowed += sum(1 for r in regions
-                                    if r is not None)
-                    wholefile += sum(1 for r in regions
-                                     if r is None)
-                else:
-                    wholefile += len(regions)
+                if not getattr(sub, "used_regions", False):
+                    regions = [None] * len(regions)
+                for spans in regions:
+                    if spans is None:
+                        wholefile += 1
+                        verify_bytes += len(fe.content)
+                    else:
+                        windowed += 1
+                        verify_bytes += sum(b - a for a, b in spans)
                 if secret.findings:
                     results.append((fe.index, secret))
 
@@ -418,6 +424,7 @@ class BatchSecretScanner:
             "rules_verified": rules_verified,
             "rules_windowed": windowed,
             "rules_wholefile": wholefile,
+            "verify_bytes": verify_bytes,
             "rules_chain_gated": handle.get("chain_gated", 0),
             "files_with_findings": len(results),
             "sieve_s": round(handle.get("pack_s", 0.0)
@@ -522,7 +529,9 @@ class BatchSecretScanner:
 
         A rule maps to merged byte spans when its window proof is
         extraction-exact (the host then regexes only those spans); to
-        None when it needs the reference's whole-file scan."""
+        None when it needs the reference's whole-file scan. A rule
+        that is space-elastic (rx.anchor.space_elastic) maps to the
+        regions round its chain's and keyword's hits."""
         from ..obs.trace import phase_span
         if handle["mode"] == "empty":
             return {}
@@ -649,6 +658,13 @@ class BatchSecretScanner:
             return not rp.run_gate or \
                 set(rp.run_gate) <= file_runs(fidx)
 
+        def verify_on(chosen, fe, rp, codes) -> None:
+            # the whole file (None), or a space-elastic rule's
+            # regions; no region at all clears the rule
+            spans = self._regions(fe, rp, codes, blk)
+            if spans is None or spans:
+                chosen[rp.rule_index] = spans
+
         # rules with no keyword gate and no anchor run everywhere
         # (reference: empty keyword list passes MatchKeywords),
         # unless their DFA chain or a mandatory class-run is
@@ -664,7 +680,7 @@ class BatchSecretScanner:
                         chain_gated += 1
                         continue
                     if runs_pass(rp, fe.index):
-                        sel[rp.rule_index] = None
+                        verify_on(sel, fe, rp, codes)
                 if sel:
                     out[fe.index] = sel
 
@@ -681,7 +697,7 @@ class BatchSecretScanner:
                     continue
                 if not rp.anchored:
                     if rp.gate and runs_pass(rp, fidx):
-                        chosen[rp.rule_index] = None
+                        verify_on(chosen, fe, rp, codes)
                     continue
                 anchor_hits = [h for a in rp.anchors
                                for h in codes.get(a, ())]
@@ -693,7 +709,7 @@ class BatchSecretScanner:
                     # no prelim pass needed (verify IS the prelim)
                     chosen[rp.rule_index] = spans
                 elif self._prelim(fe, rp, spans):
-                    chosen[rp.rule_index] = None
+                    verify_on(chosen, fe, rp, codes)
             if chosen:
                 out[fidx] = chosen
         handle["chain_gated"] = chain_gated
@@ -735,14 +751,54 @@ class BatchSecretScanner:
                 a = pos + j * blk - w
                 b = pos + (j + 1) * blk + w
                 spans.append((max(0, a), min(len(fe.content), b)))
-        spans.sort()
-        merged = []
-        for a, b in spans:
-            if merged and a <= merged[-1][1]:
-                merged[-1] = (merged[-1][0], max(merged[-1][1], b))
-            else:
-                merged.append((a, b))
-        return merged
+        return _merged(spans)
+
+    def _regions(self, fe: _FileEntry, rp, codes: dict,
+                 blk: int) -> Optional[list]:
+        """Merged byte regions for the exact verify of a rule with no
+        extraction-exact window: None, the whole file, unless the
+        rule is space-elastic (``rp.elastic``: rx.anchor.space_elastic
+        holds the proof that ``finditer`` over these regions gives the
+        whole file's matches). Round every hit block of a piece every
+        match contains, widened by the piece's length (a piece that
+        starts in the block may end past it, and one that crosses a
+        segment's end is reported by the next segment), the walk of
+        the rule's reach in the file's own bytes: at most so many
+        bytes, then to the end of the whitespace run the walk stands
+        in or next to, as often as the rule has runs on that side;
+        two bytes more on the right; what touches merged. Two pieces'
+        regions are intersected: a match holds both. ``[]`` says a
+        piece has no hit, so the rule cannot fire. Where the regions
+        are most of the file (a megabyte of base64 is one chain hit
+        after another, and has no keyword to narrow it) the file goes
+        to the regex whole: the merged region is the file anyway."""
+        if not rp.elastic:
+            return None
+        content = fe.content
+        n = len(content)
+        out = None
+        for col, reach in rp.elastic:
+            spans = []
+            for pos, mask in codes.get(col, ()):
+                m = mask
+                while m:
+                    lsb = m & -m
+                    at = pos + (lsb.bit_length() - 1) * blk
+                    m ^= lsb
+                    spans.append((at - reach.length,
+                                  at + blk + reach.length))
+            if not spans:
+                return []
+            walked = _merged([
+                (_walk(content, max(a, 0), reach.left, -1),
+                 min(n, _walk(content, min(b, n), reach.right, 1) + 2))
+                for a, b in _merged(spans)])
+            if 2 * sum(b - a for a, b in walked) > n:
+                continue
+            out = walked if out is None else _intersect(out, walked)
+        if out is None or 2 * sum(b - a for a, b in out) > n:
+            return None
+        return out
 
     def _prelim(self, fe: _FileEntry, rp, merged: list) -> bool:
         """Windowed existence check for rules whose window proof is
@@ -756,3 +812,48 @@ class BatchSecretScanner:
             if rule.regex.search(window):
                 return True
         return False
+
+
+def _merged(spans: list) -> list:
+    """Sorted, and what overlaps or touches made one."""
+    merged: list = []
+    for a, b in sorted(spans):
+        if merged and a <= merged[-1][1]:
+            merged[-1] = (merged[-1][0], max(merged[-1][1], b))
+        else:
+            merged.append((a, b))
+    return merged
+
+
+def _intersect(xs: list, ys: list) -> list:
+    """The bytes in both of two merged span lists."""
+    out = []
+    i = j = 0
+    while i < len(xs) and j < len(ys):
+        a, b = max(xs[i][0], ys[j][0]), min(xs[i][1], ys[j][1])
+        if a < b:
+            out.append((a, b))
+        if xs[i][1] < ys[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
+
+
+def _walk(content: bytes, x: int, steps: tuple, d: int) -> int:
+    """From ``x`` outwards (``d`` -1 left, +1 right) by a rule's
+    reach (rx.anchor.ElasticReach): at most ``steps[0]`` bytes, and
+    before each further reach to the end of the whitespace run the
+    walk stands in or next to."""
+    n = len(content)
+    for i, reach in enumerate(steps):
+        if i and d < 0:
+            while x > 0 and content[x - 1] in SPACE_1TO1:
+                x -= 1
+        elif i:
+            while x < n and content[x] in SPACE_1TO1:
+                x += 1
+        x += d * reach
+        if x <= 0 or x >= n:
+            return max(0, min(x, n))
+    return x

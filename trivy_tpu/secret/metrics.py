@@ -24,9 +24,10 @@ class SecretMetrics:
         "files_with_findings",
         # per-rule verify split (windowed-exact vs whole-file) and
         # rules the on-device DFA chain gate dropped before any host
-        # regex ran
+        # regex ran; verify_bytes: the bytes handed to an exact
+        # regex, a region's length or the file's for a whole-file rule
         "rules_verified", "rules_windowed", "rules_wholefile",
-        "rules_chain_gated",
+        "rules_chain_gated", "verify_bytes",
         # file bytes whose sieve ran on the device (fused or
         # sharded dispatch) — cpu-ref batches add nothing here
         "device_bytes",
@@ -74,6 +75,7 @@ class SecretMetrics:
             c["rules_wholefile"] += stats.get("rules_wholefile", 0)
             c["rules_chain_gated"] += stats.get(
                 "rules_chain_gated", 0)
+            c["verify_bytes"] += stats.get("verify_bytes", 0)
             if stats.get("mode") in ("fused", "sharded"):
                 c["device_bytes"] += stats.get("bytes_total", 0)
             c["sieve_s"] += stats.get("sieve_s", 0.0)

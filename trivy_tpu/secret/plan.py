@@ -21,6 +21,13 @@ and records, per rule:
     on-device, no host regex at all.
   - ``run_gate``: mandatory long class-runs for rules the window
     proof rejects (unchanged from round 4).
+  - ``elastic``: for a rule whose window proof is not
+    extraction-exact but whose only unbounded parts are whitespace
+    runs (rx.anchor.space_elastic): the table columns of pieces
+    every match contains, its chain and any gate keyword the regex
+    itself spells, each with the reach of a match round it. The host
+    verifies such a rule on regions round those columns' hits and
+    not on the whole file.
 
 Overlap contract (the hard error a silent straddle used to hide):
 full-length patterns are only sound when the segment overlap covers
@@ -40,7 +47,8 @@ from typing import Optional
 from ..ops.dfa import (MAX_LIT_BYTES, best_fixed_chain, build_table,
                        chain_len, chain_units)
 from ..ops.runs import RunSpec
-from .rx.anchor import analyze_rule, run_gates, strip_elastic
+from .rx.anchor import (analyze_rule, run_gates, space_elastic,
+                        strip_elastic)
 from .rx.parser import parse
 
 # longest literal the sieve will match full-length; bounded so the
@@ -63,6 +71,8 @@ class RulePlan:
     exact: bool = False           # windowed verify is extraction-exact
     run_gate: list = field(default_factory=list)  # run-spec indices
     chain: Optional[int] = None   # table column, or None
+    # [(table column, rx.anchor.ElasticReach)]; empty = no region proof
+    elastic: list = field(default_factory=list)
 
 
 @dataclass
@@ -128,7 +138,7 @@ def build_scan_plan(rules) -> ScanPlan:
                 core, _ = strip_elastic(parse(r.regex.pattern))
             except Exception:
                 core = None
-        units = None
+        units = classes = None
         # chain policy (cost-driven): anchored rules with an
         # extraction-EXACT window proof AND a selective anchor
         # already resolve on tiny merged spans — a chain would
@@ -149,10 +159,11 @@ def build_scan_plan(rules) -> ScanPlan:
                 units = chain_units(classes)
                 n = chain_len(units)
                 if n > MAX_SIEVE_CHAIN:
-                    units = None
+                    units = classes = None
                 elif n > longest[1]:
                     longest = (r.id, n)
-        analyses.append((kws, ra, core, units))
+        analyses.append((kws, ra, core, units, classes,
+                         r.regex.pattern if core is not None else ""))
         literals.extend(kws)
         if ra is not None:
             literals.extend(ra.literals)
@@ -163,11 +174,25 @@ def build_scan_plan(rules) -> ScanPlan:
     run_specs: list = []
     spec_index: dict = {}
     plans = []
-    for i, (kws, ra, core, units) in enumerate(analyses):
+    for i, (kws, ra, core, units, classes, pattern) in enumerate(
+            analyses):
         rp = RulePlan(rule_index=i,
                       gate=frozenset(table.lit_col(k) for k in kws))
         if units is not None:
             rp.chain = table.chain_col(units)
+        if core is not None and (ra is None or not ra.exact):
+            # the pieces the sieve reports and every match holds: the
+            # chain, and a keyword where the regex spells it on its
+            # spine (the column matches lowered text, so either case)
+            pieces = [(table.lit_col(k),
+                       [frozenset((b, ord(chr(b).upper())))
+                        for b in k]) for k in kws]
+            if units is not None:
+                pieces.insert(0, (rp.chain, list(classes)))
+            for col, accepts in pieces:
+                reach = space_elastic(pattern, accepts)
+                if reach is not None:
+                    rp.elastic.append((col, reach))
         if ra is not None:
             rp.anchored = True
             rp.anchors = sorted({table.lit_col(a)

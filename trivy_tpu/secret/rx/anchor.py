@@ -59,6 +59,12 @@ def max_match_len(node) -> float:
 
 _SPACE = frozenset(b" \t\n\r\f\v")
 
+# every one-byte character a whitespace class (``\\s`` over str) can
+# consume in a text that decoded one character a byte: the parser's
+# six and the four separators U+001C to U+001F (``str.isspace``); a
+# lone surrogate that an invalid byte decodes to is no whitespace
+SPACE_1TO1 = frozenset(b" \t\n\r\f\v\x1c\x1d\x1e\x1f")
+
 
 def _is_space_run(node) -> bool:
     return (isinstance(node, Rep) and node.max is None
@@ -466,3 +472,159 @@ def analyze_rule(pattern: str, max_window: int = 2048) -> RuleAnchor:
     # +2 slack keeps the edge-elastic soundness argument (a truncated
     # whitespace run must retain ≥min+1 bytes inside the window).
     return RuleAnchor(True, lits, int(m) + extra + 2, exact)
+
+
+# ---------------------------------------------------------------------
+# space-elastic rules: exact verify on regions round a sieve hit
+# ---------------------------------------------------------------------
+
+@dataclass
+class ElasticReach:
+    """How far a match can lie from one piece it must contain.
+
+    ``left`` and ``right`` are walks outwards from the piece:
+    ``(r0, r1, ..., rk)`` is "at most r0 bytes, then a whitespace run,
+    then at most r1 bytes, ..., then at most rk bytes" (k runs).
+    ``length`` is the piece's own, in bytes."""
+
+    length: int
+    left: tuple
+    right: tuple
+
+
+def _flat(node) -> list:
+    if isinstance(node, Cat):
+        return [q for p in node.parts for q in _flat(p)]
+    return [node]
+
+
+def _side_walk(parts: list, max_window: int) -> Optional[tuple]:
+    """The walk over ``parts``, listed outwards from the piece; None
+    where a part is unbounded by anything but whitespace."""
+    steps = [0]
+    for p in parts:
+        # a part is bounded, a whitespace run, or a choice between
+        # the two ((^|\s+), (\s+|$), (\s+|;)): the longest bounded
+        # option, then the run; a parse takes one of them and the
+        # walk covers both
+        options = p.options if isinstance(p, Alt) else [p]
+        bounded = [max_match_len(o) for o in options
+                   if not _is_space_run(o)]
+        if INF in bounded:
+            return None
+        steps[-1] += int(max(bounded, default=0))
+        if len(bounded) < len(options):
+            steps.append(0)
+    if sum(steps) > max_window:
+        return None
+    return tuple(steps)
+
+
+def space_elastic(pattern: str, accepts: list,
+                  max_window: int = 2048) -> Optional[ElasticReach]:
+    """The third verdict, beside "anchored, exact" and "unanchored":
+    *space-elastic*. It holds for a rule regex that is a concatenation
+    whose every unbounded repeat is a run over a whitespace-only class
+    (bare, or an option of an alternation whose other options are
+    bounded: ``(^|\\s+)``, ``(\\s+|$)``), and in which the piece
+    ``accepts`` describes, one byte class a position, lies on the
+    spine: a window of the regex's fixed positions (``ops.dfa._atoms``,
+    the positions every match threads through) whose classes are
+    subsets of ``accepts``'. The sieve reports every occurrence of
+    such a piece (a rule's chain, a gate keyword) at a block's
+    resolution, and the verdict says how far from an occurrence a
+    match through it can reach: ``ElasticReach``. None otherwise.
+
+    **What is proved.** Let T be a text that decoded one character a
+    byte (``Scanner.scan`` hands any other file to the whole-file
+    scan), W the one-byte whitespace ``SPACE_1TO1``, and for every
+    occurrence h of the piece in T let R(h) = [a, b) be the region
+    ``secret.batch`` builds: from the block the sieve reported, widened
+    by the piece's length on both sides (the occurrence starts in the
+    block, or lies across its edge or a segment's; either way it lies
+    inside), the walk of ``left`` down to a and of ``right`` up to b,
+    where a step "whitespace run" moves to the end of the maximal run
+    of W the walk stands in or next to; then two bytes more on the
+    right, cut at the file's end; regions that touch or overlap merged.
+    Then ``finditer(T, a, b)`` over the merged regions in order gives
+    the matches of ``finditer(T)``, span for span and group for group.
+
+    *1. Every parse lies in one region.* Call a parse any way the
+    regex, with ``$`` and ``\\b`` read either against T or against T
+    cut at some b, consumes T[p:e): a sequence of pieces, each at most
+    its ``max_match_len`` long, and runs, each over bytes of W. Its
+    bytes are T's, so it holds an occurrence h of the piece at the
+    position its own parts give it, at most ``max_match_len`` of the
+    parts the window touches from either end of them. Walking left
+    from x <= h by a reach r >= the parse's piece length l leaves the
+    walk at or left of the piece's start; if it stands right of the
+    run's start it stands inside the run or at its right end, all of
+    W, and the step carries it to the start of the maximal run, at or
+    left of where the parse's run began. By induction a <= p, and by
+    the mirrored walk e <= b - 2 unless b is the file's end. The walk
+    is monotone in where it starts, so it may start from the ends of a
+    stretch of adjacent hit blocks at once. Merged regions are
+    disjoint, so the parse, which contains p, lies in the merged
+    region that contains p. (Two pieces' region sets may be
+    intersected: the parse lies in both.)
+
+    *2. Every attempt gives what it gives on the whole text.* Python's
+    ``finditer(T, a, b)`` keeps ``^``, ``\\b`` and any look at T[p-1]
+    true to the whole string at a, but treats b as the string's end:
+    ``$`` holds at b, and at b-1 before a newline; ``\\b`` at b sees
+    nothing to the right. The engine is a backtracking one with no
+    look-around (the parser refuses them): the attempt at p returns
+    the first, in the regex's own order of preference, of the parses
+    from p that succeed, and a parse succeeds by its own tests alone.
+    A parse from p in [a, b) that succeeds on T ends at e <= b - 2 (or
+    b is the end and nothing is cut), so each of its tests reads the
+    same on T cut at b: it consumes below b, its ``\\b`` and ``$`` stand
+    at or below e and see T[e], and a ``$`` true on T means e is the
+    end or one before it, which the two bytes of margin make b's too.
+    A parse that succeeds on T cut at b is a parse in the sense of 1,
+    so it too ends at e <= b - 2, where no test can tell the cut. The
+    two sets of successful parses from p are the same set, greedy or
+    lazy, so their first is the same match.
+
+    *3. The sequences agree.* ``finditer`` tries p = 0, 1, ... and
+    after a match goes on from its end (no match of such a rule is
+    empty: it holds the piece). By 1 no match of T starts outside the
+    regions or leaves the one it starts in, so the whole-file scan
+    enters each region at its first byte with nothing pending, as the
+    region-wise scan does, and by 2 both then make the same attempts
+    with the same results.
+
+    What each step of the walk is for: a greedy leading ``\\s+`` cut by
+    a would give a shorter whole match than the file's, and the allow
+    rules read the whole match; a trailing ``\\s+`` cut by b would end a
+    match early and free whitespace the file's scan had consumed
+    before its next attempt. Both are parses that 1 puts inside the
+    region, whatever their length."""
+    from ...ops.dfa import _atoms
+    try:
+        parts = _flat(parse(pattern))
+    except Exception:
+        return None
+    seq: list = []               # (class, part index) or None
+    for pi, p in enumerate(parts):
+        for atom in _atoms(p):
+            if atom is None:
+                seq.append(None)
+            else:
+                seq.extend((cls, pi) for cls in atom)
+    n = len(accepts)
+    for at in range(len(seq) - n + 1 if n else 0):
+        window = seq[at:at + n]
+        if all(w is not None and w[0] <= acc
+               for w, acc in zip(window, accepts)):
+            first, last = window[0][1], window[-1][1]
+            break
+    else:
+        return None
+    # the parts the window touches count on both sides: the piece
+    # may start anywhere inside them
+    left = _side_walk(parts[last::-1], max_window)
+    right = _side_walk(parts[first:], max_window)
+    if left is None or right is None:
+        return None
+    return ElasticReach(n, left, right)

@@ -294,6 +294,10 @@ class _Parser:
         if c == "x":
             h = self.next() + self.next()
             return self._lit(int(h, 16), flags)
+        if c in "123456789":
+            # a backreference is as long as its group, not one byte:
+            # no window or region math holds for it
+            raise RegexParseError(f"backreference in {self.p!r}")
         # escaped metachar / punctuation: literal byte
         return self._lit(ord(c), flags)
 

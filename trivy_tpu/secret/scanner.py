@@ -85,8 +85,10 @@ class Scanner:
 
         ``spans``: optional sorted disjoint (start, end) byte regions —
         when the rule's anchor analysis proved extraction-exactness
-        (rx.anchor RuleAnchor.exact), restricting finditer to them
-        yields the identical match sequence at a fraction of the cost.
+        (rx.anchor RuleAnchor.exact, or space_elastic for a rule whose
+        only unbounded parts are whitespace runs), restricting
+        finditer to them yields the identical match sequence at a
+        fraction of the cost.
         ``finditer(text, a, b)`` (pos/endpos, no slicing) keeps ``\\b``
         look-back across the region edge correct.
         """
@@ -119,9 +121,10 @@ class Scanner:
         """``regions``: optional list aligned with ``self.rules`` —
         per rule either None (whole-file scan, reference behavior) or
         sorted merged (start, end) BYTE spans from the TPU sieve's
-        anchor hits, valid only when the rule's window proof is
-        extraction-exact. Byte spans equal char spans only for 1:1
-        decodes, so any multibyte file falls back whole-file."""
+        anchor, chain or keyword hits, valid only when the rule's
+        window or region proof is extraction-exact. Byte spans equal
+        char spans only for 1:1 decodes, so any multibyte file falls
+        back whole-file."""
         self.used_regions = False
         if self.allow_path(file_path):
             return Secret(file_path=file_path)
