@@ -311,8 +311,10 @@ def build_parser() -> argparse.ArgumentParser:
     scan_flags(repo)
 
     sbom = sub.add_parser("sbom", help="scan an SBOM document "
-                          "(CycloneDX/SPDX, vuln checks only)")
-    sbom.add_argument("target")
+                          "(CycloneDX/SPDX, vuln checks only); "
+                          "several documents, or a directory of "
+                          "*.json, batch-scan through scan_boms")
+    sbom.add_argument("target", nargs="+")
     scan_flags(sbom)
 
     cl = sub.add_parser("client", aliases=["c"],
@@ -2323,8 +2325,14 @@ def _finish_many(args, results) -> int:
 
 def run_sbom(args) -> int:
     """Scan an SBOM file (ref pkg/commands/artifact/run.go sbomScanner:
-    vulnerability checks only)."""
+    vulnerability checks only). Several documents (more targets than
+    one, or a directory) take the batch form."""
     from .artifact.sbom import SBOMArtifact
+    targets = args.target if isinstance(args.target, list) \
+        else [args.target]
+    if len(targets) > 1 or os.path.isdir(targets[0]):
+        return _run_sbom_batch(args, targets)
+    args.target = targets[0]
     if _reject_unwired_fault_spec(args):
         return 2
     if not os.path.isfile(args.target):
@@ -2358,6 +2366,110 @@ def run_sbom(args) -> int:
         cyclonedx=ref.cyclonedx,
     )
     return _finish(args, report)
+
+
+# documents one ``scan_boms`` call of the batch form takes: the
+# source's own batch (BASELINE.json configs[3]), and what bounds the
+# bytes, blobs and reports the command holds at once
+SBOM_CALL_DOCS = 10_000
+
+
+def _sbom_paths(targets: list) -> list:
+    """The batch form's documents: every target that is a file, and
+    under one that is a directory every ``*.json`` (CycloneDX and
+    SPDX JSON alike: ``a.cdx.json``, ``b.spdx.json``), in sorted
+    order. Raises ``OSError`` for a target that is neither."""
+    paths = []
+    for t in targets:
+        if os.path.isdir(t):
+            for root, dirs, files in os.walk(t):
+                dirs.sort()
+                paths += [os.path.join(root, f) for f in sorted(files)
+                          if f.endswith(".json")]
+        elif os.path.isfile(t):
+            paths.append(t)
+        else:
+            raise FileNotFoundError(f"no such file or directory: {t}")
+    return paths
+
+
+def _sbom_calls(runner, paths: list, options):
+    """The results of ``paths``, document for document, from
+    ``scan_boms`` calls of at most ``SBOM_CALL_DOCS``: a call's
+    documents are read when its turn comes and let go when its
+    results have been taken. A file that cannot be read fails its
+    own slot, as a document that cannot be decoded does."""
+    from .artifact.cache import MemoryCache
+    from .obs.trace import phase_span
+    from .runtime import BatchScanResult
+    for a in range(0, len(paths), SBOM_CALL_DOCS):
+        call = paths[a:a + SBOM_CALL_DOCS]
+        # a call's blobs live as long as the call: ``scan_boms``
+        # decodes every document it is given and nothing reads a
+        # blob again, so no cache tier is written (--cache-backend,
+        # --cache-dir choose nothing here)
+        runner.cache = MemoryCache()
+        boms, unread = [], {}
+        with phase_span("read", pipeline="sbom", docs=len(call)):
+            for k, path in enumerate(call):
+                try:
+                    with open(path, "rb") as f:
+                        boms.append((path, f.read()))
+                except OSError as e:
+                    unread[k] = BatchScanResult(
+                        name=path, error=str(e)).mark_failed(
+                            "host", "load_failed", str(e))
+        scanned = iter(runner.scan_boms(boms, options))
+        del boms
+        for k in range(len(call)):
+            yield unread[k] if k in unread else next(scanned)
+
+
+def _run_sbom_batch(args, targets: list) -> int:
+    """``sbom a.cdx.json b.cdx.json ...`` or ``sbom DIR``: every
+    document through ``BatchScanRunner.scan_boms`` on the direct
+    ladder (the documents are all known, so one dedup a call over
+    all their jobs beats a request a document; ``--sched`` chooses
+    nothing here, as for a tree), reported a document as ``image
+    a.tar b.tar`` reports an image."""
+    if _reject_unwired_fault_spec(args):
+        return 2
+    if getattr(args, "server", ""):
+        print("error: multi-document sbom scan is local-only; scan "
+              "one document at a time against --server",
+              file=sys.stderr)
+        return 2
+    if args.format not in ("table", "json", "template"):
+        print(f"error: multi-document scans support table/json/"
+              f"template output, not {args.format}",
+              file=sys.stderr)
+        return 2
+    try:
+        paths = _sbom_paths(targets)
+    except OSError as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 1
+    if not paths:
+        print(f"error: no *.json document under "
+              f"{', '.join(targets)}", file=sys.stderr)
+        return 1
+    _resolve_device(args)
+    from .runtime import BatchScanRunner
+    runner = BatchScanRunner(store=_store(args),
+                             backend=args.backend, sched="off")
+    options = _scan_options(args)
+    options.security_checks = ["vuln"]
+    try:
+        code = _finish_many(args, _sbom_calls(runner, paths, options))
+    finally:
+        runner.close()
+    if getattr(args, "sched_stats", False):
+        from .obs.trace import phase_rows
+        dump = _process_stats(runner.last_stats)
+        dump["sbom"] = {"documents": len(paths),
+                        "phase": phase_rows("sbom")}
+        print(json.dumps(dump, indent=2), file=sys.stderr)
+    return code
 
 
 def run_repo(args) -> int:

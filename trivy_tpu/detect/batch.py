@@ -126,6 +126,9 @@ def _book(sink: dict, span, *keys: str) -> None:
         sink[key] = sink.get(key, 0.0) + span.duration_s
 
 
+_WAVE_ROWS = 4096      # max kernel rows launched per wave
+
+
 def _job_bucket(n: int) -> int:
     """Pair-row shape ladder: powers of two up to 8192, then
     8192-steps (the shared ops.keywords ladder with pair-row
@@ -134,6 +137,21 @@ def _job_bucket(n: int) -> int:
     — repaid many times over by XLA compile-cache hits."""
     from ..ops.keywords import _bucket
     return _bucket(n, base=64, cap=8192)
+
+
+def _resident_waves(n: int) -> list:
+    """``(first row, rows, padded rows)`` of each wave the
+    synchronous resident dispatch sends ``n`` rows in. Rows that fit
+    one wave go at their ``_job_bucket`` rung, the rungs the
+    scheduler's waves take (``runtime/aot.interval_rungs``); more
+    rows go in waves of ``_WAVE_ROWS``, the last one padded to the
+    full rung too, so a pass of any size above one wave runs ONE
+    program, and a pass of ``scan_boms`` whose distinct rows differ
+    from the last pass's compiles nothing."""
+    if n <= _WAVE_ROWS:
+        return [(0, n, _job_bucket(n))]
+    return [(a, min(_WAVE_ROWS, n - a), _WAVE_ROWS)
+            for a in range(0, n, _WAVE_ROWS)]
 
 
 def _dedup(jobs: list, key_fn) -> tuple:
@@ -443,10 +461,12 @@ def _prep_resident(jobs: list, cdb, sink: dict) -> tuple:
 def detect_pairs_resident(jobs: list, backend: str = "tpu",
                           mesh=None,
                           stats: Optional[dict] = None) -> list:
-    """Evaluate ResidentPairJobs in one gather-dispatch against the
-    resident tables. Host work is O(distinct jobs): duplicates are
-    folded before rank lookup, rank lookups are cached per
-    (grammar, version), and the advisory universe is never touched."""
+    """Evaluate ResidentPairJobs by gather-dispatch against the
+    resident tables: one gather on the host engine and on a mesh,
+    :func:`_resident_waves` on one device. Host work is O(distinct
+    jobs): duplicates are folded before rank lookup, rank lookups
+    are cached per (grammar, version), and the advisory universe is
+    never touched."""
     if not jobs:
         return []
     from ..obs.trace import phase_span
@@ -471,19 +491,31 @@ def detect_pairs_resident(jobs: list, backend: str = "tpu",
         reps, members, kept, ranks, rows, host = \
             _prep_resident(jobs, cdb, sink)
         psp.set("unique", len(reps))
-        if kept:
-            P = len(kept)
-            Pp = P if backend == "cpu-ref" else _job_bucket(P)
-            pkg_rank = np.zeros(Pp, np.int32)
-            row_idx = np.zeros(Pp, np.int32)
-            pkg_rank[:P] = ranks
-            row_idx[:P] = rows
+        P = len(kept)
+        # one gather for the host engine and for a mesh (its rows
+        # shard over the chips); on one device, waves of _WAVE_ROWS
+        if not kept:
+            waves = []
+        elif backend == "cpu-ref":
+            waves = [(0, P, P)]
+        elif mesh is not None:
+            waves = [(0, P, _job_bucket(P))]
+        else:
+            waves = _resident_waves(P)
+        packed = []
+        for a, n, padded in waves:
+            pkg_rank = np.zeros(padded, np.int32)
+            row_idx = np.zeros(padded, np.int32)
+            pkg_rank[:n] = ranks[a:a + n]
+            row_idx[:n] = rows[a:a + n]
+            packed.append((pkg_rank, row_idx))
     _book(sink, psp, "dispatch_s")
 
     if kept:
         # device_compute = kernel execution only (obs/timeline.py
         # busy set); table staging keeps its db_upload span
         if backend == "cpu-ref":
+            pkg_rank, row_idx = packed[0]
             with phase_span("device_compute", pipeline="detect",
                             kind="interval", rows=P) as csp:
                 hits = interval_hits_host(
@@ -497,28 +529,34 @@ def detect_pairs_resident(jobs: list, backend: str = "tpu",
             with phase_span("device_compute", pipeline="detect",
                             kind="interval", rows=P) as csp:
                 hits = sharded_interval_hits_resident(
-                    mesh, pkg_rank, row_idx, tables)
+                    mesh, *packed[0], tables)
         else:
             import jax
             from ..ops.intervals import \
                 interval_hits_resident_donated
             tables = cdb.device_tables()
             with phase_span("h2d_upload", pipeline="detect",
-                            bytes=int(pkg_rank.nbytes +
-                                      row_idx.nbytes)) as usp:
-                dr = jax.device_put(pkg_rank)
-                di = jax.device_put(row_idx)
+                            bytes=int(sum(a.nbytes + b.nbytes
+                                          for a, b in packed))) as usp:
+                staged = [(jax.device_put(a), jax.device_put(b))
+                          for a, b in packed]
             _book(sink, usp, "device_s", "dispatch_s")
             with phase_span("device_compute", pipeline="detect",
                             kind="interval", rows=P) as csp:
-                # dr/di are fresh per-dispatch uploads → donated;
-                # the resident tables are shared across every
-                # dispatch of this generation → never donated
-                hits = np.asarray(interval_hits_resident_donated(
-                    dr, di, *tables))
+                # the gather operands are fresh per-wave uploads →
+                # donated; the resident tables are shared across
+                # every dispatch of this generation → never donated.
+                # Every wave is enqueued before the first is
+                # fetched, so the device runs through the fetches
+                lazy = [interval_hits_resident_donated(
+                    dr, di, *tables) for dr, di in staged]
+                hits = np.concatenate(
+                    [np.asarray(h)[:n]
+                     for h, (_, n, _) in zip(lazy, waves)])
         _book(sink, csp, "device_s", "dispatch_s")
         if backend != "cpu-ref":
-            DETECT_METRICS.note_wave(P)
+            for _, n, _ in waves:
+                DETECT_METRICS.note_wave(n)
         for i in np.nonzero(hits[:P])[0]:
             hit_jobs.extend(members[kept[i]])
     out = [jobs[i].payload for i in sorted(hit_jobs)]
@@ -572,8 +610,6 @@ def dispatch_jobs(jobs: list, backend: str = "tpu",
 # thread's wait is where the device wall actually passes, so its
 # device_compute spans carry the true kernel wall for the
 # idle-attribution timeline.
-
-_WAVE_ROWS = 4096      # max kernel rows launched per wave
 
 
 def _activate_ctx(span):

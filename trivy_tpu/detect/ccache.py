@@ -61,10 +61,11 @@ class KeyedMemo:
     stores than that however threads interleave, and ``len`` never
     passes ``maxsize``. What a race can cost: two threads that miss
     one key both run the factory (each gets its own value), and a
-    store made while the generations turn may be forgotten."""
+    store made while the generations turn may be forgotten. Each
+    turn is counted under ``turn_counter`` where one is named."""
 
     def __init__(self, maxsize: int, hit_counter: str,
-                 miss_counter: str):
+                 miss_counter: str, turn_counter: str = ""):
         if maxsize < 2:
             raise ValueError("a two-generation memo needs maxsize "
                              f">= 2, not {maxsize}")
@@ -72,6 +73,7 @@ class KeyedMemo:
         self._half = maxsize // 2
         self._hit = hit_counter
         self._miss = miss_counter
+        self._turn = turn_counter
         self.clear()
 
     def lookup(self, key, factory):
@@ -106,6 +108,12 @@ class KeyedMemo:
         # ``cur`` the previous one, and one's fresh dict is dropped)
         if ticket >= self._half - 1 and self._gens is gens:
             self._gens = ({}, itertools.count(), cur)
+            # a memo that turns several times inside one pass keeps
+            # only what is asked again within ``maxsize // 2`` stores
+            # (PERF.md section 4, ``sbom-zipf-2m``); two threads that
+            # turn at once count two
+            if self._turn:
+                DETECT_METRICS.inc(self._turn)
 
     def __len__(self) -> int:
         cur, _tickets, prev = self._gens
@@ -121,7 +129,8 @@ class ConstraintIntervalCache(KeyedMemo):
 
     def __init__(self, maxsize: int = 65536):
         super().__init__(maxsize, "interval_cache_hits",
-                         "interval_cache_misses")
+                         "interval_cache_misses",
+                         "constraint_cache_turns")
 
     def intervals(self, grammar: str, comparer,
                   constraint: str) -> tuple:

@@ -6,9 +6,10 @@ constraint-interval cache and the purl parse cache are process
 singletons, DB uploads happen once per (generation, mesh), and the
 numbers an operator watches on ``/metrics`` are the cumulative
 totals. Counter updates take no lock: the purl memo counts a lookup
-a component from eight pool threads (80,000 a pass of 2,000 SBOMs),
-and one shared lock there convoyed the whole decode
-(docs/performance.md "SBOM decode and the lock convoy").
+a component (80,000 a pass of 2,000 SBOMs; from eight pool threads
+until PR 33, from every scheduler worker still), and one shared lock
+there convoyed the whole decode (docs/performance.md "SBOM decode and
+the lock convoy").
 """
 
 from __future__ import annotations
@@ -40,6 +41,10 @@ class DetectMetrics:
         "interval_cache_hits", "interval_cache_misses",
         # purl parse cache (purl.from_string)
         "purl_cache_hits", "purl_cache_misses",
+        # times each memo turned its two generations (ccache.KeyedMemo)
+        "purl_cache_turns", "constraint_cache_turns",
+        # documents and components a scan_boms call decoded
+        "sbom_docs", "sbom_components",
         # device-resident advisory tables (db/compiled.py)
         "db_uploads", "db_upload_bytes", "db_invalidations",
         "resident_dispatches",

@@ -200,7 +200,8 @@ def _parse_cache():
     if _parse_memo is None:
         from .detect.ccache import KeyedMemo
         _parse_memo = KeyedMemo(65536, "purl_cache_hits",
-                                "purl_cache_misses")
+                                "purl_cache_misses",
+                                "purl_cache_turns")
     return _parse_memo
 
 

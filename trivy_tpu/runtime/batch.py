@@ -906,10 +906,13 @@ StreamingImageSource`: layer blobs decompress into the scan as they
     def scan_boms(self, boms: list,
                   options: Optional[ScanOptions] = None) -> list:
         """Batch-scan SBOM documents: ``boms`` is a list of
-        (name, raw-bytes). BASELINE config #4's shape — no tar
-        walking, no analyzers: decode → name-join → ONE interval
-        dispatch for the whole fleet against the resident advisory
-        tables."""
+        (name, raw-bytes). BASELINE config #4's shape (no tar
+        walking, no analyzers), and what ``cli sbom`` rides when it
+        is given several documents: decode → name-join → one dedup
+        of the whole call's jobs, whose distinct rows go to the
+        resident advisory tables in waves of one warmed shape
+        (``detect/batch._resident_waves``: a call of any size above
+        one wave compiles nothing the last call did not)."""
         if self.sched == "on":
             return self._scan_boms_scheduled(boms, options)
         from ..utils import defer_gc
@@ -980,6 +983,7 @@ StreamingImageSource`: layer blobs decompress into the scan as they
     def _scan_boms_db(self, db, boms: list,
                       options: Optional[ScanOptions] = None) -> list:
         from ..artifact.sbom import decode_to_blob
+        from ..detect.metrics import DETECT_METRICS
         from ..obs.trace import phase_span
 
         options = options or ScanOptions(
@@ -991,20 +995,15 @@ StreamingImageSource`: layer blobs decompress into the scan as they
         # on the calling thread, so their seconds partition a pass's
         # wall time; the stats keys are sums of their durations.
 
-        # ---- phase 1: decode + blob (host, pooled) ----
+        # ---- phase 1: decode + blob (host, this thread) ----
         # decode is the dominant host phase at fleet scale (PERF.md
-        # §5, sbom-batch): json parse + purl decode per component.
-        # The host pool spreads document decodes over the
-        # spare cores in ≥64-doc slabs — per-doc tasks made pool
-        # dispatch overhead the visible cost in the hostpool stats —
-        # and repeated purl strings short-circuit in the purl parse
-        # memo, which takes no lock: eight slabs at once ask it
-        # 80,000 times a pass (docs/performance.md "SBOM decode and
-        # the lock convoy"). A malformed document still
-        # fails only its own slot. Each slab books a decode_task on
-        # its pool thread: cpu_s over busy_s there is how much of a
-        # task's wall the thread really ran.
-        from .hostpool import map_in_pool
+        # §5, sbom-batch): json parse + purl decode per component,
+        # all of it under the interpreter lock, so it runs on the
+        # calling thread: on the host pool it was a sixth slower and
+        # unsteady (docs/performance.md "SBOM decode and the lock
+        # convoy"). A malformed document still fails only its own
+        # slot. The decode_task span books cpu_s over busy_s: how
+        # much of the decode's wall this thread really ran.
         scanner = LocalScanner(self.cache, db, memo=self.memo)
 
         def decode_one(item):
@@ -1016,12 +1015,11 @@ StreamingImageSource`: layer blobs decompress into the scan as they
 
         with phase_span("decode", pipeline="detect",
                         docs=len(boms)) as dsp:
-            decodes = map_in_pool(
-                decode_one, list(boms), chunk=64,
-                around=lambda: phase_span("decode_task",
-                                          pipeline="detect"))
+            with phase_span("decode_task", pipeline="detect"):
+                decodes = [decode_one(item) for item in boms]
         prepared, metas, failures = [], [], {}
         all_jobs = []
+        components = 0
         with phase_span("join", pipeline="detect",
                         docs=len(boms)) as jsp:
             for i, ((name, _data), dec) in enumerate(zip(boms,
@@ -1035,13 +1033,20 @@ StreamingImageSource`: layer blobs decompress into the scan as they
                     ScanTarget(name=name, artifact_id=blob_id,
                                blob_ids=[blob_id]), options)))
                 metas.append((i, name, atype, decoded))
+                components += sum(
+                    len(a.libraries) for a in blob.applications) \
+                    + sum(len(pi.packages)
+                          for pi in blob.package_infos)
             # the jobs leave the join tagged with their document
             for idx, (_, p) in enumerate(prepared):
                 for job in p.jobs:
                     job.payload = (idx, job.payload)
                     all_jobs.append(job)
 
-        # ---- phase 2: ONE interval dispatch over all SBOMs ----
+        DETECT_METRICS.inc("sbom_docs", len(prepared))
+        DETECT_METRICS.inc("sbom_components", components)
+
+        # ---- phase 2: one dedup and its waves over all SBOMs ----
         kstats: dict = {}
         hits = dispatch_jobs(all_jobs, backend=options.backend,
                              mesh=self.mesh, stats=kstats)

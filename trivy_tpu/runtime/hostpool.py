@@ -4,8 +4,8 @@
 One process-wide pool, sized to the host's spare cores, reserved for
 tasks that NEVER block on scheduler events: the mesh sieve's
 per-shard segment packing and block decode
-(parallel/secret_shard.py), interval wave packing (detect/batch.py),
-SBOM decode (runtime/batch.py), and the direct path's sieve enqueue.
+(parallel/secret_shard.py), interval wave packing (detect/batch.py)
+and the direct path's sieve enqueue.
 The one-device secret pack is NOT here: it runs on the thread that
 calls ``dispatch_files`` without letting go of the interpreter
 (secret/batch.py ``_pack``), because a task a file made that thread
@@ -23,9 +23,10 @@ lets go of it (hashing, numpy copies), and what a task touches once
 an ITEM must take no lock of its own: the purl parse memo did,
 80,000 times a pass of 2,000 SBOMs, and eight decode tasks convoyed
 on it for two thirds of the pass (docs/performance.md "SBOM decode
-and the lock convoy"). Without that lock the SBOM decode neither
-gains nor loses by the pool in a CPU rehearsal (PERF.md section 7
-keeps the question).
+and the lock convoy"). Without that lock the SBOM decode still gained
+nothing here, a json parse never lets go of the interpreter, and on
+the chip's host it was a sixth slower and unsteady: since PR 33 it
+runs on the thread that calls ``scan_boms`` (PERF.md section 6).
 """
 
 from __future__ import annotations
@@ -71,8 +72,7 @@ def get_host_pool():
     return _POOL
 
 
-def map_in_pool(fn, items: list, chunk: int = 1,
-                around=None) -> list:
+def map_in_pool(fn, items: list, chunk: int = 1) -> list:
     """``[fn(x) for x in items]`` spread over the pool (input order
     preserved). Falls back to the inline loop when the pool is
     disabled, the batch is too small to amortize the hops, or the
@@ -82,16 +82,8 @@ def map_in_pool(fn, items: list, chunk: int = 1,
     then packs segments through here again). ``fn`` must capture
     its own errors — a raising task would abandon the batch.
 
-    ``chunk > 1`` batches that many items per pool task. Per-item
-    submission made task-dispatch overhead the visible cost of a
-    fleet-scale SBOM decode: a worker did less json parsing per
-    hop than the hop cost. Decode callers pass 64 so every hop
-    amortizes over a real slab of work.
-
-    ``around`` (optional) makes a context manager that brackets
-    each pool task (a slab, or the whole inline loop) on the thread
-    that runs it: how a caller books one phase a task, never one an
-    item."""
+    ``chunk > 1`` batches that many items per pool task, for
+    items whose work is less than a hop to a worker costs."""
     from ..detect.metrics import DETECT_METRICS
     on_pool_thread = threading.current_thread().name.startswith(
         "trivy-hostpool")
@@ -99,10 +91,7 @@ def map_in_pool(fn, items: list, chunk: int = 1,
         if len(items) > max(8, chunk) and not on_pool_thread \
         else None
     def task(slab: list) -> list:
-        if around is None:
-            return [fn(x) for x in slab]
-        with around():
-            return [fn(x) for x in slab]
+        return [fn(x) for x in slab]
 
     if pool is None:
         return task(items)
@@ -115,6 +104,4 @@ def map_in_pool(fn, items: list, chunk: int = 1,
             out.extend(part)
         return out
     DETECT_METRICS.inc("pack_tasks", len(items))
-    if around is not None:
-        return list(pool.map(lambda x: task([x])[0], items))
     return list(pool.map(fn, items))
