@@ -160,7 +160,7 @@ def candidate_files(root: str) -> list:
     from trivy_tpu.artifact.walker import iter_fs
     art = LocalFSArtifact(root, MemoryCache())
     result = AnalysisResult()
-    for path, size, read in iter_fs(root):
+    for path, size, read, _ in iter_fs(root):
         art.group.analyze_file(result, path, read, size)
     return result.secret_candidates
 
@@ -461,12 +461,21 @@ def _walk_as_os_walk(root, skip_dirs=(), skip_files=()):
     return out
 
 
+def _group():
+    return LocalFSArtifact(".", MemoryCache()).group
+
+
 @pytest.mark.parametrize("skips", [((), ()),
                                    (("svc1", "svc2/pkg0"),
                                     ("svc0/requirements.txt",))])
-def test_the_walk_is_os_walks(tmp_path, skips):
+@pytest.mark.parametrize("gated", [False, True])
+@pytest.mark.parametrize("seed", [43, 44])
+def test_the_walk_is_os_walks(tmp_path, skips, gated, seed):
+    """The same files in the same order as ``os.walk`` gives them;
+    with the analyzers' gate, the size and the bytes of every file
+    somebody wants, and no size where nobody asked."""
     from trivy_tpu.artifact.walker import iter_fs
-    root = make_tree(tmp_path / "tree", 43)
+    root = make_tree(tmp_path / "tree", seed)
     os.symlink(os.path.join(root, "svc0"),
                os.path.join(root, "svc3", "link_to_dir"))
     os.symlink(os.path.join(root, "svc0", "requirements.txt"),
@@ -475,12 +484,229 @@ def test_the_walk_is_os_walks(tmp_path, skips):
     with open(os.path.join(root, "empty.txt"), "wb"):
         pass
     skip_dirs, skip_files = skips
-    got = [(rel, size, read()) for rel, size, read in
-           iter_fs(root, skip_dirs=list(skip_dirs),
-                   skip_files=list(skip_files))]
-    assert got == _walk_as_os_walk(root, skip_dirs, skip_files)
+    want = _walk_as_os_walk(root, skip_dirs, skip_files)
+    group = _group()
+    walked = list(iter_fs(root, skip_dirs=list(skip_dirs),
+                          skip_files=list(skip_files),
+                          gate=group.wanted if gated else None))
+    assert [rel for rel, _, _, _ in walked] == [r for r, _, _ in want]
     assert not any("link_to" in rel or rel.endswith("broken")
-                   for rel, _, _ in got)
+                   for rel, _, _, _ in walked)
+    unasked = 0
+    for (rel, size, read, wanted), (_, want_size, body) in zip(
+            walked, want):
+        if not gated:
+            assert wanted is None
+            assert (size, read()) == (want_size, body)
+            continue
+        assert wanted == group.wanted(rel, want_size)
+        if group.wanted(rel, None):
+            assert size == want_size
+        else:
+            assert size is None and wanted == []
+            unasked += 1
+        # also once the walk has moved on and closed the file
+        assert read() == body
+    assert unasked >= (2 if gated else 0)     # .png, node_modules
+
+
+@pytest.mark.parametrize("seed", [51, 52])
+def test_a_file_nobody_wants_costs_no_call(tmp_path, monkeypatch,
+                                           seed):
+    """With the gate the walk opens the files somebody may want and
+    no other, asks no path for a size, and reads the files somebody
+    does want and no other."""
+    from trivy_tpu.artifact import walker
+    root = make_tree(tmp_path / "tree", seed)
+    with open(os.path.join(root, "svc0", "tiny.py"), "wb") as f:
+        f.write(b"x=1\n")          # wanted but for its size
+    group = _group()
+    opened, stats, fds = [], [], {}
+    real_open, real_read = os.open, os.read
+
+    def spy_open(path, flags, *a, dir_fd=None, **kw):
+        fd = real_open(path, flags, *a, dir_fd=dir_fd, **kw)
+        if flags & os.O_DIRECTORY:   # a listing's: by path
+            fds[fd] = os.path.relpath(path, root)
+        else:                        # a file's: one component
+            assert "/" not in path
+            fds[fd] = os.path.normpath(
+                os.path.join(fds[dir_fd], path))
+            opened.append(fds[fd])
+        return fd
+
+    was_read = set()
+
+    def spy_read(fd, n):
+        was_read.add(fds[fd])
+        return real_read(fd, n)
+
+    monkeypatch.setattr(walker.os, "open", spy_open)
+    monkeypatch.setattr(walker.os, "read", spy_read)
+    for name in ("lstat", "stat"):
+        monkeypatch.setattr(
+            walker.os, name,
+            lambda p, *a, **kw: stats.append(p) or os.stat_result(
+                (0,) * 10))
+    walked = []
+    from trivy_tpu.analyzer.analyzer import AnalysisResult
+    result = AnalysisResult()
+    for rel, size, read, wanted in walker.iter_fs(
+            root, gate=group.wanted):
+        group.analyze_file(result, rel, read, size, wanted)
+        walked.append((rel, size))
+    monkeypatch.undo()
+    sizes = {rel: size for rel, size, _ in _walk_as_os_walk(root)}
+    maybe = [rel for rel in sizes if group.wanted(rel, None)]
+    assert opened == maybe and stats == []
+    assert was_read == {rel for rel in maybe
+                        if group.wanted(rel, sizes[rel])}
+    assert "svc0/tiny.py" in opened and \
+        "svc0/tiny.py" not in was_read
+    for trap in ("node_modules/dep/index.js", "assets/logo.png"):
+        assert trap in sizes and trap not in opened
+    assert dict(walked) == {rel: sizes[rel] if rel in maybe else None
+                            for rel in sizes}
+    assert len(result.secret_candidates) >= 30
+
+
+def _walk_to(it, name: str):
+    for item in it:
+        if item[0].endswith(name):
+            return item
+    raise AssertionError(f"{name} not walked")
+
+
+@pytest.mark.parametrize("what", ["removed", "unreadable",
+                                  "unreadable-unwanted", "grown"])
+def test_a_file_that_changes_under_the_walk(tmp_path, monkeypatch,
+                                            what):
+    """Between a directory's listing and the walk's reaching one of
+    its files: a file that is gone stops the walk there; a file that
+    cannot be opened raises in its ``read_fn`` and nowhere else, and
+    not at all where nobody wants it; a file that has grown is read
+    to its end."""
+    from trivy_tpu.artifact import walker
+    d = tmp_path / "tree" / "src"
+    d.mkdir(parents=True)
+    body = _text(np.random.default_rng(3), 3000)
+    for name in ("a.py", "b.py", "c.png", "d.py"):
+        (d / name).write_bytes(body)
+    it = walker.iter_fs(str(tmp_path / "tree"), gate=_group().wanted)
+    assert _walk_to(it, "a.py")[2]() == body
+    if what == "removed":
+        os.unlink(d / "b.py")
+        with pytest.raises(FileNotFoundError) as err:
+            next(it)
+        assert err.value.filename.endswith("b.py")
+        assert list(it) == []
+        return
+    if what == "grown":
+        with open(d / "b.py", "ab") as f:
+            f.write(b"# grown\n" * 40)
+        rel, size, read, _ = next(it)
+        assert rel == "src/b.py"
+        with open(d / "b.py", "ab") as f:
+            f.write(b"# and again\n")
+        assert read() == body + b"# grown\n" * 40 + b"# and again\n"
+        assert read() == body + b"# grown\n" * 40 + b"# and again\n"
+        assert [r for r, _, _, _ in it] == ["src/c.png", "src/d.py"]
+        return
+    locked = "c.png" if what == "unreadable-unwanted" else "b.py"
+    real = os.open
+
+    def no_entry(path, flags, *a, **kw):
+        if str(path).endswith(locked):
+            raise PermissionError(13, "Permission denied", str(path))
+        return real(path, flags, *a, **kw)
+
+    monkeypatch.setattr(walker.os, "open", no_entry)
+    rest = {rel: (size, read) for rel, size, read, _ in it}
+    assert list(rest) == ["src/b.py", "src/c.png", "src/d.py"]
+    size, read = rest["src/" + locked]
+    if locked == "c.png":
+        assert size is None       # nobody asked, nobody opens it
+    else:
+        assert size == len(body)
+        with pytest.raises(PermissionError):
+            read()
+    assert rest["src/d.py"][1]() == body
+
+
+@pytest.mark.parametrize("gated", [False, True])
+def test_the_budgets_checks_in_the_walks_order(tmp_path, gated):
+    """Deadline, entry, size: every file the walk meets, in its
+    order; a file nobody asked the size of has no size to check."""
+    from trivy_tpu.artifact.walker import iter_fs
+    root = make_tree(tmp_path / "tree", 53, files=12)
+    calls = []
+
+    class Budget:
+        def check_deadline(self):
+            calls.append(("deadline",))
+
+        def charge_entry(self):
+            calls.append(("entry",))
+
+        def check_file_size(self, size, path):
+            calls.append(("size", size, path))
+
+    group = _group()
+    walked = [(rel, size) for rel, size, _, _ in iter_fs(
+        root, budget=Budget(), gate=group.wanted if gated else None)]
+    want = []
+    for rel, size in walked:
+        want += [("deadline",), ("entry",)]
+        if size is not None:
+            want.append(("size", size, rel))
+    assert calls == want
+    assert len(walked) == 12 + 5
+    assert sum(size is None for _, size in walked) == \
+        (2 if gated else 0)
+
+
+@pytest.mark.parametrize("trip", ["deadline", "entries", "size"])
+def test_a_budget_that_trips_stops_the_walk_there(tmp_path,
+                                                  monkeypatch, trip):
+    """An expired deadline or a spent count of entries stops the walk
+    before the next file is touched; a file over the size bound is
+    closed unread; no descriptor is left either way."""
+    from trivy_tpu.artifact import walker
+    d = tmp_path / "tree"
+    d.mkdir()
+    for name in ("a.py", "b.py", "c.py"):
+        (d / name).write_bytes(b"x = 1\n" * 100)
+    seen = []
+
+    class Tripped(Exception):
+        pass
+
+    class Budget:
+        def check_deadline(self):
+            if trip == "deadline" and len(seen) == 2:
+                raise Tripped
+
+        def charge_entry(self):
+            seen.append(None)
+            if trip == "entries" and len(seen) == 3:
+                raise Tripped
+
+        def check_file_size(self, size, path):
+            if trip == "size" and path == "c.py":
+                raise Tripped
+
+    opened = []
+    real = os.open
+    monkeypatch.setattr(
+        walker.os, "open",
+        lambda path, flags, *a, **kw: (
+            opened.append(path), real(path, flags, *a, **kw))[1])
+    it = walker.iter_fs(str(d), budget=Budget(), gate=_group().wanted)
+    assert [next(it)[0], next(it)[0]] == ["a.py", "b.py"]
+    with pytest.raises(Tripped):
+        next(it)
+    assert ("c.py" in opened) == (trip == "size")
+    assert _fds_into(str(d)) == []
 
 
 @pytest.mark.parametrize("most", [1, 7, 4096, 1 << 30])
@@ -491,7 +717,8 @@ def test_a_short_read_is_not_the_end_of_the_file(tmp_path,
     """A read may give fewer bytes than asked for before the file
     ends (the kernel's cap on one call, a network or FUSE file
     system): the walker's reader goes on until a read gives none,
-    also where the file has grown past the listing's size."""
+    also where the file has grown past the listing's size; from the
+    descriptor the walk holds open as by path."""
     from trivy_tpu.artifact import walker
     body = bytes(np.random.default_rng(size).integers(
         0, 256, size, dtype=np.uint8))
@@ -500,11 +727,142 @@ def test_a_short_read_is_not_the_end_of_the_file(tmp_path,
     real = os.read
     monkeypatch.setattr(
         walker.os, "read", lambda fd, n: real(fd, min(n, most)))
-    (_, listed, read), = walker.iter_fs(str(tmp_path))
+    it = walker.iter_fs(str(tmp_path))
+    _, listed, read, _ = next(it)
     assert listed == size and read() == body
+    assert read() == body           # from the start again
+    assert list(it) == []           # the walk moves on: by path now
     with open(tmp_path / "f.bin", "ab") as f:
         f.write(b"grown" * 300)
     assert read() == body + b"grown" * 300
+
+
+def _parents_iter_fs(root, skip_dirs=(), skip_files=(), budget=None,
+                     gate=None):
+    """The walk as it was before it opened first: ``lstat`` for the
+    size of every file, every gate asked with it, ``open`` and the
+    reads by path."""
+    for rel, size, body in _walk_as_os_walk(root):
+        yield rel, size, (lambda body=body: body), None
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+@pytest.mark.parametrize("how", ["streamed", "direct"])
+def test_the_blob_is_the_one_of_the_walk_that_asked_lstat_first(
+        tmp_path, monkeypatch, seed, how):
+    """Blob id, parts, each part's files and the report: those of a
+    walk that asks every file's size by path and every gate with
+    it."""
+    from trivy_tpu.artifact import artifact
+    root = make_tree(tmp_path / "tree", seed)
+    with open(os.path.join(root, "svc0", "tiny.py"), "wb") as f:
+        f.write(b"x=1\n")
+    with open(os.path.join(root, "Dockerfile"), "w") as f:
+        f.write("FROM alpine:latest\nUSER root\n")
+    monkeypatch.setattr(secret_batch, "PART_ROWS", 24)
+
+    def scan():
+        ids, parts = [], []
+        real_put = MemoryCache.put_blob
+        monkeypatch.setattr(
+            MemoryCache, "put_blob",
+            lambda self, blob_id, blob: (ids.append(blob_id), real_put(
+                self, blob_id, blob))[1])
+        from trivy_tpu.runtime.batch import _TreeStream
+        real_emit = _TreeStream.emit
+        monkeypatch.setattr(
+            _TreeStream, "emit",
+            lambda self, part: (parts.append(
+                [(seq, path, len(c)) for seq, path, c in part]),
+                real_emit(self, part))[1])
+        try:
+            if how == "streamed":
+                (res,), stats = streamed(root, "cpu-ref",
+                                         ["vuln", "secret", "config"],
+                                         make_store())
+                assert res.status == "ok"
+                report = render(res.report)
+            else:
+                report = _direct(root, "cpu-ref",
+                                 ["vuln", "secret", "config"])
+        finally:
+            monkeypatch.setattr(MemoryCache, "put_blob", real_put)
+            monkeypatch.setattr(_TreeStream, "emit", real_emit)
+        return ids, parts, report
+
+    got = scan()
+    monkeypatch.setattr(artifact, "iter_fs", _parents_iter_fs)
+    want = scan()
+    assert got == want
+    assert len(got[0]) == 1 and got[0][0].startswith("sha256:")
+    assert (len(got[1]) >= 3) == (how == "streamed")
+
+
+def _fds_into(root: str) -> list:
+    out = []
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            target = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue
+        if target.startswith(root):
+            out.append(target)
+    return out
+
+
+@pytest.mark.parametrize("how", ["ok", "analyzer-error", "expired",
+                                 "cancelled"])
+def test_no_file_of_the_tree_is_left_open(tmp_path, monkeypatch, how):
+    """The walk holds one directory open at a time, whatever the
+    tree's depth, and one of its files until that is read (an
+    analyzer sees the directory's descriptor and no file's), and
+    nothing when ``inspect`` has returned, however it returned."""
+    import time
+
+    from trivy_tpu.analyzer import secret as secret_analyzer
+    root = make_tree(tmp_path / "tree", 59)
+    monkeypatch.setattr(secret_batch, "PART_ROWS", 12)
+    held, reqs = [], []
+    real = secret_analyzer.SecretCandidateAnalyzer.analyze
+
+    def analyze(self, path, content):
+        held.append(len(_fds_into(root)))
+        if how == "analyzer-error" and len(held) == 20:
+            raise RuntimeError("analyzer gave up")
+        if how == "cancelled" and len(held) == 20:
+            reqs[0].cancel()
+        return real(self, path, content)
+
+    monkeypatch.setattr(secret_analyzer.SecretCandidateAnalyzer, "analyze",
+                        analyze)
+    if how in ("expired", "cancelled"):
+        real_collect = secret_batch.BatchSecretScanner.collect
+
+        def slow(self, handle):
+            time.sleep(0.4)
+            return real_collect(self, handle)
+
+        monkeypatch.setattr(secret_batch.BatchSecretScanner,
+                            "collect", slow)
+    runner = BatchScanRunner(backend="cpu-ref", sched="on")
+    try:
+        opts = ScanOptions(backend="cpu-ref",
+                           security_checks=["secret"])
+        if how == "expired":
+            opts.deadline_s = 0.3
+        reqs.append(runner.submit_tree(root, opts))
+        try:
+            res = reqs[0].result(timeout=120)
+            status = res.status
+        except Exception as e:       # noqa: BLE001 (the tree's)
+            status = type(e).__name__
+    finally:
+        runner.close()
+    assert status == {"ok": "ok", "analyzer-error": "RuntimeError",
+                      "expired": "DeadlineExceeded",
+                      "cancelled": "RequestCancelled"}[how]
+    assert held and max(held) == 1
+    assert _fds_into(root) == []
 
 
 # ---------------------------------------------------------------

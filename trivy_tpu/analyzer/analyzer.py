@@ -172,27 +172,54 @@ class AnalyzerGroup:
                 self._by_path.setdefault(p, []).append(a)
             for b in a.basenames:
                 self._by_base.setdefault(b, []).append(a)
+        # the analyzers whose gate may look at a file's size
+        self._sized = frozenset(self._probe)
 
     def versions(self) -> dict:
         return {a.type: a.version for a in self.analyzers}
 
-    def analyze_file(self, result: AnalysisResult, path: str,
-                     content_fn: Callable, size: int) -> None:
-        content = None          # read once, shared by all analyzers
+    def wanted(self, path: str, size: Optional[int],
+               among: Optional[list] = None) -> list:
+        """The gate without the content: the analyzers that ask for
+        ``path`` of ``size``, in the order they run (the two
+        dispatch tables, then the probe list's ``required`` and
+        ``--file-patterns``). Empty for a file nobody reads. A
+        ``size`` of None asks who would at some size (``required``
+        leaves its size rules out then): where that is nobody, a
+        walk need not so much as ask the file's size, and where it
+        is somebody, the answer goes back in as ``among`` with the
+        size and only those are asked again
+        (``artifact/walker.iter_fs``)."""
+        if among is not None:
+            # the tables and --file-patterns never look at a size
+            return [a for a in among
+                    if a not in self._sized
+                    or (self.patterns and self._forced(a, path))
+                    or a.required(path, size)]
         matched = list(self._by_path.get(path, ()))
         for a in self._by_base.get(path.rpartition("/")[2], ()):
             if a not in matched:   # declared in both tables
                 matched.append(a)
-        for a in matched:
-            if content is None:
-                content = content_fn()
-            result.merge(a.analyze(path, content))
+        forced = self._forced if self.patterns else None
         for a in self._probe:
-            pat = self.patterns.get(a.type)
-            if pat is not None and pat.search(path):
-                pass                      # forced by --file-patterns
-            elif not a.required(path, size):
-                continue
-            if content is None:
-                content = content_fn()
+            if (forced and forced(a, path)) or a.required(path, size):
+                matched.append(a)
+        return matched
+
+    def _forced(self, a, path: str) -> bool:
+        """A match of ``--file-patterns`` forces an analyzer on."""
+        pat = self.patterns.get(a.type)
+        return pat is not None and pat.search(path) is not None
+
+    def analyze_file(self, result: AnalysisResult, path: str,
+                     content_fn: Callable, size: int,
+                     wanted: Optional[list] = None) -> None:
+        """``wanted``: what :meth:`wanted` said of this file, where
+        the caller has asked already (the gates run once a file)."""
+        if wanted is None:
+            wanted = self.wanted(path, size)
+        if not wanted:
+            return
+        content = content_fn()  # read once, shared by all analyzers
+        for a in wanted:
             result.merge(a.analyze(path, content))

@@ -369,9 +369,10 @@ class LocalFSArtifact:
         while walking:
             parts = []
             with phase_span("tree_walk", pipeline="ingest"):
-                for path, size, read in files:
+                for path, size, read, wanted in files:
                     n_files += 1
-                    self.group.analyze_file(result, path, read, size)
+                    self.group.analyze_file(result, path, read, size,
+                                            wanted)
                     if not result.secret_candidates:
                         continue
                     for p, content in result.secret_candidates:
@@ -412,17 +413,24 @@ class LocalFSArtifact:
         in the walk either way, and the blob is the same."""
         result = AnalysisResult()
         files = iter_fs(self.root, skip_dirs=self.opt.skip_dirs,
-                        skip_files=self.opt.skip_files)
-        if stream is not None and self.opt.scan_secrets:
-            result.secrets = self._stream_secrets(result, files,
-                                                  stream)
-        else:
-            for path, size, read in files:
-                self.group.analyze_file(result, path, read, size)
-            if result.secret_candidates and self.opt.scan_secrets:
-                scanner = _secret_scanner(self.opt)
-                result.secrets = [s for _, s in scanner.scan_files(
-                    [(p, c) for p, c in result.secret_candidates])]
+                        skip_files=self.opt.skip_files,
+                        gate=self.group.wanted)
+        try:
+            if stream is not None and self.opt.scan_secrets:
+                result.secrets = self._stream_secrets(result, files,
+                                                      stream)
+            else:
+                for path, size, read, wanted in files:
+                    self.group.analyze_file(result, path, read, size,
+                                            wanted)
+        finally:
+            # an error or a cancelled tree leaves no file open
+            files.close()
+        if stream is None and result.secret_candidates \
+                and self.opt.scan_secrets:
+            scanner = _secret_scanner(self.opt)
+            result.secrets = [s for _, s in scanner.scan_files(
+                [(p, c) for p, c in result.secret_candidates])]
 
         blob = result.to_blob_info()
         post_handle(blob)

@@ -170,22 +170,47 @@ def _clean_skip(paths) -> set:
 
 def iter_fs(root: str, skip_dirs: list = (),
             skip_files: list = (),
-            budget: Optional[ResourceBudget] = None):
-    """Directory walk → (rel_path, size, read_fn), one at a time, so
-    that a caller that streams (``LocalFSArtifact``) has its first
-    files before the tree is walked to its end (reference:
+            budget: Optional[ResourceBudget] = None,
+            gate: Optional[Callable] = None):
+    """Directory walk → (rel_path, size, read_fn, wanted), one at a
+    time, so that a caller that streams (``LocalFSArtifact``) has its
+    first files before the tree is walked to its end (reference:
     walker/fs.go; shared skip logic walk.go:47-62). Skip lists match
     both the cwd-relative walked path (reference behavior for
     relative scan roots) and the root-relative path (convenience).
     Symlinks are never followed, to a directory or to a file, so a
     link farm cannot pull the walk outside ``root``; a budget
-    additionally bounds file count, per-file size, and wall clock.
+    additionally bounds file count, wall clock (both asked before a
+    file is touched) and the size of every file that is opened.
     The order is ``os.walk``'s (a directory's files, names sorted,
-    then its directories as the file system lists them); the
-    directory's own listing says what is a regular file, so a file
-    costs one ``lstat`` for its size where ``os.walk`` and
-    ``os.path`` cost three (a tree of 40,000 files on a slow file
-    system is walked in seconds of system calls)."""
+    then its directories as the file system lists them).
+
+    ``gate(rel_path, size, among=None)`` says who wants a file,
+    without its content (``AnalyzerGroup.wanted``; a ``size`` of
+    None asks who would at some size, and those are asked again,
+    ``among``, once the size is known): its answer comes back as
+    ``wanted``. Without a gate every file is wanted at any size and
+    ``wanted`` is None.
+
+    Which system calls a file costs, and who makes them: all are
+    made here, on the thread that iterates, and the ones that take a
+    path take a short one (what a path costs by its length, and why
+    nothing is read ahead of the walk: PERF.md section 6, PR 34). A
+    directory is opened once, by path, listed from its descriptor
+    (the listing says what is a regular file), and closed before the
+    walk goes down: one directory and one file are open at a time
+    whatever the depth. A file that nobody wants at any size costs
+    no call (its ``size`` is None: nobody asked, so the budget's
+    bound on a file's size is not asked of it either; it is never
+    read). Any other file is opened from the directory's descriptor,
+    a path of one component, and its size is the open file's
+    (``fstat``); a file that its size rules out is closed unread; a
+    wanted file is read from that descriptor by its ``read_fn`` (the
+    reads to its end, ``close``; a ``read_fn`` called again, or
+    after the walk has moved on, reads by path). A file that cannot
+    be opened is asked ``lstat``: gone, and the walk raises here;
+    there and wanted, and its ``read_fn`` raises what ``open``
+    raised."""
     skip_dirs = _clean_skip(skip_dirs)
     skip_files = _clean_skip(skip_files)
     root_prefix = posixpath.normpath(
@@ -196,53 +221,129 @@ def iter_fs(root: str, skip_dirs: list = (),
             posixpath.join(root_prefix, rel) in skips
 
     def walk(dirpath: str, rel_dir: str):
+        # the directory's own descriptor: a path from it is one
+        # component, and a path's price goes by its components
+        # (os.fwalk's way; here the files' order is the walk's)
+        dfd = None
         try:
-            with os.scandir(dirpath) as it:
+            dfd = os.open(dirpath, os.O_RDONLY | os.O_DIRECTORY)
+            with os.scandir(dfd) as it:
                 entries = list(it)
         except OSError:
+            if dfd is not None:
+                os.close(dfd)
             return      # as os.walk: what cannot be listed is passed
-        files = [e for e in entries
-                 if e.is_file(follow_symlinks=False)]
-        for e in sorted(files, key=lambda e: e.name):
-            rel = posixpath.join(rel_dir, e.name)
-            if skipped(rel, skip_files):
-                continue
-            size = e.stat(follow_symlinks=False).st_size
-            if budget is not None:
-                budget.check_deadline()
-                budget.charge_entry()
-                budget.check_file_size(size, rel)
-            yield rel, size, _file_reader(e.path, size)
+        try:
+            files = [e for e in entries
+                     if e.is_file(follow_symlinks=False)]
+            for e in sorted(files, key=lambda e: e.name):
+                rel = posixpath.join(rel_dir, e.name)
+                if skipped(rel, skip_files):
+                    continue
+                full = os.path.join(dirpath, e.name)
+                if budget is not None:   # before the file is touched
+                    budget.check_deadline()
+                    budget.charge_entry()
+                wanted = gate(rel, None) if gate is not None else None
+                opened = None
+                if gate is not None and not wanted:
+                    size, read = None, _file_reader(full, 0)
+                else:
+                    try:
+                        read = opened = _OpenFile(e.name, dfd, full)
+                        size = opened.size
+                    except OSError as err:
+                        size = e.stat(follow_symlinks=False).st_size
+                        read = _raiser(err)
+                    if gate is not None:
+                        wanted = gate(rel, size, wanted)
+                try:
+                    if budget is not None and size is not None:
+                        budget.check_file_size(size, rel)
+                    if gate is not None and not wanted \
+                            and opened is not None:
+                        opened.close()      # its size rules it out
+                    yield rel, size, read, wanted
+                finally:
+                    if opened is not None:
+                        opened.close()
+        finally:
+            os.close(dfd)
+        # by path and with no descriptor held: a tree's depth costs
+        # the walk none
         for e in entries:
             if e.is_dir(follow_symlinks=False):
                 rel = posixpath.join(rel_dir, e.name)
                 if not skipped(rel, skip_dirs):
-                    yield from walk(e.path, rel)
+                    yield from walk(os.path.join(dirpath, e.name),
+                                    rel)
 
     yield from walk(root, "")
 
 
+def _read_fd(fd: int, size: int) -> bytes:
+    """The rest of an open file by ``os.read``: the first read asks
+    for a byte more than ``size``, so a file that has not grown is
+    read in one call and the empty read that says it ended. A read
+    may come back short before the end (one call gives 0x7ffff000
+    bytes at the most; a network or FUSE file system gives what it
+    has), so only an empty read ends the loop."""
+    chunks = []
+    want = size + 1
+    while True:
+        data = os.read(fd, want)
+        if not data:
+            break
+        chunks.append(data)
+        want = max(want - len(data), 1 << 20)
+    return b"".join(chunks)
+
+
 def _file_reader(full: str, size: int) -> Callable:
-    """The file's bytes by ``os.read`` to the end of the file: the
-    first read asks for a byte more than the listing's ``size``, so
-    a file that has not grown is read in one call and the empty read
-    that says it ended (four system calls where a buffered
-    ``open().read()`` makes six). A read may come back short before
-    the end (one call gives 0x7ffff000 bytes at the most; a network
-    or FUSE file system gives what it has), so only an empty read
-    ends the loop."""
+    """The file's bytes by path: ``open``, the reads to its end and
+    ``close`` (four system calls where a buffered ``open().read()``
+    makes six)."""
     def read() -> bytes:
         fd = os.open(full, os.O_RDONLY)
         try:
-            chunks = []
-            want = size + 1
-            while True:
-                data = os.read(fd, want)
-                if not data:
-                    break
-                chunks.append(data)
-                want = max(want - len(data), 1 << 20)
-            return b"".join(chunks)
+            return _read_fd(fd, size)
         finally:
             os.close(fd)
+    return read
+
+
+class _OpenFile:
+    """``read_fn`` of a file the walk has opened: its bytes from the
+    descriptor, which is closed with the read or when the walk moves
+    on; after that, by path like any other file's."""
+
+    __slots__ = ("fd", "full", "size")
+
+    def __init__(self, name: str, dir_fd: int, full: str):
+        self.full = full
+        self.fd = os.open(name, os.O_RDONLY, dir_fd=dir_fd)
+        try:
+            self.size = os.fstat(self.fd).st_size
+        except OSError:
+            self.close()
+            raise
+
+    def __call__(self) -> bytes:
+        if self.fd is None:
+            return _file_reader(self.full, self.size)()
+        try:
+            return _read_fd(self.fd, self.size)
+        finally:
+            self.close()
+
+    def close(self) -> None:
+        if self.fd is not None:
+            fd, self.fd = self.fd, None
+            os.close(fd)
+
+
+def _raiser(err: OSError) -> Callable:
+    """``read_fn`` of a file that is there and cannot be opened."""
+    def read() -> bytes:
+        raise err
     return read

@@ -66,7 +66,11 @@ class _ModuleAnalyzer(Analyzer):
 
     def required(self, path: str, size: Optional[int] = None) -> bool:
         if hasattr(self.mod, "required"):
-            return bool(self.mod.required(path, size))
+            # a module's own gate is never asked without a size: it
+            # may compare it (AnalyzerGroup.wanted asks "at some
+            # size?" first, and "maybe" is always a right answer)
+            return size is None or \
+                bool(self.mod.required(path, size))
         return any(p.search(path) for p in self._patterns)
 
     def analyze(self, path: str, content: bytes) -> AnalysisResult:
