@@ -513,3 +513,372 @@ def test_phase_table_loses_no_update_under_threads():
     row = phase_rows("test")["stress"]
     assert row["n"] == workers * each
     assert row["busy_s"] >= row["cpu_s"] >= 0.0
+
+
+# ---------------------------------------------------------------
+# the split: user, system and off-CPU seconds a phase; the host
+# pipeline (PR 35)
+# ---------------------------------------------------------------
+
+COLUMNS = {"n", "busy_s", "cpu_s", "user_s", "sys_s", "wait_s"}
+
+
+def _row(pipeline, phase):
+    from trivy_tpu.obs.trace import phase_rows
+    return phase_rows(pipeline)[phase]
+
+
+def _spin_cpu(seconds: float) -> None:
+    """Pure Python until this thread has burnt ``seconds`` of CPU:
+    by the thread's own clock, so a loaded runner only adds wait."""
+    import time
+    end = time.thread_time() + seconds
+    x = 0
+    while time.thread_time() < end:
+        for i in range(2000):
+            x = (x * 31 + i) & 0xffffff
+
+
+def test_every_row_carries_the_split(tmp_path):
+    from trivy_tpu.obs.trace import phase_table
+    _run_fleet_sched_on(tmp_path)
+    _run_boms_sched_off(tmp_path)
+    table = phase_table()
+    assert {"secret", "detect", "ingest", "sched"} <= set(table)
+    for pipeline, rows in table.items():
+        for phase, r in rows.items():
+            where = f"{pipeline}.{phase}"
+            assert set(r) == COLUMNS, where
+            assert r["user_s"] >= 0 and r["sys_s"] >= 0, where
+            assert r["user_s"] + r["sys_s"] == pytest.approx(
+                r["cpu_s"], abs=1e-9), where
+            assert r["wait_s"] >= 0, where
+            assert r["wait_s"] == pytest.approx(
+                r["busy_s"] - r["cpu_s"], abs=1e-9), where
+
+
+def test_a_sleeping_phase_books_wait():
+    import time
+
+    from trivy_tpu.obs.trace import phase_span
+    with phase_span("sleeps", pipeline="test") as sp:
+        time.sleep(0.2)
+    row = _row("test", "sleeps")
+    assert row["wait_s"] >= 0.15
+    assert row["cpu_s"] < 0.05 and sp.cpu_s < 0.05
+
+
+def test_a_spinning_phase_books_user():
+    """The spin is measured out in CPU seconds, not wall, so that
+    a loaded runner cannot starve it: of that CPU all but the
+    clock's own calls is the interpreter's, and on a quiet runner
+    it is most of the wall too."""
+    from trivy_tpu.obs.trace import phase_span
+    with phase_span("spins", pipeline="test") as sp:
+        _spin_cpu(0.3)
+    row = _row("test", "spins")
+    assert row["cpu_s"] == pytest.approx(min(sp.cpu_s,
+                                             row["busy_s"]))
+    assert 0.28 <= row["cpu_s"] <= row["busy_s"]      # to a tick
+    assert row["user_s"] >= 0.5 * row["cpu_s"]
+    assert row["sys_s"] <= 0.5 * row["cpu_s"]
+
+
+def test_two_spinning_threads_wait_for_each_other():
+    """One interpreter: two threads that compute side by side
+    inside phases each spend a good part of the wall waiting for
+    it, and together they book no more user seconds than one
+    interpreter has (load on the runner only adds wait)."""
+    import threading
+    import time
+
+    from trivy_tpu.obs.trace import phase_span
+    wall = 0.5
+
+    def work(name):
+        with phase_span(name, pipeline="test"):
+            end = time.monotonic() + wall
+            x = 0
+            while time.monotonic() < end:
+                for i in range(2000):
+                    x = (x * 31 + i) & 0xffffff
+
+    names = ("two_spin_a", "two_spin_b")
+    threads = [threading.Thread(target=work, args=(n,))
+               for n in names]
+    t0 = time.monotonic()
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    took = time.monotonic() - t0
+    rows = [_row("test", n) for n in names]
+    for row in rows:
+        assert row["n"] == 1 and row["busy_s"] >= wall
+        assert row["wait_s"] > 0.1
+    assert sum(r["user_s"] for r in rows) <= 1.3 * took
+
+
+def test_book_phase_by_hand_is_all_wait():
+    from trivy_tpu.obs.trace import book_phase
+    book_phase("test", "hand_wait", 0.25)
+    row = _row("test", "hand_wait")
+    assert row == {"n": 1, "busy_s": 0.25, "cpu_s": 0.0,
+                   "user_s": 0.0, "sys_s": 0.0, "wait_s": 0.25}
+
+
+def test_every_booking_is_held_to_its_wall():
+    """The kernel moves a thread's books a tick at a time, so a
+    short span may read a whole tick or nothing. Each booking is
+    held to its own wall, the split keeping its proportions, and
+    what a span read beyond it is owed to the row's next spans:
+    nothing is lost, and no stretch of a row has more CPU than
+    wall."""
+    from trivy_tpu.obs.trace import book_phase
+    book_phase("test", "hand_split", 1.0, 0.8, sys_s=0.2)
+    assert _row("test", "hand_split") == pytest.approx(
+        {"n": 1, "busy_s": 1.0, "cpu_s": 0.8, "user_s": 0.6,
+         "sys_s": 0.2, "wait_s": 0.2})
+    # spans of 1 ms, a tick of 10 ms caught by the second
+    book_phase("test", "hand_tick", 0.001)
+    book_phase("test", "hand_tick", 0.001, 0.010, sys_s=0.004)
+    assert _row("test", "hand_tick") == pytest.approx(
+        {"n": 2, "busy_s": 0.002, "cpu_s": 0.001, "user_s": 0.0006,
+         "sys_s": 0.0004, "wait_s": 0.001})
+    book_phase("test", "hand_tick", 0.001)
+    assert _row("test", "hand_tick") == pytest.approx(
+        {"n": 3, "busy_s": 0.003, "cpu_s": 0.002, "user_s": 0.0012,
+         "sys_s": 0.0008, "wait_s": 0.001})
+    for _ in range(11):             # the tick is paid out, no more
+        book_phase("test", "hand_tick", 0.001)
+    assert _row("test", "hand_tick") == pytest.approx(
+        {"n": 14, "busy_s": 0.014, "cpu_s": 0.010, "user_s": 0.006,
+         "sys_s": 0.004, "wait_s": 0.004})
+
+
+@pytest.mark.parametrize("share", [0.3, 0.6, 0.9])
+def test_no_window_of_a_row_has_more_cpu_than_wall(share):
+    """What a reader of two snapshots sees (the benchmark's window,
+    a Prometheus rate): between ANY two readings of a row, spans of
+    1 ms under a tick of 10 ms, ``cpu_s`` grows by no more than
+    ``busy_s`` and ``wait_s`` never falls, and the whole row still
+    sums to the CPU that ran."""
+    import random
+
+    from trivy_tpu.obs.trace import book_phase
+    rng = random.Random(35)
+    phase = f"windows_{share}"
+    span, tick, n = 0.001, 0.010, 2000
+    book_phase("test", phase, span)
+    seen, ticks = [_row("test", phase)], 0
+    for _ in range(n):
+        hit = rng.random() < share * span / tick
+        ticks += hit
+        book_phase("test", phase, span, tick * hit,
+                   sys_s=0.25 * tick * hit)
+        seen.append(_row("test", phase))
+    for i, b in enumerate(seen):
+        for a in seen[max(0, i - 40):i:13]:
+            d = {k: b[k] - a[k] for k in b}
+            assert -1e-12 <= d["cpu_s"] <= d["busy_s"] + 1e-12
+            assert d["wait_s"] >= -1e-12
+            assert d["user_s"] + d["sys_s"] == pytest.approx(
+                d["cpu_s"], abs=1e-12)
+    last = seen[-1]
+    # all but what the last ticks still owe
+    assert (ticks - 2) * tick <= last["cpu_s"] <= ticks * tick + 1e-9
+    assert last["sys_s"] == pytest.approx(0.25 * last["cpu_s"])
+
+
+def test_a_full_collection_under_the_phase_lock_returns():
+    """The collector runs its callbacks on whichever thread trips
+    the threshold, and that thread may be inside ``book_phase`` or
+    ``phase_table``, holding the table's lock: the callback takes
+    no lock, so the collection is booked and the thread goes on."""
+    import gc
+    import threading
+
+    from trivy_tpu.obs import trace
+    from trivy_tpu.utils import sparse_full_gc
+    release = sparse_full_gc()
+    done = []
+
+    def holder():
+        n0 = trace.phase_rows("host")["gc_full"]["n"]
+        with trace._PHASE_LOCK:
+            gc.collect()                        # a real one
+            trace._on_gc("start", {"generation": 2})
+            trace._on_gc("stop", {"generation": 2})
+        done.append(trace.phase_rows("host")["gc_full"]["n"] - n0)
+
+    t = threading.Thread(target=holder, daemon=True)
+    try:
+        t.start()
+        t.join(timeout=20)
+        assert not t.is_alive(), "the callback waits for its own lock"
+    finally:
+        if not t.is_alive():
+            release()
+    assert done == [2]
+
+
+def test_a_full_collection_inside_phase_table_returns(monkeypatch):
+    """The same from inside a snapshot: a collection planted where
+    ``phase_table`` builds its rows."""
+    import gc
+    import threading
+
+    from trivy_tpu.obs import trace
+    from trivy_tpu.utils import sparse_full_gc
+    release = sparse_full_gc()
+    row_dict = trace._row_dict
+    out = []
+
+    def collecting(*args):
+        gc.collect()
+        return row_dict(*args)
+
+    def snapshot():
+        trace.ensure_phase("test", "in_table")
+        n0 = trace.phase_rows("host")["gc_full"]["n"]
+        monkeypatch.setattr(trace, "_row_dict", collecting)
+        table = trace.phase_table()
+        monkeypatch.setattr(trace, "_row_dict", row_dict)
+        out.append((table["host"]["gc_full"]["n"] - n0,
+                    sum(len(rows) for rows in table.values())))
+
+    t = threading.Thread(target=snapshot, daemon=True)
+    try:
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+    finally:
+        if not t.is_alive():
+            release()
+    (booked, rows), = out
+    # one collection a row, each booked (the ``gc_full`` row's own
+    # after that row was read)
+    assert booked == rows - 1 >= 1
+
+
+def test_rows_still_book_without_rusage_thread(monkeypatch):
+    """No ``RUSAGE_THREAD`` (not Linux): the thread's CPU seconds
+    are booked all the same, all of them as user seconds."""
+    import types
+
+    from trivy_tpu.obs import trace
+    bare = types.SimpleNamespace(
+        getrusage=trace.resource.getrusage,
+        RUSAGE_SELF=trace.resource.RUSAGE_SELF)
+    monkeypatch.setattr(trace, "resource", bare)
+    monkeypatch.setattr(trace, "_thread_cpu",
+                        trace._thread_cpu_reader())
+    with trace.phase_span("no_rusage", pipeline="test"):
+        _spin_cpu(0.05)
+    row = _row("test", "no_rusage")
+    assert row["n"] == 1 and row["cpu_s"] >= 0.049
+    assert row["sys_s"] == 0.0
+    assert row["user_s"] == row["cpu_s"]
+    assert trace.process_cpu()["user_s"] > 0
+
+
+def test_scheduler_stats_carry_the_host_rows():
+    """``stats()["detect"]["host"]``: the process's CPU, which
+    only grows, and the full collections, there at zero from
+    ``start()`` and booked when one runs under a running
+    scheduler."""
+    import gc
+
+    from trivy_tpu.obs.trace import phase_rows
+    from trivy_tpu.sched import ScanScheduler
+    n0 = phase_rows("host").get("gc_full", {}).get("n", 0)
+    with ScanScheduler(config=SchedConfig(workers=1)) as sched:
+        first = sched.stats()["detect"]["host"]
+        assert set(first) == {"process", "phase"}
+        assert set(first["process"]) == {"user_s", "sys_s"}
+        assert first["phase"]["gc_full"]["n"] == n0
+        _spin_cpu(0.05)
+        gc.collect()
+        gc.collect(0)                       # young: not booked
+        second = sched.stats()["detect"]["host"]
+    assert second["process"]["user_s"] >= \
+        first["process"]["user_s"] + 0.04
+    assert second["process"]["sys_s"] >= first["process"]["sys_s"]
+    row = second["phase"]["gc_full"]
+    assert set(row) == COLUMNS
+    assert row["n"] == n0 + 1
+    assert row["busy_s"] > first["phase"]["gc_full"]["busy_s"]
+    gc.collect()                            # closed: nobody listens
+    assert phase_rows("host")["gc_full"]["n"] == n0 + 1
+
+
+@pytest.mark.parametrize("order", ["ab", "ba"])
+def test_gc_callbacks_are_as_found_after_close(order):
+    import gc
+
+    from trivy_tpu.sched import ScanScheduler
+    found = list(gc.callbacks)
+    scheds = {"a": ScanScheduler(config=SchedConfig(workers=1)),
+              "b": ScanScheduler(config=SchedConfig(workers=1))}
+    scheds["a"].start()
+    assert len(gc.callbacks) == len(found) + 1
+    scheds["b"].start()
+    assert len(gc.callbacks) == len(found) + 1    # one entry
+    scheds[order[0]].close()
+    assert len(gc.callbacks) == len(found) + 1
+    scheds[order[1]].close()
+    assert gc.callbacks == found
+
+
+def test_a_gc_full_span_is_on_the_profilers_clock(annotator):
+    import gc
+
+    from trivy_tpu.sched import ScanScheduler
+    with ScanScheduler(config=SchedConfig(workers=1)):
+        gc.collect()
+    mine = [(kind, name) for kind, name, _tid in annotator.events
+            if name == "trivy.host.gc_full"]
+    assert mine == [("B", "trivy.host.gc_full"),
+                    ("E", "trivy.host.gc_full")]
+
+
+def test_rows_a_metric_reads_are_there_at_zero():
+    """A ring that never filled reads a slot wait of 0, not none."""
+    from trivy_tpu.obs.trace import ensure_phase, phase_rows
+    from trivy_tpu.runtime.ring import DispatchRing
+    ring = DispatchRing(depth=2, name="test")
+    try:
+        row = phase_rows("sched")["slot_wait"]
+        assert set(row) == COLUMNS
+    finally:
+        ring.close()
+    ensure_phase("test", "at_zero")
+    ensure_phase("test", "at_zero")
+    assert phase_rows("test")["at_zero"] == dict.fromkeys(
+        COLUMNS, 0)
+
+
+def test_metrics_render_the_split_series(tmp_path):
+    from trivy_tpu.obs.prom import render_prometheus
+    stats = _run_fleet_sched_on(tmp_path)["sched"]
+    text = render_prometheus(stats)
+    for series in ("phase_user_seconds_total",
+                   "phase_system_seconds_total"):
+        lines = [ln for ln in text.splitlines()
+                 if ln.startswith(f"trivy_tpu_{series}{{")]
+        assert any('pipeline="secret"' in ln and 'phase="pack"' in ln
+                   for ln in lines), series
+        assert any('pipeline="host"' in ln and 'phase="gc_full"' in ln
+                   for ln in lines), series
+
+
+def test_both_snapshots_carry_host(tmp_path):
+    from trivy_tpu.detect.metrics import DETECT_METRICS
+    host = DETECT_METRICS.snapshot()["host"]
+    assert set(host) == {"process", "phase"}
+    assert host["process"]["user_s"] > 0
+    stats = _run_fleet_sched_on(tmp_path)["sched"]
+    # one key: a scheduler's snapshot carries the detect one whole
+    assert set(stats["detect"]["host"]) == {"process", "phase"}
+    assert "gc_full" in stats["detect"]["host"]["phase"]
+    assert "host" not in stats and "interval_kernel_s" not in stats

@@ -107,8 +107,11 @@ class DetectMetrics:
         # the interval and SBOM rows of the phase clock
         # (obs/trace.phase_span: decode, decode_task, join, pack,
         # h2d_upload, device_compute, finish, gc), cumulative
-        from ..obs.trace import phase_rows
+        from ..obs.trace import host_snapshot, phase_rows
         out["phase"] = phase_rows("detect")
+        # the process's CPU and the full collections, for a run
+        # with no scheduler to carry them (``scan_boms``)
+        out["host"] = host_snapshot()
         # the findings memo's outcomes (memo/metrics.py), beside the
         # memo_lookup and memo_store rows above: queries asked, those
         # answered, and layers served whole

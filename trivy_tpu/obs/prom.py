@@ -120,7 +120,8 @@ def _histograms(w: _Writer, name: str, label: str, hists: dict,
 
 def _phase_rows(w: _Writer, by_pipeline: dict) -> None:
     """The phase clock's rows (obs/trace.phase_span): runs, wall
-    seconds and thread-CPU seconds by (pipeline, phase)."""
+    seconds and thread-CPU seconds, whole and split into user and
+    system, by (pipeline, phase)."""
     rows = [(pl, ph, r) for pl, phases in sorted(by_pipeline.items())
             for ph, r in sorted((phases or {}).items())]
     if not rows:
@@ -131,7 +132,13 @@ def _phase_rows(w: _Writer, by_pipeline: dict) -> None:
              "Wall seconds inside each pipeline phase."),
             ("cpu_s", "phase_cpu_seconds_total",
              "Thread-CPU seconds inside each pipeline phase; busy "
-             "less cpu is time the thread waited.")):
+             "less cpu is time the thread waited."),
+            ("user_s", "phase_user_seconds_total",
+             "The part of the phase's thread-CPU seconds spent "
+             "computing in user space."),
+            ("sys_s", "phase_system_seconds_total",
+             "The part of the phase's thread-CPU seconds the kernel "
+             "spent on the thread's system calls.")):
         full = f"{_PREFIX}_{name}"
         w.header(full, "counter", help_)
         for pl, ph, r in rows:
@@ -292,7 +299,7 @@ def render_prometheus(stats: dict, phase_hists=None,
                  "hits/misses, resident-DB uploads).")
         for k in sorted(detect):
             if k.endswith(("_rate", "_ratio", "amortization")) \
-                    or k in ("db_upload_bytes", "phase", "memo"):
+                    or k in ("db_upload_bytes", "phase", "memo", "host"):
                 continue     # derived gauges / byte totals below —
                 # a byte count inside an event-count family would
                 # poison any sum() over it
@@ -353,7 +360,8 @@ def render_prometheus(stats: dict, phase_hists=None,
     _phase_rows(w, {"sched": stats.get("phase"),
                     "detect": detect.get("phase"),
                     "secret": secret.get("phase"),
-                    "ingest": ingest.get("phase")})
+                    "ingest": ingest.get("phase"),
+                    "host": detect.get("host", {}).get("phase")})
 
     if ingest:
         # streaming-ingest counters (docs/performance.md §9):

@@ -31,8 +31,11 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
+import gc
 import os
 import re
+import resource
 import threading
 import time
 
@@ -96,7 +99,8 @@ def add_event(name: str, **attrs) -> None:
 # dozen in all; MAX_PHASE_NAMES still folds a runaway caller.
 
 _PHASE_LOCK = threading.Lock()
-_PHASE_ROWS: dict = {}        # (pipeline, phase) -> [n, busy_s, cpu_s]
+# (pipeline, phase) -> [n, busy_s, user_s, sys_s, owed user, owed sys]
+_PHASE_ROWS: dict = {}
 
 # ``factory(name)`` -> context manager that puts ``name`` on the
 # device profiler's clock (jax.profiler.TraceAnnotation), installed
@@ -118,37 +122,104 @@ def annotation(name: str):
     return ann(name) if ann is not None else _NO_ANNOTATION
 
 
+def _thread_cpu_reader():
+    """``read()[0]``, ``read()[1]``: the calling thread's user and
+    system CPU seconds, which the kernel keeps apart
+    (``getrusage(RUSAGE_THREAD)``, Linux). The kernel moves these
+    books at its timer tick (4 ms on a stock kernel, 10 ms on the
+    chip's sandboxed host, whose ``time.thread_time()`` steps the
+    same way) and at a context switch, not at the call: a span
+    shorter than a tick reads nothing or a whole tick, and
+    :func:`book_phase` says what becomes of the tick. Where the
+    platform has no ``RUSAGE_THREAD`` all of ``time.thread_time()``
+    reads as user seconds and ``sys_s`` stays 0."""
+    which = getattr(resource, "RUSAGE_THREAD", None)
+    if which is None:
+        return lambda: (time.thread_time(), 0.0)
+    return functools.partial(resource.getrusage, which)
+
+
+_thread_cpu = _thread_cpu_reader()
+
+
+def _row_locked(pipeline: str, phase: str) -> list:
+    key = (pipeline, phase)
+    row = _PHASE_ROWS.get(key)
+    if row is None:
+        if len(_PHASE_ROWS) >= MAX_PHASE_NAMES:
+            key = (pipeline, "other")
+            row = _PHASE_ROWS.get(key)
+        if row is None:
+            row = _PHASE_ROWS[key] = [0, 0.0, 0.0, 0.0, 0.0, 0.0]
+    return row
+
+
+def ensure_phase(pipeline: str, phase: str) -> None:
+    """The row is there, at zero, before it is first booked: a
+    reader that takes a missing row for "not measured" (the
+    benchmark's) reads 0 for a wait that never happened."""
+    with _PHASE_LOCK:
+        _row_locked(pipeline, phase)
+
+
 def book_phase(pipeline: str, phase: str, busy_s: float,
-               cpu_s: float = 0.0) -> None:
+               cpu_s: float = 0.0, *, sys_s: float = 0.0) -> None:
     """One row booked by hand, for a wait that starts on one thread
     and ends on another and so has no ``with`` to stand in (the
-    scheduler's ``hit_wait``). Everything else uses
-    :func:`phase_span`."""
-    key = (pipeline, phase)
+    scheduler's ``hit_wait``: all of it ``wait_s``). ``sys_s`` is
+    the part of ``cpu_s`` spent in the kernel. Everything else uses
+    :func:`phase_span`.
+
+    Every booking is held to its wall, so ``cpu_s <= busy_s`` holds
+    for a row and for the difference of any two readings of it. CPU
+    seconds come a tick at a time, so a short span may read a whole
+    tick: what it reads beyond its wall is owed to the row's next
+    bookings, which take it as far as their own wall has room, the
+    split keeping its proportions. Over many spans nothing is lost;
+    a row's last tick may stay owed."""
     with _PHASE_LOCK:
-        row = _PHASE_ROWS.get(key)
-        if row is None:
-            if len(_PHASE_ROWS) >= MAX_PHASE_NAMES:
-                key = (pipeline, "other")
-                row = _PHASE_ROWS.get(key)
-            if row is None:
-                row = _PHASE_ROWS[key] = [0, 0.0, 0.0]
+        row = _row_locked(pipeline, phase)
+        user = cpu_s - sys_s + row[4]
+        sys_ = sys_s + row[5]
+        over = user + sys_ - busy_s
+        if over > 0.0:
+            keep = busy_s / (user + sys_)
+            row[4], row[5] = user * (1.0 - keep), sys_ * (1.0 - keep)
+            user, sys_ = user * keep, sys_ * keep
+        else:
+            row[4] = row[5] = 0.0
         row[0] += 1
         row[1] += busy_s
-        row[2] += cpu_s
+        row[2] += user
+        row[3] += sys_
+
+
+def _row_dict(n: int, busy: float, user: float = 0.0,
+              sys_: float = 0.0) -> dict:
+    cpu = min(user + sys_, busy)    # sums of floats: to the last bit
+    return {"n": n, "busy_s": busy, "cpu_s": cpu, "user_s": user,
+            "sys_s": sys_, "wait_s": busy - cpu}
 
 
 def phase_table() -> dict:
-    """``{pipeline: {phase: {"n", "busy_s", "cpu_s"}}}``, cumulative
-    since process start. ``busy_s`` is wall time inside the phase,
-    ``cpu_s`` the calling thread's CPU time inside it: busy less cpu
-    is time the thread waited (for the interpreter, a lock, the
-    device)."""
-    out: dict = {}
+    """``{pipeline: {phase: {"n", "busy_s", "cpu_s", "user_s",
+    "sys_s", "wait_s"}}}``, cumulative since process start.
+    ``busy_s`` is wall time inside the phase. The calling thread's
+    CPU time inside it is ``cpu_s``, and the kernel splits it:
+    ``user_s`` the interpreter (and native code) computing,
+    ``sys_s`` the kernel working for the thread's calls. ``wait_s``
+    (busy less cpu) is time the thread was off the CPU: waiting
+    for the interpreter, a lock, the device or a core. The CPU
+    columns are sampled at the kernel's tick, so trust a row that
+    holds many ticks."""
     with _PHASE_LOCK:
-        for (pl, ph), r in _PHASE_ROWS.items():
-            out.setdefault(pl, {})[ph] = {
-                "n": r[0], "busy_s": r[1], "cpu_s": r[2]}
+        rows = [(key, *row[:4]) for key, row in _PHASE_ROWS.items()]
+    out: dict = {}
+    for (pl, ph), n, busy, user, sys_ in rows:
+        out.setdefault(pl, {})[ph] = _row_dict(n, busy, user, sys_)
+    if _gc_full[0] is not None:
+        out.setdefault("host", {})["gc_full"] = _row_dict(
+            _gc_full[0], _gc_full[1])
     return out
 
 
@@ -157,11 +228,72 @@ def phase_rows(pipeline: str) -> dict:
     return phase_table().get(pipeline, {})
 
 
+# ---- the ``host`` pipeline: what belongs to no thread's phase ----
+
+def process_cpu() -> dict:
+    """The whole process's user and system CPU seconds, every
+    thread's and the runtime's native ones (``getrusage(
+    RUSAGE_SELF)``). Read at snapshot time, never on a hot path:
+    over a window, ``user_s`` a second of wall is how many cores'
+    worth of computing the process did, and one interpreter gives
+    at most one."""
+    r = resource.getrusage(resource.RUSAGE_SELF)
+    return {"user_s": r.ru_utime, "sys_s": r.ru_stime}
+
+
+def host_snapshot() -> dict:
+    """``{"process": {"user_s", "sys_s"}, "phase": {"gc_full"}}``:
+    what ``DETECT_METRICS.snapshot()["host"]`` carries, and with it
+    ``scheduler.stats()["detect"]["host"]``."""
+    return {"process": process_cpu(), "phase": phase_rows("host")}
+
+
+# n (None until first watched), busy_s, start, open profiler span.
+# The collector runs ``_on_gc`` on whichever thread tripped its
+# threshold, and that thread may hold any lock of this process,
+# ``_PHASE_LOCK`` included: so the row lives here, outside the
+# table, and ``_on_gc`` takes no lock. The interpreter never runs
+# two collections at a time.
+_gc_full = [None, 0.0, 0.0, _NO_ANNOTATION]
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` entry: an automatic FULL collection
+    stops every thread, so it is booked as ``host.gc_full`` and
+    bracketed ``trivy.host.gc_full`` on the profiler's clock.
+    Young and middle collections return at once."""
+    if info["generation"] < 2:
+        return
+    if phase == "start":
+        _gc_full[3] = ann = annotation("trivy.host.gc_full")
+        ann.__enter__()
+        _gc_full[2] = time.monotonic()
+    elif _gc_full[2]:               # installed before its start
+        busy = time.monotonic() - _gc_full[2]
+        _gc_full[2] = 0.0
+        _gc_full[3].__exit__(None, None, None)
+        _gc_full[0] += 1
+        _gc_full[1] += busy
+
+
+def watch_full_gc(on: bool) -> None:
+    """Install or remove :func:`_on_gc`. ``utils.sparse_full_gc``
+    calls this for its first holder and after its last, so the
+    entry is there exactly while a scheduler runs; the row is in
+    the table, at zero, from the first install on."""
+    if on:
+        if _gc_full[0] is None:
+            _gc_full[0] = 0
+        gc.callbacks.append(_on_gc)
+    else:
+        gc.callbacks.remove(_on_gc)
+
+
 class _PhaseSpanCtx:
     """Context manager behind :func:`phase_span`, and the handle the
     ``with`` binds: ``set`` forwards to the tracer span,
     ``duration_s``/``cpu_s`` hold the phase's seconds after exit
-    (error exits too)."""
+    (error exits too; ``cpu_s`` no more than ``duration_s``)."""
 
     __slots__ = ("name", "pipeline", "attrs", "span", "duration_s",
                  "cpu_s", "_token", "_ann", "_t0", "_c0")
@@ -193,28 +325,31 @@ class _PhaseSpanCtx:
         # one clock: a live tracer span's own start is the phase's
         self._t0 = time.monotonic() if self.span.noop \
             else self.span.start_mono
-        self._c0 = time.thread_time()
+        self._c0 = _thread_cpu()
         return self
 
     def __exit__(self, exc_type, *exc):
-        cpu = time.thread_time() - self._c0
+        c1, c0 = _thread_cpu(), self._c0
+        sys_ = c1[1] - c0[1]
+        cpu = c1[0] - c0[0] + sys_
         if self._token is not None:
             _ACTIVE.reset(self._token)
         self.span.end("error" if exc_type is not None else None)
         end = time.monotonic() if self.span.noop \
             else self.span.end_mono
         self.duration_s = max(0.0, end - self._t0)
-        self.cpu_s = max(0.0, min(cpu, self.duration_s))
+        self.cpu_s = min(cpu, self.duration_s)
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
         book_phase(self.pipeline, self.name, self.duration_s,
-                   self.cpu_s)
+                   cpu, sys_s=sys_)
 
 
 def phase_span(name: str, *, pipeline: str, **attrs) -> _PhaseSpanCtx:
     """``with phase_span("pack", pipeline="secret") as sp:`` — the
     one boundary marker of a pipeline phase. It always measures: on
-    exit the phase's wall and thread-CPU seconds are booked in the
+    exit the phase's wall seconds and the thread's user and system
+    CPU seconds are booked in the
     process-wide phase table (:func:`phase_rows`) and left on
     ``sp.duration_s``/``sp.cpu_s`` for the per-call stats dicts; it
     brackets ``trivy.<pipeline>.<name>`` on the device profiler's

@@ -238,6 +238,9 @@ class DispatchRing:
         self.metrics = metrics if metrics is not None \
             else RING_METRICS
         self.metrics.note_depth_limit(self.depth)
+        # a ring that never fills reads a wait of 0, not none
+        from ..obs.trace import ensure_phase
+        ensure_phase("sched", "slot_wait")
         self._cv = threading.Condition()
         self._slots: deque = deque()      # launched, not collected
         self._collecting: Optional[Slot] = None

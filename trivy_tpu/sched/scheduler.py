@@ -144,7 +144,6 @@ class ScanScheduler:
         self._threads: list = []
         self._cv = threading.Condition()
         self._analyzing = 0
-        self._kernel_s = 0.0      # interval-kernel wall (all batches)
         # monotonic end of the last metered device dispatch — the
         # demand-gated idle baseline (goodput: device time between
         # "work was ready" and "dispatch started" is waste)
@@ -363,8 +362,6 @@ class ScanScheduler:
         # SLO verdicts (obs/slo.py): burn rates over the outcome
         # stream — the autoscaling/alerting signal GET /slo serves
         out["slo"] = self.slo.snapshot()
-        with self._lock:
-            out["interval_kernel_s"] = round(self._kernel_s, 4)
         # per-tenant cost books + the goodput reconciliation
         # (docs/observability.md "Cost attribution & goodput")
         out["cost"] = self.cost_snapshot()
@@ -854,9 +851,6 @@ class ScanScheduler:
                     for i, payload in collect_dispatch(slot["ih"]):
                         detected_by.setdefault(i, []).append(
                             payload)
-                    with self._lock:
-                        self._kernel_s += slot["kstats"].get(
-                            "device_s", 0.0)
                 found_by: dict = {}
                 if slot["sieve"] is not None:
                     for idx, secret in self.secret_scanner.collect(
@@ -1048,9 +1042,6 @@ class ScanScheduler:
                                 mesh=self.mesh, stats=kstats):
                             detected_by.setdefault(i, []).append(
                                 payload)
-                        with self._lock:
-                            self._kernel_s += kstats.get(
-                                "device_s", 0.0)
 
                     found_by: dict = {}
                     if sieve_handle is not None:

@@ -60,13 +60,18 @@ def sparse_full_gc():
     collected twice (PERF.md section 6, PR 30). Held, a full
     collection is considered every ``_FULL_GC_EVERY`` middle ones;
     young and middle collections, which find a request's short-lived
-    cycles, run as before. Counted: the first holder raises the
-    threshold and the last one puts back what the first found."""
+    cycles, run as before. Each full collection that does run stops
+    every thread, so while this is held it is booked on the phase
+    clock as ``host.gc_full`` (``obs/trace.watch_full_gc``).
+    Counted: the first holder raises the threshold and the last one
+    puts back what the first found."""
+    from ..obs.trace import watch_full_gc
     with _sparse_lock:
         if not _sparse["holders"]:
             found = _sparse["found"] = gc.get_threshold()
             gc.set_threshold(found[0], found[1],
                              max(found[2], _FULL_GC_EVERY))
+            watch_full_gc(True)
         _sparse["holders"] += 1
 
     def release() -> None:
@@ -74,6 +79,7 @@ def sparse_full_gc():
             _sparse["holders"] -= 1
             if not _sparse["holders"]:
                 gc.set_threshold(*_sparse["found"])
+                watch_full_gc(False)
 
     return release
 
