@@ -742,7 +742,8 @@ def _parents_iter_fs(root, skip_dirs=(), skip_files=(), budget=None,
     """The walk as it was before it opened first: ``lstat`` for the
     size of every file, every gate asked with it, ``open`` and the
     reads by path."""
-    for rel, size, body in _walk_as_os_walk(root):
+    for rel, size, body in _walk_as_os_walk(root, skip_dirs,
+                                            skip_files):
         yield rel, size, (lambda body=body: body), None
 
 
@@ -798,6 +799,286 @@ def test_the_blob_is_the_one_of_the_walk_that_asked_lstat_first(
     assert (len(got[1]) >= 3) == (how == "streamed")
 
 
+# ---------------------------------------------------------------
+# the gates as an index, and the walk's fewer steps a file
+# ---------------------------------------------------------------
+
+def _spy_on_required(monkeypatch) -> list:
+    """Every ``required`` call made of a registered analyzer, as
+    ``(type, path, size)``."""
+    from trivy_tpu.analyzer import registered_analyzers
+    calls = []
+    for a in registered_analyzers():
+        monkeypatch.setattr(
+            a, "required",
+            lambda path, size=None, a=a, real=a.required:
+            (calls.append((a.type, path, size)),
+             real(path, size))[1])
+    return calls
+
+
+def test_a_file_costs_the_required_calls_its_name_points_at(
+        tmp_path, monkeypatch):
+    """With the default ``fs`` group a file nobody wants makes no
+    ``required`` call, a plain source file makes the secret
+    analyzer's, once, and no other, and the group's two counters
+    are what was called, where the benchmark reads them."""
+    root = make_tree(tmp_path / "tree", 61)
+    with open(os.path.join(root, "svc0", "tiny.py"), "wb") as f:
+        f.write(b"x=1\n")          # wanted but for its size
+    with open(os.path.join(root, "Makefile"), "wb") as f:
+        f.write(b"all:\n\ttrue\n" * 20)
+    monkeypatch.setattr(secret_batch, "PART_ROWS", 24)
+    calls = _spy_on_required(monkeypatch)
+    ingest0 = INGEST_METRICS.snapshot()
+    (res,), stats = streamed(root, "cpu-ref", ["vuln", "secret"],
+                             make_store())
+    assert res.status == "ok"
+    ingest1 = INGEST_METRICS.snapshot()
+    by_path: dict = {}
+    for a_type, path, size in calls:
+        by_path.setdefault(path, []).append((a_type, size))
+    files = [rel for rel, _, _ in _walk_as_os_walk(root)]
+    for trap in ("node_modules/dep/index.js", "assets/logo.png"):
+        assert trap in files and trap not in by_path
+    # the pip analyzer's by its table: no call of its own
+    assert by_path["svc0/requirements.txt"] == [("secret", None)]
+    # .json points at the one analyzer keyed by it (*.deps.json)
+    assert by_path["svc0/package-lock.json"] == [
+        ("dotnet-core", None)]
+    sources = [rel for rel in files if rel.endswith(".py")]
+    assert len(sources) >= 36
+    for rel in sources:             # tiny.py too: its size is held
+        assert by_path[rel] == [("secret", None)]   # to the key's
+    assert by_path["svc1/blob.dat"] == [("secret", None)]
+    assert sorted(by_path["Makefile"]) == [
+        ("executable-digest", None), ("gobinary", None),
+        ("rustbinary", None), ("secret", None)]
+    assert set(by_path) <= set(files)
+    assert ingest1["gate_files"] - ingest0["gate_files"] == \
+        len(files)
+    assert ingest1["gate_probes"] - ingest0["gate_probes"] == \
+        len(calls) == len(sources) + 7
+    assert stats["sched"]["ingest"]["gate_files"] >= len(files)
+    assert stats["sched"]["ingest"]["gate_probes"] >= len(calls)
+
+
+def _plain_gate(self, path, size, among=None):
+    """``AnalyzerGroup.wanted`` as every analyzer asked directly."""
+    from tests.test_analyzer_gate import brute_force
+    want = brute_force(self, path, size)
+    return want if among is None else [a for a in want if a in among]
+
+
+def _plain_analyze_into(self, result, path, content):
+    """An analyzer's findings as a result of its own, merged."""
+    from trivy_tpu.analyzer.analyzer import AnalysisResult
+    from trivy_tpu.analyzer.secret import is_binary
+    assert self.type == "secret"
+    result.merge(None if is_binary(content) else AnalysisResult(
+        secret_candidates=[(path, content)]))
+
+
+def _the_plain_way(monkeypatch):
+    """The steps this file's walk and gate no longer take, taken:
+    ``os.walk`` with its path joins, every analyzer's ``required``,
+    a result made and merged for every candidate, the skip lists
+    asked of every file of a layer."""
+    from trivy_tpu.analyzer.analyzer import AnalyzerGroup
+    from trivy_tpu.analyzer.secret import SecretCandidateAnalyzer
+    from trivy_tpu.artifact import artifact
+    monkeypatch.setattr(artifact, "iter_fs", _parents_iter_fs)
+    monkeypatch.setattr(AnalyzerGroup, "wanted", _plain_gate)
+    monkeypatch.setattr(SecretCandidateAnalyzer, "analyze_into",
+                        _plain_analyze_into)
+    asked = []
+    real = artifact.ImageArtifact._skipped
+    monkeypatch.setattr(
+        artifact.ImageArtifact, "_skipped",
+        lambda self, path: (asked.append(path), real(self, path))[1])
+    monkeypatch.setattr(
+        artifact.ImageArtifact, "_analyze_layers",
+        lambda self, *a, real=artifact.ImageArtifact._analyze_layers:
+        (setattr(self.opt, "skip_files",
+                 self.opt.skip_files or ["no/such/file"]),
+         real(self, *a))[1])
+    return asked
+
+
+SKIPS = {"none": ((), ()),
+         "set": (("svc1", "svc2/pkg0"), ("svc0/requirements.txt",))}
+
+
+@pytest.mark.parametrize("skips", sorted(SKIPS))
+@pytest.mark.parametrize("how", ["streamed", "direct"])
+@pytest.mark.parametrize("seed", [1, 2])
+def test_a_trees_blob_parts_and_report_are_the_plain_ways(
+        tmp_path, monkeypatch, seed, how, skips):
+    """Blob id, every part's files and the report, byte for byte
+    those of the walk and the gate that take every step a file."""
+    from trivy_tpu.runtime.batch import _TreeStream
+    root = make_tree(tmp_path / "tree", seed)
+    for rel, body in (("svc0/tiny.py", b"x=1\n"),
+                      ("Dockerfile", b"FROM alpine:latest\n"),
+                      ("svc3/.pyc", b"export K=ghp_" + b"c" * 36
+                       + b"\n"),
+                      ("svc2/IMG.PNG", b"ghp_" + b"A" * 36),
+                      ("svc3/node_modules", b"export K=ghp_" +
+                       b"b" * 36 + b"\n"),
+                      ("go.mod", b"module x\n\ngo 1.20\n")):
+        with open(os.path.join(root, rel), "wb") as f:
+            f.write(body)
+    monkeypatch.setattr(secret_batch, "PART_ROWS", 24)
+    skip_dirs, skip_files = SKIPS[skips]
+    checks = ["vuln", "secret", "config"]
+
+    def scan():
+        ids, parts = [], []
+        real_put = MemoryCache.put_blob
+        real_emit = _TreeStream.emit
+        with monkeypatch.context() as m:
+            m.setattr(
+                MemoryCache, "put_blob",
+                lambda self, blob_id, blob: (
+                    ids.append(blob_id),
+                    real_put(self, blob_id, blob))[1])
+            m.setattr(
+                _TreeStream, "emit",
+                lambda self, part: (parts.append(
+                    [(seq, path, len(c)) for seq, path, c in part]),
+                    real_emit(self, part))[1])
+            opts = ScanOptions(backend="cpu-ref",
+                               security_checks=checks)
+            if how == "streamed":
+                runner = BatchScanRunner(
+                    store=make_store(), backend="cpu-ref",
+                    sched="on", artifact_option=ArtifactOption(
+                        skip_dirs=list(skip_dirs),
+                        skip_files=list(skip_files),
+                        scan_misconfig=True))
+                try:
+                    (res,) = runner.scan_trees([root], opts)
+                finally:
+                    runner.close()
+                assert res.status == "ok"
+                report = render(res.report)
+            else:
+                from trivy_tpu.secret.batch import BatchSecretScanner
+                cache = MemoryCache()
+                ref = LocalFSArtifact(root, cache, ArtifactOption(
+                    secret_scanner=BatchSecretScanner(
+                        backend="cpu-ref"),
+                    skip_dirs=list(skip_dirs),
+                    skip_files=list(skip_files),
+                    scan_misconfig=True)).inspect()
+                results, os_found = LocalScanner(cache, None).scan(
+                    ScanTarget(name=ref.name, artifact_id=ref.id,
+                               blob_ids=ref.blob_ids), opts)
+                report = _cli_render(Report(
+                    artifact_name=root, artifact_type="filesystem",
+                    metadata=Metadata(os=os_found), results=results))
+        return ids, parts, report
+
+    got = scan()
+    with monkeypatch.context() as m:
+        _the_plain_way(m)
+        want = scan()
+    assert got == want
+    assert len(got[0]) == 1 and got[0][0].startswith("sha256:")
+    assert (len(got[1]) >= 3) == (how == "streamed")
+    shown = json.loads(got[2])
+    targets = {r["Target"] for r in shown["Results"]}
+    # a file called node_modules, and a dotfile with no extension
+    assert {"svc3/node_modules", "svc3/.pyc"} <= targets
+    assert not any(t.endswith("IMG.PNG") for t in targets)
+    if skips == "set":
+        assert not any(t.startswith(("svc1/", "svc2/pkg0/"))
+                       for t in targets)
+
+
+def _three_layer_image(path: str, seed: int) -> str:
+    from trivy_tpu.utils.synth import APK_PARAGRAPH, write_image_tar
+    rng = np.random.default_rng([seed, 7])
+    apk = "".join(APK_PARAGRAPH.format(name=f"pkg{i}",
+                                       version=f"1.{i}.2-r0")
+                  for i in range(8)).encode()
+    app = {f"srv/app/mod{i}/{WORDS[i]}.py": _text(rng, 400 + 90 * i)
+           for i in range(12)}
+    app["srv/app/mod0/cfg.env"] = b"# service\n" + _pat_line(rng)
+    app["srv/app/mod1/requirements.txt"] = b"flask==1.0.0\n"
+    app["srv/app/node_modules/dep/index.js"] = _pat_line(rng)
+    app["srv/app/mod2/logo.png"] = _pat_line(rng)
+    app["srv/app/mod3/.pyc"] = b"# a dotfile\n" + _pat_line(rng)
+    top = {"srv/app/mod4/IMG.PNG": _pat_line(rng),
+           "srv/app/mod5/key.txt": _text(rng, 200) + _pat_line(rng),
+           "srv/app/mod5/.wh.old.txt": b"",
+           "usr/local/bin/tool": b"\x7fELF" + bytes(200),
+           "srv/skipme/secret.txt": _pat_line(rng),
+           "srv/app/go.mod": b"module x\n\ngo 1.20\n"}
+    layers = [{"etc/alpine-release": b"3.16.2\n",
+               "etc/os-release": b"ID=alpine\nVERSION_ID=3.16.2\n",
+               "lib/apk/db/installed": apk}, app, top]
+    return write_image_tar(path, layers, f"t/img:{seed}")
+
+
+@pytest.mark.parametrize("skips", ["none", "set"])
+@pytest.mark.parametrize("seed", [1, 2])
+def test_an_images_blobs_and_report_are_the_plain_ways(
+        tmp_path, monkeypatch, seed, skips):
+    """A three-layer image: blob ids, the blobs and the report, byte
+    for byte those of the gate that asks every analyzer and of the
+    skip lists asked of every file; with both lists empty
+    ``_skipped`` is not called a file."""
+    from trivy_tpu.artifact import artifact
+    from trivy_tpu.artifact.image import load_image
+    from trivy_tpu.secret.batch import BatchSecretScanner
+    path = _three_layer_image(str(tmp_path / "img.tar"), seed)
+    skip_dirs, skip_files = {
+        "none": ([], []),
+        "set": (["srv/skipme", "/srv/app/mod2"],
+                ["/srv/app/mod5/key.txt"])}[skips]
+    checks = ["vuln", "secret"]
+
+    def scan():
+        cache = MemoryCache()
+        ref = artifact.ImageArtifact(
+            load_image(path), cache, ArtifactOption(
+                secret_scanner=BatchSecretScanner(backend="cpu-ref"),
+                skip_dirs=list(skip_dirs),
+                skip_files=list(skip_files))).inspect()
+        blobs = [json.dumps(cache.get_blob(b).to_dict(),
+                            sort_keys=True) for b in ref.blob_ids]
+        results, os_found = LocalScanner(cache, make_store()).scan(
+            ScanTarget(name=ref.name, artifact_id=ref.id,
+                       blob_ids=ref.blob_ids),
+            ScanOptions(backend="cpu-ref", security_checks=checks))
+        return ref.id, ref.blob_ids, blobs, render(Report(
+            artifact_name=path, artifact_type="container_image",
+            metadata=Metadata(os=os_found), results=results))
+
+    asked = []
+    real = artifact.ImageArtifact._skipped
+    monkeypatch.setattr(
+        artifact.ImageArtifact, "_skipped",
+        lambda self, path: (asked.append(path), real(self, path))[1])
+    got = scan()
+    assert bool(asked) == (skips == "set")
+    with monkeypatch.context() as m:
+        plain_asked = _the_plain_way(m)
+        want = scan()
+        assert len(plain_asked) >= 20
+    assert got == want
+    assert len(got[1]) == 3
+    targets = {r["Target"] for r in json.loads(got[3])["Results"]
+               if r.get("Secrets")}
+    assert "/srv/app/mod0/cfg.env" in targets
+    assert "/srv/app/mod3/.pyc" in targets
+    assert not any(t.endswith((".png", ".PNG", "index.js"))
+                   for t in targets)
+    assert ("/srv/skipme/secret.txt" in targets) == (skips == "none")
+    assert ("/srv/app/mod5/key.txt" in targets) == (skips == "none")
+
+
 def _fds_into(root: str) -> list:
     out = []
     for fd in os.listdir("/proc/self/fd"):
@@ -823,18 +1104,18 @@ def test_no_file_of_the_tree_is_left_open(tmp_path, monkeypatch, how):
     root = make_tree(tmp_path / "tree", 59)
     monkeypatch.setattr(secret_batch, "PART_ROWS", 12)
     held, reqs = [], []
-    real = secret_analyzer.SecretCandidateAnalyzer.analyze
+    real = secret_analyzer.SecretCandidateAnalyzer.analyze_into
 
-    def analyze(self, path, content):
+    def analyze_into(self, result, path, content):
         held.append(len(_fds_into(root)))
         if how == "analyzer-error" and len(held) == 20:
             raise RuntimeError("analyzer gave up")
         if how == "cancelled" and len(held) == 20:
             reqs[0].cancel()
-        return real(self, path, content)
+        real(self, result, path, content)
 
-    monkeypatch.setattr(secret_analyzer.SecretCandidateAnalyzer, "analyze",
-                        analyze)
+    monkeypatch.setattr(secret_analyzer.SecretCandidateAnalyzer,
+                        "analyze_into", analyze_into)
     if how in ("expired", "cancelled"):
         real_collect = secret_batch.BatchSecretScanner.collect
 

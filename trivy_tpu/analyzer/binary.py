@@ -19,7 +19,8 @@ from typing import Optional
 
 from ..types import Package
 from ..utils import get_logger
-from .analyzer import AnalysisResult, Analyzer, register_analyzer
+from .analyzer import (AnalysisResult, Analyzer, GateKey,
+                       register_analyzer)
 from .language import _app
 
 log = get_logger("analyzer.binary")
@@ -39,13 +40,10 @@ def _looks_executable(content: bytes) -> bool:
         content[:4] in _MACHO
 
 
-def _binary_required(path: str, size) -> bool:
-    if size is not None and (size < 64 or size > MAX_BINARY_SIZE):
-        return False
-    base = path.rsplit("/", 1)[-1]
-    # extension-less files and Windows executables; magic is checked
-    # on content before any parsing
-    return "." not in base or base.endswith(".exe")
+# extension-less files and Windows executables; magic is checked on
+# content before any parsing
+_BINARY_KEY = GateKey(dotless_or_exe=True,
+                      sizes=(64, MAX_BINARY_SIZE))
 
 
 def _read_var_string(data: bytes, off: int):
@@ -128,9 +126,7 @@ def parse_go_modules(mod_text: str) -> list:
 class GoBinaryAnalyzer(Analyzer):
     type = "gobinary"
     version = 1
-
-    def required(self, path: str, size: Optional[int] = None) -> bool:
-        return _binary_required(path, size)
+    key = _BINARY_KEY
 
     def analyze(self, path: str, content: bytes) -> AnalysisResult:
         if not _looks_executable(content):
@@ -175,9 +171,7 @@ def parse_rust_audit(content: bytes):
 class RustBinaryAnalyzer(Analyzer):
     type = "rustbinary"
     version = 1
-
-    def required(self, path: str, size: Optional[int] = None) -> bool:
-        return _binary_required(path, size)
+    key = _BINARY_KEY
 
     def analyze(self, path: str, content: bytes) -> AnalysisResult:
         if not _looks_executable(content):
@@ -211,12 +205,13 @@ class ExecutableDigestAnalyzer(Analyzer):
 
     type = "executable-digest"
     version = 1
+    key = _BINARY_KEY
 
     def required(self, path: str, size: Optional[int] = None) -> bool:
         import os
         if not os.environ.get("TRIVY_REKOR_URL"):
             return False
-        return _binary_required(path, size)
+        return super().required(path, size)
 
     def analyze(self, path: str, content: bytes) -> AnalysisResult:
         r = AnalysisResult()

@@ -15,7 +15,8 @@ from __future__ import annotations
 import json
 import posixpath
 
-from .analyzer import AnalysisResult, Analyzer, register_analyzer
+from .analyzer import (AnalysisResult, Analyzer, GateKey,
+                       register_analyzer)
 
 
 @register_analyzer
@@ -25,6 +26,7 @@ class ContentManifestAnalyzer(Analyzer):
 
     type = "redhat content manifest"
     version = 1
+    key = GateKey(dirs=("root/buildinfo/content_manifests/",))
 
     def required(self, path, size=None):
         head, name = posixpath.split(path)
@@ -53,6 +55,7 @@ class BuildInfoDockerfileAnalyzer(Analyzer):
 
     type = "redhat dockerfile"
     version = 1
+    key = GateKey(dirs=("root/buildinfo/",))
 
     def required(self, path, size=None):
         head, name = posixpath.split(path)

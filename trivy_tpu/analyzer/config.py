@@ -15,7 +15,8 @@ import os
 from typing import Optional
 
 from ..types import ConfigFile
-from .analyzer import AnalysisResult, Analyzer, register_analyzer
+from .analyzer import (AnalysisResult, Analyzer, GateKey,
+                       register_analyzer)
 
 # collectors skip anything bigger — IaC files are small; big yaml/json
 # blobs are data, not config
@@ -51,21 +52,14 @@ class DockerfileAnalyzer(_Collector):
 @register_analyzer
 class YamlConfigAnalyzer(_Collector):
     type = "yaml"
-
-    def required(self, path: str, size: Optional[int] = None) -> bool:
-        if size is not None and size > MAX_CONFIG_SIZE:
-            return False
-        return path.endswith((".yaml", ".yml"))
+    key = GateKey(suffixes=(".yaml", ".yml"),
+                  sizes=(0, MAX_CONFIG_SIZE))
 
 
 @register_analyzer
 class JsonConfigAnalyzer(_Collector):
     type = "json"
-
-    def required(self, path: str, size: Optional[int] = None) -> bool:
-        if size is not None and size > MAX_CONFIG_SIZE:
-            return False
-        return path.endswith(".json")
+    key = GateKey(suffixes=(".json",), sizes=(0, MAX_CONFIG_SIZE))
 
 
 @register_analyzer
@@ -75,9 +69,5 @@ class TerraformConfigAnalyzer(_Collector):
     JSON collector's CFN/k8s sniffing)."""
 
     type = "terraform"
-
-    def required(self, path: str, size: Optional[int] = None) -> bool:
-        if size is not None and size > MAX_CONFIG_SIZE:
-            return False
-        return path.endswith(".tf")
+    key = GateKey(suffixes=(".tf",), sizes=(0, MAX_CONFIG_SIZE))
 

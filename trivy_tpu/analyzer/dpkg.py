@@ -7,7 +7,8 @@ from __future__ import annotations
 import re
 
 from ..types import Package, PackageInfo
-from .analyzer import AnalysisResult, Analyzer, register_analyzer
+from .analyzer import (AnalysisResult, Analyzer, GateKey,
+                       register_analyzer)
 
 _STATUS = "var/lib/dpkg/status"
 _STATUS_DIR = "var/lib/dpkg/status.d/"
@@ -36,6 +37,7 @@ def _split_version(full: str) -> tuple:
 class DpkgAnalyzer(Analyzer):
     type = "dpkg"
     version = 3
+    key = GateKey(dirs=("var/lib/dpkg/",))
 
     def required(self, path, size=None):
         return (path == _STATUS or path.startswith(_STATUS_DIR)

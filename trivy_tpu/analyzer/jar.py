@@ -14,11 +14,11 @@ import io
 import posixpath
 import re
 import zipfile
-from typing import Optional
 
 from ..types import Package
 from ..utils import get_logger
-from .analyzer import AnalysisResult, Analyzer, register_analyzer
+from .analyzer import (AnalysisResult, Analyzer, GateKey,
+                       register_analyzer)
 from .language import _app
 
 log = get_logger("analyzer.jar")
@@ -153,9 +153,7 @@ def _scan_zip(path: str, data: bytes, depth: int,
 class JarAnalyzer(Analyzer):
     type = "jar"
     version = 1
-
-    def required(self, path: str, size: Optional[int] = None) -> bool:
-        return path.endswith(_EXTS)
+    key = GateKey(suffixes=_EXTS)
 
     def analyze(self, path: str, content: bytes) -> AnalysisResult:
         pkgs: list = []

@@ -13,7 +13,8 @@ import re
 
 from ..types import Application, Package
 from ..types.artifact import Location
-from .analyzer import AnalysisResult, Analyzer, register_analyzer
+from .analyzer import (AnalysisResult, Analyzer, GateKey,
+                       register_analyzer)
 
 
 def _app(app_type: str, path: str, pkgs: list) -> AnalysisResult:
@@ -446,9 +447,7 @@ class GradleLockAnalyzer(Analyzer):
 
     type = "gradle"
     version = 1
-
-    def required(self, path, size=None):
-        return posixpath.basename(path).endswith("gradle.lockfile")
+    key = GateKey(suffixes=("gradle.lockfile",))
 
     def analyze(self, path, content):
         pkgs: dict = {}
@@ -478,9 +477,7 @@ class GoModAnalyzer(Analyzer):
 
     type = "gomod"
     version = 2
-
-    def required(self, path, size=None):
-        return posixpath.basename(path) in ("go.mod", "go.sum")
+    key = GateKey(basenames=frozenset({"go.mod", "go.sum"}))
 
     def analyze(self, path, content):
         if posixpath.basename(path) == "go.sum":
@@ -534,10 +531,8 @@ class NugetLockAnalyzer(Analyzer):
 
     type = "nuget"
     version = 1
-
-    def required(self, path, size=None):
-        return posixpath.basename(path) in ("packages.lock.json",
-                                            "packages.config")
+    key = GateKey(basenames=frozenset({"packages.lock.json",
+                                       "packages.config"}))
 
     def analyze(self, path, content):
         if path.endswith("packages.config"):
@@ -588,9 +583,7 @@ class DotNetDepsAnalyzer(Analyzer):
 
     type = "dotnet-core"
     version = 1
-
-    def required(self, path, size=None):
-        return path.endswith(".deps.json")
+    key = GateKey(suffixes=(".deps.json",))
 
     def analyze(self, path, content):
         try:

@@ -21,7 +21,8 @@ from typing import Optional
 from ..licensing import normalize
 from ..licensing.classifier import classify, is_human_readable
 from ..types import LicenseFile, LicenseFinding
-from .analyzer import AnalysisResult, Analyzer, register_analyzer
+from .analyzer import (AnalysisResult, Analyzer, GateKey,
+                       register_analyzer)
 
 LICENSE_ANALYZER_TYPES = ("license-file", "dpkg-license")
 
@@ -60,6 +61,8 @@ def _is_license_filename(path: str) -> bool:
 class LicenseFileAnalyzer(Analyzer):
     type = "license-file"
     version = 1
+    key = GateKey(everything=True, but_dirs=_SKIP_DIRS,
+                  sizes=(0, MAX_LICENSE_SIZE))
 
     def required(self, path: str, size: Optional[int] = None) -> bool:
         if size is not None and size > MAX_LICENSE_SIZE:
@@ -94,6 +97,7 @@ _COPYRIGHT_PATH_RE = re.compile(
 class DpkgLicenseAnalyzer(Analyzer):
     type = "dpkg-license"
     version = 1
+    key = GateKey(basenames=frozenset({"copyright"}))
 
     def required(self, path: str, size: Optional[int] = None) -> bool:
         return _COPYRIGHT_PATH_RE.match(path) is not None

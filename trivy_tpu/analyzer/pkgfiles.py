@@ -15,7 +15,8 @@ import re
 from typing import Optional
 
 from ..types import Package
-from .analyzer import AnalysisResult, Analyzer, register_analyzer
+from .analyzer import (AnalysisResult, Analyzer, GateKey,
+                       register_analyzer)
 from .language import _app
 
 
@@ -26,11 +27,8 @@ class PythonPkgAnalyzer(Analyzer):
 
     type = "python-pkg"
     version = 1
-
-    def required(self, path: str, size: Optional[int] = None) -> bool:
-        return path.endswith((".dist-info/METADATA",
-                              ".egg-info/PKG-INFO",
-                              ".egg-info"))
+    key = GateKey(suffixes=(".dist-info/METADATA",
+                            ".egg-info/PKG-INFO", ".egg-info"))
 
     def analyze(self, path: str, content: bytes) -> AnalysisResult:
         headers = {}
@@ -100,6 +98,7 @@ class GemspecAnalyzer(Analyzer):
 
     type = "gemspec"
     version = 1
+    key = GateKey(suffixes=(".gemspec",))
 
     def required(self, path: str, size: Optional[int] = None) -> bool:
         return "specifications/" in path and \

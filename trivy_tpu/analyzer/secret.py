@@ -13,7 +13,8 @@ from __future__ import annotations
 
 import posixpath
 
-from .analyzer import AnalysisResult, Analyzer, register_analyzer
+from .analyzer import (AnalysisResult, Analyzer, GateKey,
+                       register_analyzer)
 
 SKIP_FILES = {"go.mod", "go.sum", "package-lock.json", "yarn.lock",
               "pnpm-lock.yaml", "Pipfile.lock", "Gemfile.lock"}
@@ -33,29 +34,28 @@ class SecretCandidateAnalyzer(Analyzer):
     type = "secret"
     version = 1
     config_path = ""      # set from --secret-config (secret.go:135)
+    key = GateKey(everything=True, but_dirs=tuple(sorted(SKIP_DIRS)),
+                  but_basenames=frozenset(SKIP_FILES),
+                  but_exts=frozenset(SKIP_EXTS), sizes=(10, None))
 
     def required(self, path, size=None):
-        if size is not None and size < 10:
-            return False
-        dir_, name = posixpath.split(path)
-        if SKIP_DIRS & set(dir_.split("/")):
-            return False
-        if name in SKIP_FILES:
-            return False
-        ext = posixpath.splitext(name)[1].lower()
-        if ext in SKIP_EXTS:
-            return False
         # the secret-rule config itself is never scanned; the
         # reference compares basename(configPath) against the walked
         # path (secret.go:135) — a deliberate quirk we replicate
         # exactly (a top-level file merely SHARING the config's name
-        # is skipped there too)
+        # is skipped there too). Asked each time, so a config_path
+        # set after a group was built (cli.py) is honoured.
         if self.config_path and \
                 posixpath.basename(self.config_path) == path:
             return False
-        return True
+        return Analyzer.required(self, path, size)
 
     def analyze(self, path, content):
-        if is_binary(content):
-            return None
-        return AnalysisResult(secret_candidates=[(path, content)])
+        result = AnalysisResult()
+        self.analyze_into(result, path, content)
+        return result
+
+    def analyze_into(self, result, path, content):
+        # most files of a scan come here: no result of its own
+        if not is_binary(content):
+            result.secret_candidates.append((path, content))

@@ -196,7 +196,7 @@ class ImageArtifact:
             # benchmark's snapshots read it (artifact/metrics.py)
             INGEST_METRICS.note_inspect(
                 len(blob_ids), len(todo), self._bytes_analyzed,
-                len(base))
+                len(base), self.group.take_gate_counts())
             if missing_artifact and \
                     getattr(self, "_os_found", None) is None:
                 # OS layer may be a cache hit while the artifact
@@ -274,6 +274,9 @@ class ImageArtifact:
     def _analyze_layers(self, todo: list, layer_results: list,
                         all_candidates: list, base: set) -> None:
         from ..obs.trace import add_event, phase_span
+        # the skip lists are asked of a file only where one is set
+        skipped = self._skipped if self.opt.skip_dirs \
+            or self.opt.skip_files else None
         for i in todo:
             layer = self.image.layers[i]
             result = AnalysisResult()
@@ -289,7 +292,7 @@ class ImageArtifact:
                     files, opq_dirs, wh_files = collect_layer_tar(
                         tf, budget=self.budget)
                     for path, size, read in files:
-                        if self._skipped(path):
+                        if skipped is not None and skipped(path):
                             continue
                         self._bytes_analyzed += size
                         self.group.analyze_file(result, path, read,
@@ -393,7 +396,8 @@ class LocalFSArtifact:
                     stream.emit(parts.pop(0))
                 else:
                     parts.pop(0)
-        INGEST_METRICS.note_tree(n_files, n_candidates, n_bytes)
+        INGEST_METRICS.note_tree(n_files, n_candidates, n_bytes,
+                                 self.group.take_gate_counts())
         return stream.finish()
 
     def __init__(self, root: str, cache,

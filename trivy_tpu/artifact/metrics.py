@@ -41,6 +41,10 @@ class IngestMetrics:
         # analyzer's skips: directory, name, extension, size, a NUL
         # in the head), and the bytes of those that are
         "tree_files", "tree_files_skipped", "tree_bytes",
+        # AnalyzerGroup's gate, image layers and trees alike: files
+        # it was asked about and the analyzers' ``required`` calls
+        # that took (its index answers most files with none or one)
+        "gate_files", "gate_probes",
     )
 
     def __init__(self):
@@ -55,9 +59,12 @@ class IngestMetrics:
             self.counters[name] = self.counters.get(name, 0) + n
 
     def note_inspect(self, layers: int, analyzed: int,
-                     nbytes: int, base: int) -> None:
+                     nbytes: int, base: int,
+                     gates: tuple = (0, 0)) -> None:
         with self._lock:
             c = self.counters
+            c["gate_files"] += gates[0]
+            c["gate_probes"] += gates[1]
             c["layers_seen"] += layers
             c["layers_cached"] += layers - analyzed
             c["layers_analyzed"] += analyzed
@@ -65,9 +72,11 @@ class IngestMetrics:
             c["base_layers_skipped"] += base
 
     def note_tree(self, files: int, candidates: int,
-                  nbytes: int) -> None:
+                  nbytes: int, gates: tuple = (0, 0)) -> None:
         with self._lock:
             c = self.counters
+            c["gate_files"] += gates[0]
+            c["gate_probes"] += gates[1]
             c["tree_files"] += files
             c["tree_files_skipped"] += files - candidates
             c["tree_bytes"] += nbytes

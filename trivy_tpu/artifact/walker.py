@@ -17,6 +17,7 @@ from __future__ import annotations
 import os
 import posixpath
 import tarfile
+from functools import partial
 from typing import Callable, Optional
 
 from ..guard.budget import GUARD_METRICS, ResourceBudget
@@ -210,7 +211,12 @@ def iter_fs(root: str, skip_dirs: list = (),
     after the walk has moved on, reads by path). A file that cannot
     be opened is asked ``lstat``: gone, and the walk raises here;
     there and wanted, and its ``read_fn`` raises what ``open``
-    raised."""
+    raised.
+
+    What a file costs besides: one relative path, built from its
+    directory's; the skip lists are not asked where they are empty,
+    and a file's path from the root is built only when the file has
+    to be read by it."""
     skip_dirs = _clean_skip(skip_dirs)
     skip_files = _clean_skip(skip_files)
     root_prefix = posixpath.normpath(
@@ -236,21 +242,23 @@ def iter_fs(root: str, skip_dirs: list = (),
         try:
             files = [e for e in entries
                      if e.is_file(follow_symlinks=False)]
+            prefix = rel_dir + "/" if rel_dir else ""
             for e in sorted(files, key=lambda e: e.name):
-                rel = posixpath.join(rel_dir, e.name)
-                if skipped(rel, skip_files):
+                name = e.name
+                rel = prefix + name
+                if skip_files and skipped(rel, skip_files):
                     continue
-                full = os.path.join(dirpath, e.name)
                 if budget is not None:   # before the file is touched
                     budget.check_deadline()
                     budget.charge_entry()
                 wanted = gate(rel, None) if gate is not None else None
                 opened = None
                 if gate is not None and not wanted:
-                    size, read = None, _file_reader(full, 0)
+                    size, read = None, partial(_read_path, dirpath,
+                                               name, 0)
                 else:
                     try:
-                        read = opened = _OpenFile(e.name, dfd, full)
+                        read = opened = _OpenFile(name, dfd, dirpath)
                         size = opened.size
                     except OSError as err:
                         size = e.stat(follow_symlinks=False).st_size
@@ -274,7 +282,7 @@ def iter_fs(root: str, skip_dirs: list = (),
         for e in entries:
             if e.is_dir(follow_symlinks=False):
                 rel = posixpath.join(rel_dir, e.name)
-                if not skipped(rel, skip_dirs):
+                if not (skip_dirs and skipped(rel, skip_dirs)):
                     yield from walk(os.path.join(dirpath, e.name),
                                     rel)
 
@@ -299,17 +307,15 @@ def _read_fd(fd: int, size: int) -> bytes:
     return b"".join(chunks)
 
 
-def _file_reader(full: str, size: int) -> Callable:
+def _read_path(dirpath: str, name: str, size: int) -> bytes:
     """The file's bytes by path: ``open``, the reads to its end and
     ``close`` (four system calls where a buffered ``open().read()``
     makes six)."""
-    def read() -> bytes:
-        fd = os.open(full, os.O_RDONLY)
-        try:
-            return _read_fd(fd, size)
-        finally:
-            os.close(fd)
-    return read
+    fd = os.open(os.path.join(dirpath, name), os.O_RDONLY)
+    try:
+        return _read_fd(fd, size)
+    finally:
+        os.close(fd)
 
 
 class _OpenFile:
@@ -317,10 +323,11 @@ class _OpenFile:
     descriptor, which is closed with the read or when the walk moves
     on; after that, by path like any other file's."""
 
-    __slots__ = ("fd", "full", "size")
+    __slots__ = ("fd", "dirpath", "name", "size")
 
-    def __init__(self, name: str, dir_fd: int, full: str):
-        self.full = full
+    def __init__(self, name: str, dir_fd: int, dirpath: str):
+        self.dirpath = dirpath
+        self.name = name
         self.fd = os.open(name, os.O_RDONLY, dir_fd=dir_fd)
         try:
             self.size = os.fstat(self.fd).st_size
@@ -330,7 +337,7 @@ class _OpenFile:
 
     def __call__(self) -> bytes:
         if self.fd is None:
-            return _file_reader(self.full, self.size)()
+            return _read_path(self.dirpath, self.name, self.size)
         try:
             return _read_fd(self.fd, self.size)
         finally:

@@ -408,7 +408,12 @@ def render_prometheus(stats: dict, phase_hists=None,
                  "Of them, files that were no secret candidate."),
                 ("tree_bytes",
                  "Candidate bytes streamed to the sieve from "
-                 "trees.")):
+                 "trees."),
+                ("gate_files",
+                 "Files the analyzers' gate was asked about."),
+                ("gate_probes",
+                 "Analyzer required() calls the gate made for "
+                 "them.")):
             w.scalar(f"{_PREFIX}_ingest_{k}_total", "counter",
                      help_, ingest.get(k))
 
