@@ -12,6 +12,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import threading
 from typing import Optional
 
 from ..types import ArtifactInfo, BlobInfo
@@ -114,7 +115,10 @@ class FSCache(MemoryCache):
 
     def _write(self, kind: str, key: str, obj) -> None:
         path = self._path(kind, key)
-        tmp = path + ".tmp"
+        # a temp file of the writer's own: two clients that push the
+        # same base layer at once (each was told it is missing)
+        # would else write one file together
+        tmp = f"{path}.{threading.get_ident()}.tmp"
         data = obj.to_dict() if hasattr(obj, "to_dict") else obj
         with open(tmp, "w", encoding="utf-8") as f:
             json.dump(data, f)

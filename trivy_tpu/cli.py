@@ -1165,7 +1165,7 @@ def run_k8s(args) -> int:
 
 
 def run_server(args) -> int:
-    from .rpc.server import ScanServer, serve_forever
+    from .rpc.server import build_server, serve_forever
     host, _, port = args.listen.rpartition(":")
     if not port.isdigit():
         print(f"error: --listen needs host:port, got "
@@ -1197,10 +1197,9 @@ def run_server(args) -> int:
         return rc
     _resolve_device(args)
     sched = "off"
-    scheduler = None
     if getattr(args, "sched", "on") == "on":
         try:
-            cfg = _sched_config(args)
+            sched = _sched_config(args)
         except ValueError as e:
             print(f"error: --tenant-config/--tenant-budget: "
                   f"{e}", file=sys.stderr)
@@ -1208,25 +1207,12 @@ def run_server(args) -> int:
         if getattr(args, "sched_deadline", ""):
             from .flag import parse_duration
             try:
-                cfg.default_deadline_s = parse_duration(
+                sched.default_deadline_s = parse_duration(
                     args.sched_deadline)
             except ValueError as e:
                 print(f"error: --sched-deadline: {e}",
                       file=sys.stderr)
                 return 2
-        if slos is not None:
-            cfg.slos = slos
-        # the scheduler is built HERE (not inside ScanServer) so the
-        # admission webhook's image scans share it — and so it
-        # carries a secret scanner, which blob-only RPC scans never
-        # needed but admission-path image loads do
-        from .secret.batch import BatchSecretScanner
-        from .sched import ScanScheduler
-        scheduler = ScanScheduler(
-            config=cfg, backend="tpu",
-            secret_scanner=BatchSecretScanner(backend="tpu"))
-        sched = scheduler
-    injector = _fault_injector(args)
     federator = None
     if getattr(args, "federate_peers", ""):
         from .obs.federate import Federator, parse_peers
@@ -1241,55 +1227,43 @@ def run_server(args) -> int:
             timeout_s=getattr(args, "federate_timeout", 2.0),
             stale_after_s=getattr(args, "federate_stale_after",
                                   60.0))
-    memo = _memo(args, injector=injector)
-    impact = None
-    if getattr(args, "impact_index", False):
-        if memo is None:
-            print("error: --impact-index needs the findings memo "
-                  "(drop --no-memo)", file=sys.stderr)
-            return 2
-        from .impact import ImpactIndex
-        impact = ImpactIndex(
-            store=memo.store,
-            name=getattr(args, "replica_name", "") or args.listen)
-        # a restarted / rescheduled replica recovers its slice from
-        # the shared memo tier before taking queries — the
-        # elasticity story (docs/serving.md)
-        impact.rebuild(memo, store)
+    no_memo = getattr(args, "no_memo", False)
     prewarm_members = [m.strip() for m in
                        getattr(args, "prewarm_members",
                                "").split(",") if m.strip()]
-    if prewarm_members and memo is None:
-        print("error: --prewarm-members needs the findings memo "
-              "(drop --no-memo)", file=sys.stderr)
-        return 2
-    server = ScanServer(store=store,
-                        cache_dir=args.cache_dir,
-                        token=args.auth_token,
-                        token_header=args.token_header,
-                        sched=sched,
-                        slos=None if scheduler is not None else slos,
-                        memo=memo,
-                        impact=impact,
-                        federator=federator,
-                        replica_name=(
-                            getattr(args, "replica_name", "")
-                            or args.listen),
-                        compile_cache_dir=getattr(
-                            args, "compile_cache", ""),
-                        prewarm_members=prewarm_members,
-                        prewarm_deadline_s=getattr(
-                            args, "prewarm_deadline", 5.0))
-    server.fault_injector = injector
+    for flag, given in (("--impact-index",
+                         getattr(args, "impact_index", False)),
+                        ("--prewarm-members", prewarm_members)):
+        if given and no_memo:
+            print(f"error: {flag} needs the findings memo "
+                  "(drop --no-memo)", file=sys.stderr)
+            return 2
+    # scheduler, memo, cache, server and the warm of the interval
+    # ladder: one function, which the benchmark's served cell calls
+    # too (rpc/server.build_server)
+    server = build_server(
+        store=store, sched=sched, slos=slos,
+        cache_dir=args.cache_dir, memo=not no_memo,
+        memo_uri=getattr(args, "memo_cache", ""),
+        fault_injector=_fault_injector(args),
+        impact_index=getattr(args, "impact_index", False),
+        compile_cache_dir=getattr(args, "compile_cache", ""),
+        replica_name=(getattr(args, "replica_name", "")
+                      or args.listen),
+        token=args.auth_token, token_header=args.token_header,
+        federator=federator, prewarm_members=prewarm_members,
+        prewarm_deadline_s=getattr(args, "prewarm_deadline", 5.0))
     adm_runner = None
     try:
         server.admission, adm_runner = _admission_controller(
             args, server)
     except ValueError as e:
+        server.close()
         print(f"error: --admission-policy: {e}", file=sys.stderr)
         return 2
     print(f"trivy-tpu server listening on {args.listen}")
     try:
+        # its shutdown closes the scheduler build_server made
         return serve_forever(
             host or "127.0.0.1", int(port), server,
             db_watch_prefix=args.compiled_db,
@@ -1298,8 +1272,6 @@ def run_server(args) -> int:
     finally:
         if adm_runner is not None:
             adm_runner.close()
-        if scheduler is not None:
-            scheduler.close()
 
 
 def run_route(args) -> int:

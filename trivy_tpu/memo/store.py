@@ -91,7 +91,11 @@ class FSMemoStore:
 
     def put(self, key: str, data: bytes) -> None:
         path = self._path(key)
-        tmp = path + ".tmp"
+        # a temp file of the writer's own: two requests that miss
+        # the same layer store the same key at once, and one name
+        # for both let the second truncate what the first was about
+        # to move into place (and fail the first's rename)
+        tmp = f"{path}.{threading.get_ident()}.tmp"
         with open(tmp, "wb") as f:
             f.write(data)
         os.replace(tmp, path)

@@ -357,11 +357,33 @@ def render_prometheus(stats: dict, phase_hists=None,
                  secret.get("dfa_upload_amortization"))
 
     ingest = stats.get("ingest") or {}
+    rpc = stats.get("rpc") or {}
     _phase_rows(w, {"sched": stats.get("phase"),
                     "detect": detect.get("phase"),
                     "secret": secret.get("phase"),
                     "ingest": ingest.get("phase"),
+                    "rpc": rpc.get("phase"),
                     "host": detect.get("host", {}).get("phase")})
+
+    if rpc:
+        # the wire's counters of a ScanServer (rpc/metrics.py)
+        full = f"{_PREFIX}_rpc_requests_total"
+        w.header(full, "counter", "RPC calls handled, by method.")
+        for method, n in sorted(rpc.get("requests", {}).items()):
+            w.sample(full, [("method", method)], n)
+        for k, help_ in (
+                ("bytes_in", "Request body bytes read."),
+                ("bytes_out", "Response body bytes written."),
+                ("shed_503",
+                 "Scans the admission queue shed with a 503."),
+                ("retried",
+                 "Scans that came again under an idempotency key "
+                 "the server had met."),
+                ("blobs_asked", "Blobs MissingBlobs was asked for."),
+                ("blobs_held",
+                 "Of them, blobs the server's cache held.")):
+            w.scalar(f"{_PREFIX}_rpc_{k}_total", "counter", help_,
+                     rpc.get(k))
 
     if ingest:
         # streaming-ingest counters (docs/performance.md §9):

@@ -27,7 +27,9 @@ from ..types.convert import (artifact_info_from_dict,
                              blob_info_from_dict)
 from ..obs.propagate import TRACEPARENT_HEADER
 from ..obs.propagate import extract as extract_context
+from ..obs.trace import phase_span
 from ..utils import get_logger
+from .metrics import RpcMetrics
 
 log = get_logger("rpc.server")
 
@@ -75,6 +77,9 @@ class _IdemEntry:
 
     def __init__(self, ttl_s: float):
         self.expires = time.monotonic() + ttl_s
+        # this key came before and its entry was forgotten (the
+        # first attempt was shed or failed): a retry, run afresh
+        self.retry = False
         self._event = threading.Event()
         self._result = None
         self._error: Optional[BaseException] = None
@@ -115,6 +120,10 @@ class _IdempotencyCache:
         self._lock = threading.Lock()
         # tenant (LRU) -> key (insertion order) -> _IdemEntry
         self._tenants: "OrderedDict" = OrderedDict()
+        # (tenant, key) of entries forgotten after an error, oldest
+        # first and capped like one tenant's bucket: what lets the
+        # attempt that follows a 503 be counted as the retry it is
+        self._forgotten: "OrderedDict" = OrderedDict()
         self.hits = 0
         self.evictions = 0
 
@@ -157,6 +166,8 @@ class _IdempotencyCache:
                 self.hits += 1
                 return False, entry
             entry = _IdemEntry(self.ttl_s)
+            entry.retry = self._forgotten.pop((tenant, key),
+                                              False)
             bucket[key] = entry
             while len(bucket) > self.per_tenant_cap:
                 bucket.popitem(last=False)
@@ -166,8 +177,11 @@ class _IdempotencyCache:
     def forget(self, key: str, tenant: str = "") -> None:
         with self._lock:
             bucket = self._tenants.get(tenant)
-            if bucket is not None:
-                bucket.pop(key, None)
+            if bucket is not None and \
+                    bucket.pop(key, None) is not None:
+                self._forgotten[(tenant, key)] = True
+                while len(self._forgotten) > self.per_tenant_cap:
+                    self._forgotten.popitem(last=False)
 
     def stats(self) -> dict:
         with self._lock:
@@ -195,13 +209,13 @@ class ScanServer:
                  cache_dir: str = "", token: str = "",
                  token_header: str = DEFAULT_TOKEN_HEADER,
                  sched: str = "off", sched_config=None,
+                 secret_scanner=None,
                  max_body_bytes: int = MAX_BODY_BYTES,
                  max_scan_blobs: int = MAX_SCAN_BLOBS,
                  tracer=None, slos=None, memo=None,
                  admission=None, watch_source=None,
                  federator=None, replica_name: str = "self",
-                 impact=None, compile_cache_dir: str = "",
-                 prewarm_members=None,
+                 impact=None, prewarm_members=None,
                  prewarm_deadline_s: float = 5.0):
         self.max_body_bytes = max_body_bytes
         self.max_scan_blobs = max_scan_blobs
@@ -234,6 +248,9 @@ class ScanServer:
         # fault_injector: trivy_tpu.faults.FaultInjector (or None);
         # the HTTP handler consults it per POST (--fault-spec)
         self.fault_injector = None
+        # the wire's counters and, with them in ``snapshot()``, the
+        # ``rpc`` rows of the phase clock (rpc/metrics.py)
+        self.rpc = RpcMetrics()
         self.scheduler = None
         self._owns_scheduler = False
         if hasattr(sched, "submit"):        # a ScanScheduler
@@ -243,9 +260,18 @@ class ScanServer:
             cfg = sched_config
             if isinstance(sched, SchedConfig):
                 cfg = sched
-            self.scheduler = ScanScheduler(config=cfg,
-                                           tracer=tracer)
+            # secret_scanner: blob-only Scans never need one; the
+            # admission webhook's image loads, which share this
+            # scheduler, do (:func:`build_server` hands the device's)
+            self.scheduler = ScanScheduler(
+                config=cfg, secret_scanner=secret_scanner,
+                tracer=tracer)
             self._owns_scheduler = True
+        if self.scheduler is not None:
+            # its stats() carry this server's book under "rpc", so
+            # one snapshot holds the request from the wire to the
+            # wave and back
+            self.scheduler.rpc_metrics = self.rpc
         # tracer (docs/observability.md): Scan RPCs propagate the
         # client's trace_id into per-request span trees, served back
         # at GET /trace/<id>; a shared scheduler's tracer wins so
@@ -307,28 +333,19 @@ class ScanServer:
         # elastic lifecycle (docs/serving.md "Elastic lifecycle"):
         # the hot-digest recency book (exported on GET /handoff so a
         # drain's ring successors prefetch the moving working set),
-        # the boot-time AOT shape precompile against a persistent
-        # compilation cache, and the pre-join memo prewarm that
-        # keeps /healthz in the ``warming`` state until the post-
-        # join key ranges are staged (or the deadline bounds the
-        # walk into a cold join)
+        # the boot-time warm of the interval ladder
+        # (:meth:`warm_ladder`, called by :func:`build_server`), and
+        # the pre-join memo prewarm; /healthz says ``warming`` while
+        # either runs (the prewarm until the post-join key ranges
+        # are staged, or the deadline bounds the walk into a cold
+        # join)
         from ..memo.warmth import HotSet
         self.hot = HotSet()
-        self._warming = False
+        self._prewarming = False
+        self._ladder_warming = False
         self.compile_cache: dict = {}
-        from ..runtime.aot import warm_ladders
-        from ..runtime.device import on_accelerator
-        if compile_cache_dir or (self._owns_scheduler
-                                 and on_accelerator()):
-            # thin clients sieve on their own hosts, so the interval
-            # ladder against the resident table is all there is
-            self.compile_cache = warm_ladders(
-                store=self.store,
-                config=self.scheduler.config
-                if self.scheduler is not None else None,
-                cache_dir=compile_cache_dir)
         if prewarm_members and self.memo is not None:
-            self._warming = True
+            self._prewarming = True
             threading.Thread(
                 target=self._prewarm,
                 args=(list(prewarm_members),
@@ -375,7 +392,33 @@ class ScanServer:
         finally:
             # ready is unconditional: prewarm buys warmth, it never
             # gates liveness past its deadline
-            self._warming = False
+            self._prewarming = False
+
+    @property
+    def _warming(self) -> bool:
+        return self._prewarming or self._ladder_warming
+
+    def warm_ladder(self, cache_dir: str = "") -> dict:
+        """Run the resident interval kernel at every rung a batch of
+        this server's scheduler can take (``runtime/aot.
+        warm_ladders``), so that no Scan compiles: thin clients
+        sieve on their own hosts, so the interval ladder against
+        the resident table is all there is. ``cache_dir`` places the
+        persistent compile cache (``--compile-cache``; empty: where
+        ``configure_compile_cache`` puts it). ``health()`` says
+        ``warming`` until it returns. A store that is no compiled
+        table has no program to warm."""
+        from ..runtime.aot import warm_ladders
+        self._ladder_warming = True
+        try:
+            self.compile_cache = warm_ladders(
+                store=self.store,
+                config=self.scheduler.config
+                if self.scheduler is not None else None,
+                cache_dir=cache_dir)
+        finally:
+            self._ladder_warming = False
+        return self.compile_cache
 
     def build_info(self) -> dict:
         """The trivy_tpu_build_info identity labels (also mirrored
@@ -467,8 +510,12 @@ class ScanServer:
         return {}
 
     def missing_blobs(self, body: dict) -> dict:
+        blob_ids = body.get("blob_ids") or []
         missing_artifact, missing = self.cache.missing_blobs(
-            body.get("artifact_id", ""), body.get("blob_ids") or [])
+            body.get("artifact_id", ""), blob_ids)
+        # the served layer cache's outcome: a thin client analyzes,
+        # so this is where a layer is a hit or a miss for the server
+        self.rpc.note_missing(len(blob_ids), len(missing))
         return {"missing_artifact": missing_artifact,
                 "missing_blob_ids": list(missing)}
 
@@ -504,6 +551,8 @@ class ScanServer:
         if not key:
             return self._scan(body)
         fresh, entry = self._idem.claim(key, tenant)
+        if not fresh or entry.retry:
+            self.rpc.inc("retried")
         if not fresh:
             return entry.outcome(timeout=self._idem.ttl_s)
         try:
@@ -624,12 +673,18 @@ class ScanServer:
             trace_id=extract_context(body).trace_id[:64],
             parent_span_id=extract_context(body)
             .parent_span_id[:64])
-        try:
-            self.scheduler.submit(req)
-        except BaseException:
-            self.store.release()
-            raise
-        return req.result()
+        # the handler thread's own wall from admission to the
+        # request's resolution: queue, analysis, batch and finish
+        # all pass on other threads while this one is parked here
+        with phase_span("scan_wait", pipeline="rpc"):
+            try:
+                self.scheduler.submit(req)
+            except BaseException as e:
+                self.store.release()
+                if isinstance(e, QueueFullError):
+                    self.rpc.inc("shed_503")
+                raise
+            return req.result()
 
     def metrics(self) -> dict:
         """The /metrics payload: scheduler state when serving is on,
@@ -638,6 +693,10 @@ class ScanServer:
             else self.scheduler.stats()
         out["draining"] = self._draining
         out["idempotency"] = self._idem.stats()
+        if "rpc" not in out:
+            # a scheduler this server rides carries the book in its
+            # stats(); a sched-off server reports it here
+            out["rpc"] = self.rpc.snapshot()
         from ..obs.procstats import process_self_stats
         out["process"] = process_self_stats()
         if "dispatch" not in out:
@@ -840,11 +899,85 @@ class ScanServer:
         SCANNER_PREFIX + "Scan": scan,
     }
 
-    def handle(self, path: str, body: dict) -> dict:
+    # the Cache service's methods that have a row on the phase clock
+    # (``rpc.<phase>``); Scan's row is ``scan_wait``, booked where the
+    # handler thread parks (:meth:`_scan_scheduled`)
+    PHASES = {"MissingBlobs": "missing_blobs", "PutBlob": "put_blob",
+              "PutArtifact": "put_artifact"}
+
+    def handle(self, path: str, body: dict,
+               bytes_in: int = 0) -> dict:
         fn = self.ROUTES.get(path)
         if fn is None:
             raise LookupError(path)
-        return fn(self, body)
+        method = path.rsplit("/", 1)[-1]
+        self.rpc.note_request(method, bytes_in)
+        phase = self.PHASES.get(method)
+        if phase is None:
+            return fn(self, body)
+        with phase_span(phase, pipeline="rpc"):
+            return fn(self, body)
+
+
+def build_server(store=None, *, sched="on", slos=None,
+                 cache=None, cache_dir: str = "", memo=True,
+                 memo_uri: str = "", fault_injector=None,
+                 impact_index: bool = False,
+                 compile_cache_dir: str = "",
+                 replica_name: str = "self",
+                 **server_kwargs) -> ScanServer:
+    """What ``trivy-tpu server`` serves, built in one place: the
+    scheduler with its secret scanner, the findings memo, the blob
+    cache, the :class:`ScanServer` over them and the warm of the
+    interval ladder, so that the command, an embedder and the
+    benchmark's served cell start the same server and none of them
+    compiles under its first Scans.
+
+    ``sched`` is :class:`ScanServer`'s: ``"on"`` or a
+    ``SchedConfig`` has it build a scheduler it then owns
+    (``close()`` closes it), here with the device's secret scanner;
+    a ``ScanScheduler`` is served over as it is handed (``watch
+    --listen``'s; its owner closes it); ``"off"`` keeps the direct
+    path. ``memo``: ``True`` makes the CLI's default memo, persisted
+    under ``cache_dir`` or where ``memo_uri`` says (``--memo-cache``);
+    a ``FindingsMemo`` is taken as it is; ``False`` or ``None`` runs
+    without. ``cache`` or else ``cache_dir`` place the blob cache as
+    :class:`ScanServer` does (no directory: in memory).
+    ``impact_index`` needs the memo (``ValueError`` without).
+    ``slos`` configure the engine of a scheduler the server owns,
+    or of a sched-off server. What is left of ``server_kwargs`` is
+    :class:`ScanServer`'s own (token, prewarm members, federator)."""
+    secret_scanner = None
+    if sched not in (None, "off", False) \
+            and not hasattr(sched, "submit"):
+        from ..secret.batch import BatchSecretScanner
+        secret_scanner = BatchSecretScanner(backend="tpu")
+    if memo is True:
+        from ..memo import make_findings_memo
+        memo = make_findings_memo(
+            cache=cache, cache_dir=cache_dir, uri=memo_uri,
+            fault_injector=fault_injector, backend="tpu")
+    elif memo is False:
+        memo = None
+    impact = None
+    if impact_index:
+        if memo is None:
+            raise ValueError("the impact index needs the findings "
+                             "memo")
+        from ..impact import ImpactIndex
+        impact = ImpactIndex(store=memo.store, name=replica_name)
+        # a restarted or rescheduled replica recovers its slice from
+        # the shared memo tier before taking queries
+        # (docs/serving.md "Elastic lifecycle")
+        impact.rebuild(memo, store)
+    server = ScanServer(
+        store=store, cache=cache, cache_dir=cache_dir, sched=sched,
+        secret_scanner=secret_scanner, slos=slos, memo=memo,
+        impact=impact, replica_name=replica_name, **server_kwargs)
+    server.fault_injector = fault_injector
+    if server.scheduler is not None or compile_cache_dir:
+        server.warm_ladder(compile_cache_dir)
+    return server
 
 
 class DBWorker(threading.Thread):
@@ -904,12 +1037,14 @@ def _make_handler(server: ScanServer):
             log.debug("http: " + fmt, *args)
 
         def _reply(self, code: int, payload: dict,
-                   headers=None) -> None:
-            self._reply_text(code, json.dumps(payload),
-                             "application/json", headers=headers)
+                   headers=None) -> int:
+            return self._reply_text(code, json.dumps(payload),
+                                    "application/json",
+                                    headers=headers)
 
         def _reply_text(self, code: int, text: str,
-                        ctype: str, headers=None) -> None:
+                        ctype: str, headers=None) -> int:
+            """Returns the body's bytes."""
             data = text.encode()
             self.send_response(code)
             self.send_header("Content-Type", ctype)
@@ -918,6 +1053,7 @@ def _make_handler(server: ScanServer):
                 self.send_header(k, v)
             self.end_headers()
             self.wfile.write(data)
+            return len(data)
 
         def _authorized(self) -> bool:
             if not server.token:
@@ -1091,9 +1227,13 @@ def _make_handler(server: ScanServer):
                            f"exceeds {server.max_body_bytes}"})
                 self.close_connection = True
                 return
-            raw = self.rfile.read(length) if length else b"{}"
             try:
-                body = json.loads(raw or b"{}")
+                # the body off the socket and out of JSON, on this
+                # connection's handler thread
+                with phase_span("decode", pipeline="rpc"):
+                    raw = self.rfile.read(length) if length \
+                        else b"{}"
+                    body = json.loads(raw or b"{}")
             except ValueError:
                 if self.path.split("?", 1)[0] == \
                         "/registry/notifications" and \
@@ -1148,7 +1288,7 @@ def _make_handler(server: ScanServer):
                 return
             from ..sched import DeadlineExceeded, SchedulerClosed
             try:
-                out = server.handle(self.path, body)
+                out = server.handle(self.path, body, bytes_in=length)
             except LookupError:
                 self._reply(404, {"code": "bad_route",
                                   "msg": self.path})
@@ -1207,7 +1347,9 @@ def _make_handler(server: ScanServer):
                 # case Scan idempotency keys exist for
                 self.close_connection = True
                 return
-            self._reply(200, out)
+            # the response into JSON and onto the socket
+            with phase_span("encode", pipeline="rpc"):
+                server.rpc.inc("bytes_out", self._reply(200, out))
 
         def _handle_admission(self, body: dict) -> None:
             """POST /k8s/admission: AdmissionReview in, review out.
@@ -1257,6 +1399,14 @@ def _make_handler(server: ScanServer):
     return Handler
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    # connections the kernel holds for the accept loop. A client
+    # opens one a call and CI runners push in bursts: at the
+    # library's 5 a burst finds the queue full, and its connects
+    # are reset or wait a second for the kernel to try again
+    request_queue_size = 128
+
+
 def serve(addr: str = "127.0.0.1", port: int = 4954,
           server: Optional[ScanServer] = None,
           db_watch_prefix: str = "",
@@ -1264,7 +1414,7 @@ def serve(addr: str = "127.0.0.1", port: int = 4954,
     """Start the HTTP server on a background thread. Returns
     (httpd, worker|None); call ``httpd.shutdown()`` to stop."""
     server = server or ScanServer()
-    httpd = ThreadingHTTPServer((addr, port), _make_handler(server))
+    httpd = _HTTPServer((addr, port), _make_handler(server))
     thread = threading.Thread(target=httpd.serve_forever,
                               daemon=True)
     thread.start()

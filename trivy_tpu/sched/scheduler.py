@@ -98,6 +98,10 @@ class ScanScheduler:
         # consulted at the top of every device dispatch so injected
         # device failures exercise the bisect/quarantine machinery
         self.fault_injector = None
+        # rpc_metrics: the ``rpc.metrics.RpcMetrics`` of a ScanServer
+        # that rides this scheduler (it sets this), or None;
+        # ``stats()["rpc"]`` carries its snapshot
+        self.rpc_metrics = None
         # tracer: trivy_tpu.obs.Tracer — every admitted request gets
         # a root span with per-stage children (docs/observability.md)
         self.tracer = tracer if tracer is not None else get_tracer()
@@ -365,6 +369,11 @@ class ScanScheduler:
         # per-tenant cost books + the goodput reconciliation
         # (docs/observability.md "Cost attribution & goodput")
         out["cost"] = self.cost_snapshot()
+        if self.rpc_metrics is not None:
+            # a ScanServer rides this scheduler: its wire counters
+            # and the ``rpc`` rows of the phase clock
+            # (rpc/metrics.py), as "ingest" holds the walkers'
+            out["rpc"] = self.rpc_metrics.snapshot()
         return out
 
     def cost_snapshot(self) -> dict:
