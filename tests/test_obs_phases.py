@@ -238,7 +238,8 @@ def test_phase_table_has_rows_on_both_paths(tmp_path, path):
         for phase in phases:
             row = rows.get(pipeline, {}).get(phase)
             assert row and row["n"] > 0, (pipeline, phase, rows)
-            assert row["busy_s"] >= row["cpu_s"] >= 0.0, row
+            # a difference of two running sums: equal to an ulp
+            assert row["busy_s"] + 1e-12 >= row["cpu_s"] >= 0.0, row
             assert row["busy_s"] > 0.0, (pipeline, phase, row)
 
 

@@ -126,6 +126,38 @@ def test_job_bucket_ladder():
     assert _job_bucket(20000) == 24576
 
 
+# the pair-row ladder and its warm-up, pinned (PR 38): a finer
+# segment ladder, when one comes, is to leave these as they are
+JOB_BUCKETS = [(1, 64), (63, 64), (64, 64), (65, 128), (128, 128),
+               (129, 256), (1000, 1024), (4096, 4096), (4097, 8192),
+               (8191, 8192), (8192, 8192), (8193, 16384),
+               (9000, 16384), (10240, 16384), (12288, 16384),
+               (16384, 16384), (16385, 24576), (20000, 24576),
+               (24576, 24576), (24577, 32768), (40000, 40960),
+               (100000, 106496)]
+
+
+@pytest.mark.parametrize("n,rung", JOB_BUCKETS)
+def test_job_bucket_keeps_its_coarse_steps(n, rung):
+    """The interval kernel costs a fraction of a millisecond a rung:
+    powers of two from 64 to 8,192, multiples of 8,192 above."""
+    from trivy_tpu.detect.batch import _job_bucket
+    assert _job_bucket(n) == rung
+
+
+@pytest.mark.parametrize("jobs,rungs", [
+    (32768, (64, 128, 256, 512, 1024, 2048, 4096)),
+    (100000, (64, 128, 256, 512, 1024, 2048, 4096)),
+    (1000, (64, 128, 256, 512, 1024)),
+    (64, (64,)),
+])
+def test_interval_rungs_are_what_they_were(jobs, rungs):
+    from trivy_tpu.runtime.aot import interval_rungs
+    from trivy_tpu.sched import SchedConfig
+    assert SchedConfig().max_batch_jobs == 32768
+    assert interval_rungs(jobs) == rungs
+
+
 def _naive_segment(scanner, files):
     """The pre-bulk packer, kept as the reference implementation."""
     seg_file, seg_pos, chunks = [], [], []
@@ -335,6 +367,36 @@ def test_mesh_segment_layout_matches_shape_bucket(mesh8):
         rows = [r for r in range(lay["B"]) if seg_file[r] == idx]
         assert rows == list(range(rows[0], rows[0] + len(rows)))
         assert rows[0] // rps == rows[-1] // rps
+
+
+@pytest.mark.parametrize("shards", [2, 4, 8])
+@pytest.mark.parametrize("total,rung", [
+    (4132, 8192), (5121, 8192), (8192, 8192), (8193, 12288),
+    (12289, 16384)])
+def test_mesh_layout_over_the_cap(shards, total, rung):
+    """Every rung above the cap is a multiple of it: a power-of-two
+    shard count carves it into whole 64-row blocks, and the padded
+    total is the one-device rung (an image of 4,132 rows pads to
+    8,192 on a mesh as on one device)."""
+    from trivy_tpu.ops.keywords import _bucket
+    from trivy_tpu.parallel.mesh import make_mesh
+    from trivy_tpu.secret.batch import BatchSecretScanner, _FileEntry
+    s = BatchSecretScanner(backend="cpu-ref", mesh=make_mesh(shards))
+    step = s.seg_len - s.overlap
+    # files of four rows and one that brings the total to the row
+    sizes = [s.seg_len + 3 * step] * (total // 4)
+    if total % 4:
+        sizes.append(s.seg_len + (total % 4 - 1) * step)
+    entries = [_FileEntry(path=f"f{i}", content=bytes(n), index=i)
+               for i, n in enumerate(sizes)]
+    metas = s._metas(entries)
+    assert sum(m[2] for m in metas) == total
+    lay = s._layout(metas)
+    assert _bucket(total) == rung
+    assert lay["n_shards"] == shards
+    assert lay["B"] == rung == shards * lay["rows_per_shard"]
+    assert lay["rows_per_shard"] % 64 == 0
+    assert sum(1 for f in lay["seg_file"] if f >= 0) == total
 
 
 def test_detect_metrics_on_metrics_surface():

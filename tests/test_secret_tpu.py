@@ -469,3 +469,122 @@ def test_pack_buffer_is_allocated_padded():
     assert handle["padded"].shape[0] == _bucket(B)
     assert np.shares_memory(handle["buf"], handle["padded"])
     assert handle["buf"].shape[0] == B
+
+
+# --- the segment ladder (ops/keywords._bucket) ---
+
+SEGMENT_RUNGS = [(1, 256), (256, 256), (257, 512), (2049, 4096),
+                 (4096, 4096), (4097, 8192), (4132, 8192),
+                 (5120, 8192), (5121, 8192), (6144, 8192),
+                 (6145, 8192), (7168, 8192), (7169, 8192),
+                 (8192, 8192), (8193, 12288), (12288, 12288),
+                 (12289, 16384), (40000, 40960)]
+
+
+@pytest.mark.parametrize("rows,rung", SEGMENT_RUNGS)
+def test_segment_ladder_rungs(rows, rung):
+    from trivy_tpu.ops.keywords import _bucket
+    assert _bucket(rows) == rung
+
+
+@pytest.mark.parametrize("lo,hi", [(1, 4096), (4097, 8192),
+                                   (8193, 40000)])
+def test_segment_ladder_properties(lo, hi):
+    """Over every row count: the rung holds the rows, is a rung
+    itself, never falls as rows rise; up to the cap it is a power of
+    two under twice the rows, above it a multiple of the cap (whole
+    64-row blocks for up to 16 shards, whole Pallas tiles), so an
+    image just over the cap pads to twice its rows (PERF.md section
+    6, PR 38: what that costs the device, and why it is not yet the
+    wall)."""
+    from trivy_tpu.ops.dfa_pallas import TILE_B
+    from trivy_tpu.ops.keywords import PART_ROWS, SIEVE_CAP, _bucket
+    cap = SIEVE_CAP
+    assert PART_ROWS == 2 * cap
+    last = _bucket(lo - 1) if lo > 1 else 0
+    for n in range(lo, hi + 1):
+        b = _bucket(n)
+        assert b >= n and _bucket(b) == b and b >= last
+        assert b % TILE_B == 0
+        if n <= cap:
+            assert b & (b - 1) == 0 and (b < 2 * n or b == 256)
+        else:
+            assert b % cap == 0 and b - n < cap
+        last = b
+
+
+def test_image_of_4132_rows_books_its_rows_and_its_rung(cpu):
+    """The fleet cell's image: 4,132 segment rows in a few hundred
+    files. It goes out at the 8,192 rung, finds what the exact
+    engine finds, and books its real and its padded rows."""
+    from trivy_tpu.secret.metrics import SECRET_METRICS
+    s = BatchSecretScanner()
+    step = s.seg_len - s.overlap
+    rng = np.random.default_rng(38)
+    sizes = [s.seg_len + 7 * step] * 500 + [900] * 132
+    planted = dict(zip(rng.choice(len(sizes), len(SAMPLES),
+                                  replace=False).tolist(),
+                       SAMPLES.values()))
+    files = []
+    for i, n in enumerate(sizes):
+        body = bytearray(rng.integers(97, 123, n).astype(np.uint8)
+                         .tobytes())
+        body[::61] = b"\n" * len(body[::61])
+        if i in planted:
+            # written over the text, across a segment's end where
+            # the file has one: the row count stays what it is
+            at = s.seg_len - 12 if n > s.seg_len else 100
+            body[at:at + len(planted[i]) + 1] = b"\n" + planted[i]
+        files.append((f"layer/f{i}.txt", bytes(body)))
+    rows = sum(s._n_segs(len(c)) for _, c in files)
+    assert rows == 4132
+    before = SECRET_METRICS.snapshot()
+    handle = s.dispatch_files(files)
+    assert handle["padded_rows"] == 8192
+    assert handle["cm"].shape[0] == 4096      # the compacted fetch
+    got = _norm(sec for _, sec in s.collect(handle))
+    after = SECRET_METRICS.snapshot()
+    want = _norm(sec for sec in (cpu.scan(p, c) for p, c in files)
+                 if sec.findings)
+    assert got == want and len(got) >= len(SAMPLES) - 1
+    assert after["sieve_rows"] - before["sieve_rows"] == 4132
+    assert after["sieve_rows_padded"] \
+        - before["sieve_rows_padded"] == 8192
+    # the exact engine books neither, and its decode (the host
+    # kernel's masks and run hits) chooses the same rules and spans
+    ref = BatchSecretScanner(backend="cpu-ref")
+    ref_handle = ref.dispatch_files(files)
+    again = s.dispatch_files(files)
+    assert s._decode(again) == ref._decode(ref_handle) != {}
+    assert again["chain_gated"] == ref_handle["chain_gated"] > 0
+    ref.collect(ref_handle)
+    assert SECRET_METRICS.snapshot()["sieve_rows"] \
+        == after["sieve_rows"]
+
+
+def test_rules_gated_in_is_the_plans_gate_test():
+    """The index from a hit column to the rules it gates answers
+    what asking every rule answers, a rule no keyword gates (a
+    custom one) among them, in the plan's order."""
+    from trivy_tpu.secret.model import Rule
+    import re
+    base = new_scanner()
+    free = Rule(id="no-keywords", category="custom", severity="LOW",
+                title="t", regex=re.compile(r"zz[0-9]{30}"),
+                keywords=[])
+    from trivy_tpu.secret.scanner import Scanner
+    s = BatchSecretScanner(
+        scanner=Scanner(list(base.rules) + [free], base.allow_rules,
+                        base.exclude_block),
+        backend="cpu-ref")
+    rules = s.plan.rules
+    assert any(not rp.gate for rp in rules) \
+        and sum(1 for rp in rules if rp.gate) >= 83
+    rng = random.Random(38)
+    cols = list(range(s.table.n_patterns))
+    hits = [set(), set(cols)] + [{c} for c in cols] + \
+        [set(rng.sample(cols, rng.randrange(1, 9)))
+         for _ in range(300)]
+    for hit in hits:
+        assert s._rules_gated_in(hit) == \
+            [rp for rp in rules if not rp.gate or hit & rp.gate]

@@ -358,6 +358,58 @@ def test_parts_are_cut_to_rungs_the_ladder_warms(seed):
         - before["tree_oversize_files"] == want
 
 
+@pytest.mark.parametrize("budget,rungs", [
+    (4 << 20, (256, 512, 1024, 2048, 4096, 8192)),
+    (5 << 20, (256, 512, 1024, 2048, 4096, 8192)),
+    (2 << 20, (256, 512, 1024, 2048, 4096)),
+    (256 << 10, (256, 512)),
+    (16 << 20, (256, 512, 1024, 2048, 4096, 8192, 12288, 16384,
+                20480)),
+])
+def test_sieve_rungs_reach_the_rung_over_twice_the_budget(budget,
+                                                          rungs):
+    """The warm-up ends at the rung over twice the byte budget in
+    rows: a tree's ``PART_ROWS`` at the default budget, less with a
+    smaller one (which leaves a tree's full parts to compile when
+    they come)."""
+    from trivy_tpu.ops.keywords import PART_ROWS
+    from trivy_tpu.runtime.aot import sieve_rungs
+    from trivy_tpu.sched import SchedConfig
+    assert SchedConfig().max_batch_bytes == 4 << 20
+    got = sieve_rungs(budget, 2048, 48)
+    assert got == rungs
+    if budget == 4 << 20:
+        assert got[-1] == PART_ROWS
+
+
+def test_every_rung_over_the_cap_is_warmed_twice(monkeypatch):
+    """``precompile_dfa_shapes`` runs the fused sieve at every rung
+    and the full-fetch sieve at every rung above ``SIEVE_CAP`` (the
+    programs' calls are spied on, not made: compiles of the CPU's
+    sieve are not the point)."""
+    from trivy_tpu.ops.keywords import SIEVE_CAP
+    from trivy_tpu.runtime import aot
+    from trivy_tpu.secret.batch import BatchSecretScanner
+    calls = []
+
+    def warm_call(fn, args, key, manifest, meta):
+        assert args[0].shape == (meta["B"], 2048)
+        calls.append((meta["kernel"], meta["B"]))
+        return 0.0
+
+    monkeypatch.setattr(aot, "_warm_call", warm_call)
+    scanner = BatchSecretScanner(backend="tpu")
+    rungs = aot.sieve_rungs(16 << 20, scanner.seg_len,
+                            scanner.overlap)
+    out = aot.precompile_dfa_shapes(scanner, rungs)
+    over = [b for b in rungs if b > SIEVE_CAP]
+    assert over == [8192, 12288, 16384, 20480]
+    assert out["shapes"] == list(rungs)
+    assert out["full_shapes"] == over
+    assert calls == [("dfa_fused", b) for b in rungs] \
+        + [("dfa_full", b) for b in over]
+
+
 def test_shapes_dispatched_are_the_parts_rung(tmp_path, monkeypatch):
     """On the jitted path every part but the oversize file's goes
     out at the rung the parts are cut to."""

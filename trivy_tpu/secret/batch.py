@@ -101,6 +101,22 @@ def rules_fingerprint(scanner=None) -> str:
     return fp
 
 
+def _runs_by_file(hits: np.ndarray, seg_file: list) -> dict:
+    """file index → set of run-spec indices from ``hits`` [rows,
+    specs] bool; rows of no file (``seg_file`` -1, a shard's
+    padding) are dropped. One numpy call, then plain integers: on
+    the drain thread every further call into numpy gives the
+    interpreter away for a switch interval (PERF.md section 6,
+    PR 38), and a loop over numpy's scalars costs three times one
+    over a list's."""
+    rows, specs = np.nonzero(hits)
+    out: dict = {}
+    for si, sp in zip(rows.tolist(), specs.tolist()):
+        if seg_file[si] >= 0:
+            out.setdefault(seg_file[si], set()).add(sp)
+    return out
+
+
 class PartCutter:
     """Cuts a stream of candidate files, whole, into sieve batches
     of at most ``PART_ROWS`` segment rows (``ops.keywords``: the
@@ -179,6 +195,14 @@ class BatchSecretScanner:
         self.seg_len = max(seg_len, 4 * self.overlap, 128)
         self.seg_len = ((self.seg_len + 127) // 128) * 128
         self.plan.validate_overlap(self.overlap)
+        # table column -> the plan's rules (by position) it gates, and
+        # the rules no keyword gates (``_rules_gated_in``)
+        self._gated_by: dict = {}
+        for ri, rp in enumerate(self.plan.rules):
+            for col in rp.gate:
+                self._gated_by.setdefault(col, []).append(ri)
+        self._gateless = [ri for ri, rp in enumerate(self.plan.rules)
+                          if not rp.gate]
         self.stats: dict = {}
 
     # --- segmenting ---
@@ -420,6 +444,11 @@ class BatchSecretScanner:
         self.stats = {
             "files_total": len(entries),
             "bytes_total": sum(len(fe.content) for fe in entries),
+            # real segment rows, and the rows of the rung a fused
+            # dispatch uploaded (the sharded path pads a shard at a
+            # time and says nothing here)
+            "sieve_rows": len(handle.get("seg_file", ())),
+            "sieve_rows_padded": handle.get("padded_rows", 0),
             "files_gated": len(candidates),
             "rules_verified": rules_verified,
             "rules_windowed": windowed,
@@ -613,15 +642,10 @@ class BatchSecretScanner:
 
         def file_runs(fidx) -> set:
             if not runs_ready[0]:
-                if run_fetch is not None:
-                    for si, sp in zip(*np.nonzero(run_fetch)):
-                        if seg_file[int(si)] < 0:
-                            continue      # shard-padding row
-                        runs_cache.setdefault(
-                            seg_file[int(si)], set()).add(int(sp))
-                else:
-                    runs_cache.update(
-                        self._file_runs(buf, seg_file))
+                runs_cache.update(
+                    _runs_by_file(run_fetch, seg_file)
+                    if run_fetch is not None
+                    else self._file_runs(buf, seg_file))
                 runs_ready[0] = True
             return runs_cache.get(fidx, set())
 
@@ -688,9 +712,7 @@ class BatchSecretScanner:
             fe = by_index[fidx]
             hit = set(codes)
             chosen = dict(out.get(fidx, ()))
-            for rp in self.plan.rules:
-                if rp.gate and not (hit & rp.gate):
-                    continue
+            for rp in self._rules_gated_in(hit):
                 if rp.chain is not None and rp.chain not in hit:
                     if rp.gate:
                         chain_gated += 1
@@ -715,6 +737,17 @@ class BatchSecretScanner:
         handle["chain_gated"] = chain_gated
         return out
 
+    def _rules_gated_in(self, hit: set) -> list:
+        """The plan's rules whose keyword gate a file's hit columns
+        pass, and those nothing gates, in the plan's order: read off
+        the few columns a file hits, where a loop over the plan
+        asked each of 83 rules about each of 384 files a batch."""
+        cand = set(self._gateless)
+        for col in hit:
+            cand.update(self._gated_by.get(col, ()))
+        rules = self.plan.rules
+        return [rules[ri] for ri in sorted(cand)]
+
     def _file_runs(self, buf: np.ndarray, seg_file: list) -> dict:
         """file index → set of run-spec indices present somewhere in
         the file, on the host kernel's path (the fused dispatch
@@ -725,13 +758,7 @@ class BatchSecretScanner:
         if not specs:
             return {}
         from ..ops.runs import run_hits_host
-        hits = run_hits_host(buf, specs)
-        out: dict = {}
-        for si, sp in zip(*np.nonzero(hits)):
-            if seg_file[int(si)] < 0:
-                continue                  # shard-padding row
-            out.setdefault(seg_file[int(si)], set()).add(int(sp))
-        return out
+        return _runs_by_file(run_hits_host(buf, specs), seg_file)
 
     def _windows(self, fe: _FileEntry, rp, anchor_hits: list,
                  blk: int) -> list:

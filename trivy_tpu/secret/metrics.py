@@ -31,6 +31,10 @@ class SecretMetrics:
         # file bytes whose sieve ran on the device (fused or
         # sharded dispatch) — cpu-ref batches add nothing here
         "device_bytes",
+        # a fused dispatch's real segment rows, and the rows of the
+        # buffer it uploaded (its ``_bucket`` rung): what the pad
+        # ladder costs the device, which sieves every row
+        "sieve_rows", "sieve_rows_padded",
         # wall-time accumulators (seconds, float)
         "sieve_s", "verify_s",
         # DFA table residency (ops/dfa.py DfaTable hooks)
@@ -78,6 +82,10 @@ class SecretMetrics:
             c["verify_bytes"] += stats.get("verify_bytes", 0)
             if stats.get("mode") in ("fused", "sharded"):
                 c["device_bytes"] += stats.get("bytes_total", 0)
+            if stats.get("mode") == "fused":
+                c["sieve_rows"] += stats.get("sieve_rows", 0)
+                c["sieve_rows_padded"] += stats.get(
+                    "sieve_rows_padded", 0)
             c["sieve_s"] += stats.get("sieve_s", 0.0)
             c["verify_s"] += stats.get("verify_s", 0.0)
 
