@@ -15,6 +15,7 @@ cap, and keep pipelined fetch/decompress spans out of the idle
 attribution's serialized causes.
 """
 
+import builtins
 import dataclasses
 import io
 import json
@@ -27,16 +28,18 @@ import numpy as np
 import pytest
 
 from tests.test_sched import _norm, make_fleet, make_store
+from trivy_tpu.artifact import stream
 from trivy_tpu.artifact.artifact import ArtifactOption
 from trivy_tpu.artifact.localreg import LocalRegistry
 from trivy_tpu.artifact.registry import DistributionClient
 from trivy_tpu.artifact.stream import (INGEST_METRICS,
-                                       clear_config_memo)
+                                       clear_config_memo, stream_image)
 from trivy_tpu.faults import FaultInjector, parse_fault_spec
 from trivy_tpu.faults.hostile import EXPECTED_STATUS
 from trivy_tpu.guard import (ResourceBudget, ResourceBudgetExceeded,
                              ResourceLimits)
-from trivy_tpu.guard.budget import LayerBudget
+from trivy_tpu.guard.budget import (GuardMetrics, LayerBudget,
+                                    MalformedArchiveError)
 from trivy_tpu.obs.prom import render_prometheus
 from trivy_tpu.obs.timeline import CAUSE_SPANS, CAUSES, Timeline
 from trivy_tpu.runtime import BatchScanRunner
@@ -159,7 +162,9 @@ class TestStreamParity:
         _reg, refs, _paths = fleet_registry
         pulled = _scan_refs(refs, streaming=False)
         assert all(r.status == "ok" for r in pulled)
-        assert INGEST_METRICS.snapshot()["streams"] == 0
+        m = INGEST_METRICS.snapshot()
+        assert m["streams"] == 0 and m["spool_bytes_read"] == 0
+        assert m["layers_analyzed"] > 0
 
 
 # ---------------------------------------------------------------
@@ -310,6 +315,111 @@ class TestHostileStreaming:
         assert INGEST_METRICS.snapshot()["cancelled_fetches"] >= 1
         # well under the blob's size: the body was cut, not drained
         assert snap["bytes_served"] < len(big) // 2, snap
+
+
+# ---------------------------------------------------------------
+# a pulled layer is read from its spool whole and walked in memory
+# ---------------------------------------------------------------
+
+@pytest.fixture
+def pulled_source(tmp_path):
+    """One image of the fleet, served gzipped as a registry serves it:
+    → (registry, open_source). ``open_source(budget)`` opens it as a
+    streaming source; the test's sources are closed and the registry
+    stopped on the way out."""
+    paths = make_fleet(tmp_path, 1)
+    reg = LocalRegistry(gzip_level=6)
+    reg.add_image("fleet/img", "0", paths[0])
+    reg.start()
+    opened = []
+
+    def open_source(budget=None):
+        src = stream_image(DistributionClient(),
+                           reg.ref("fleet/img", "0"), budget=budget)
+        opened.append(src)
+        return src
+
+    yield reg, open_source
+    for src in opened:
+        src.close()
+    reg.stop()
+
+
+def _walk(tf):
+    """Every member's name, type and bytes, in the archive's order."""
+    return [(m.name, m.type,
+             tf.extractfile(m).read() if m.isreg() else None)
+            for m in tf.getmembers()]
+
+
+class TestSpoolReadWhole:
+    def test_layer_walks_memory_as_the_spool_file(self, pulled_source):
+        _reg, open_source = pulled_source
+        src = open_source()
+        for layer, st in zip(src.layers, src._fetches):
+            tf = layer.open()
+            assert isinstance(tf.fileobj, io.BytesIO)
+            with tarfile.open(st.spool) as from_file:
+                want = _walk(from_file)
+            assert want and _walk(tf) == want
+
+    def test_spool_opened_and_read_once_a_layer(self, pulled_source,
+                                                monkeypatch):
+        _reg, open_source = pulled_source
+        src = open_source()
+        reads = []
+
+        def spy(path, mode="r", *args, **kw):
+            f = builtins.open(path, mode, *args, **kw)
+            if "r" in mode:
+                reads.append(path)
+                real = f.read
+                f.read = lambda *a: reads.append(a) or real(*a)
+            return f
+
+        monkeypatch.setattr(stream, "open", spy, raising=False)
+        for layer in src.layers:
+            layer.open()
+        spools = [st.spool for st in src._fetches]
+        # per layer: its spool opened, then one read() of it all
+        assert reads == [x for p in spools for x in (p, ())]
+        m = INGEST_METRICS.snapshot()
+        assert m["spool_bytes_read"] == m["bytes_inflated"] == \
+            sum(os.path.getsize(p) for p in spools) > 0
+
+    def test_open_after_close_refetches(self, pulled_source):
+        reg, open_source = pulled_source
+        src = open_source()
+        first = [_walk(layer.open()) for layer in src.layers]
+        gets = reg.snapshot()["blob_gets"]
+        src.close()
+        assert not os.path.exists(src._spool_dir)
+        again = [_walk(layer.open()) for layer in src.layers]
+        assert again == first
+        assert reg.snapshot()["blob_gets"] == gets + len(src.layers)
+        src.close()
+        assert not os.path.exists(src._spool_dir)
+
+    @pytest.mark.parametrize("guarded", [True, False])
+    @pytest.mark.parametrize("spool", [b"", b"not a tar\n" * 200],
+                             ids=["empty", "text"])
+    def test_non_tar_spool_is_malformed(self, pulled_source, spool,
+                                        guarded):
+        _reg, open_source = pulled_source
+        guard = GuardMetrics()
+        budget = ResourceBudget(metrics=guard) if guarded else None
+        src = open_source(budget)
+        st = src._fetches[0]
+        st.done.wait(60)
+        assert st.error is None
+        with open(st.spool, "wb") as f:
+            f.write(spool)
+        with pytest.raises(MalformedArchiveError,
+                           match="unreadable layer tar"):
+            src.layers[0].open()
+        assert guard.snapshot()["malformed_archives"] == int(guarded)
+        assert INGEST_METRICS.snapshot()["spool_bytes_read"] == \
+            len(spool)
 
 
 # ---------------------------------------------------------------

@@ -27,9 +27,10 @@ class IngestMetrics:
         "full_restarts", "warm_probe_outages",
         "cancelled_fetches", "config_memo_hits",
         # every HTTP request DistributionClient sends (manifest,
-        # config, blob, token, each retry) and the bytes its layer
-        # inflaters wrote to their spools
-        "registry_requests", "bytes_inflated",
+        # config, blob, token, each retry), the bytes its layer
+        # inflaters wrote to their spools, and the bytes read back
+        # whole from them as their layers were opened
+        "registry_requests", "bytes_inflated", "spool_bytes_read",
         # ImageArtifact.inspect: layers an image asked the cache
         # for, those it held, and those walked and analyzed instead
         # (seen = cached + analyzed)

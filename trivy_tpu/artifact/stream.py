@@ -10,7 +10,11 @@ incremental instead of whole-image:
   resumable fetch engine (``registry.fetch_blob``) straight into a
   bounded chunk-wise gzip inflater (the same 64 KiB / budget-charge
   contract as ``guard/safetar.decompress_bounded``, extended to the
-  push side), spooling the decompressed tar to disk. Layers download
+  push side), spooling the decompressed tar to disk chunk by chunk.
+  The spool is read back whole, in one call, when its layer is
+  opened, and the walk runs over those bytes in memory, as it does
+  for a docker-save or OCI layer, so that no system call sits between
+  a tar header and the next on the analyze thread. Layers download
   and inflate concurrently on a dedicated fetch pool while earlier
   layers are already being analyzed and dispatched. The pool is
   sized for network parallelism (``TRIVY_TPU_FETCH_CONCURRENCY``,
@@ -308,10 +312,15 @@ class StreamingImageSource:
     from manifest+config alone — enough for ``ImageArtifact`` to
     compute cache keys and for the warm probe — while each
     ``LayerRef.open()`` blocks only until *that* layer's spool is
-    ready. ``close()`` deletes the spools, and a fetch still running
-    then stops at its next chunk and deletes what it wrote; an open
-    after close refetches on demand (the same re-open-after-close
-    contract the shared ``_Archive`` handle documents).
+    ready. The spool is written chunk by chunk as the blob inflates
+    and read back whole when its layer is opened: the ``TarFile``
+    handed to the walk lies over a ``BytesIO``, not over the file
+    (``spool_bytes_read`` counts those reads; on a cold pull it equals
+    ``bytes_inflated``). ``close()`` deletes the spools, and a fetch
+    still running then stops at its next chunk and deletes what it
+    wrote; an open after close refetches on demand (the same
+    re-open-after-close contract the shared ``_Archive`` handle
+    documents).
 
     Three rows of the phase clock (``stats()["ingest"]["phase"]``)
     are booked once a layer: ``layer_fetch`` (the fetch worker from
@@ -526,7 +535,8 @@ class StreamingImageSource:
             if st.error is not None:
                 raise st.error
             try:
-                return tarfile.open(st.spool)
+                with open(st.spool, "rb") as f:
+                    data = f.read()
             except FileNotFoundError:
                 if attempt:
                     raise
@@ -535,6 +545,12 @@ class StreamingImageSource:
                     st.started = False
                     st.done.clear()
                     st.error = None
+                continue
+            # the inflater charged every byte of the spool as it was
+            # written, so the layer budget already bounds this read
+            INGEST_METRICS.inc("spool_bytes_read", len(data))
+            try:
+                return tarfile.open(fileobj=io.BytesIO(data))
             except _ARCHIVE_ERRORS as e:
                 if self.ingest_budget is not None:
                     self.ingest_budget.malformed(

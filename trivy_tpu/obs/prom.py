@@ -420,6 +420,9 @@ def render_prometheus(stats: dict, phase_hists=None,
                 ("bytes_inflated",
                  "Layer bytes inflated to spools by the streaming "
                  "path."),
+                ("spool_bytes_read",
+                 "Spool bytes read back whole as their layers were "
+                 "opened."),
                 ("layers_seen",
                  "Layers images asked the blob cache for."),
                 ("layers_cached", "Layers the blob cache held."),
