@@ -19,6 +19,7 @@ import random
 import tarfile
 import threading
 import time
+import tracemalloc
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pytest
@@ -34,6 +35,7 @@ from trivy_tpu.guard import (GUARD_METRICS, IngestDeadlineExceeded,
                              ResourceBudgetExceeded, ResourceLimits,
                              decompress_bounded, make_budget,
                              open_layer_bytes)
+from trivy_tpu.guard.safetar import LayerTar
 from trivy_tpu.runtime import BatchScanRunner
 from trivy_tpu.types import ScanOptions
 
@@ -240,8 +242,7 @@ def _walk(names, budget=None, sizes=None):
             ti = tarfile.TarInfo(name)
             ti.size = (sizes or {}).get(name, 0)
             tf.addfile(ti, io.BytesIO(b"x" * ti.size))
-    buf.seek(0)
-    with tarfile.open(fileobj=buf) as tf:
+    with LayerTar(buf.getvalue()) as tf:
         return collect_layer_tar(tf, budget=budget)
 
 
@@ -292,6 +293,36 @@ class TestWalkerPaths:
                            match="entry count"):
             _walk([f"f{i}" for i in range(200)],
                   budget=ResourceBudget(lim))
+
+    def test_header_flood_is_parsed_no_further_than_the_budget(self):
+        # twenty times max_files empty members in one layer: the
+        # index reads one member past the entries the budget has
+        # left and hands the layer to tarfile, which trips where it
+        # always tripped; the walk's peak stays a fraction of the
+        # layer, where one parse of every header would cost more
+        # than the layer itself
+        import trivy_tpu.obs.trace  # noqa: F401 — the trip imports it
+        lim = ResourceLimits(max_files=500)
+        buf = io.BytesIO()
+        with tarfile.open(fileobj=buf, mode="w") as tf:
+            tf.addfile(tarfile.TarInfo("f"))
+        flood = buf.getvalue()[:512] * (20 * lim.max_files) + \
+            bytes(1024)
+        budget = ResourceBudget(lim)
+        tracemalloc.start()
+        try:
+            with pytest.raises(ResourceBudgetExceeded,
+                               match="entry count"):
+                with open_layer_bytes(flood, budget) as tf:
+                    try:
+                        collect_layer_tar(tf, budget=budget)
+                    finally:
+                        assert not tf.indexed
+            _, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+        assert budget.entries <= lim.max_files + 32
+        assert peak < len(flood) // 4
 
     def test_oversize_member_trips(self):
         lim = ResourceLimits(max_file_bytes=10)

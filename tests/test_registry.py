@@ -18,6 +18,7 @@ from trivy_tpu.artifact.registry import (MT_MANIFEST,
                                          MT_MANIFEST_LIST,
                                          DistributionClient,
                                          RegistryError, parse_ref)
+from trivy_tpu.artifact.walker import collect_layer_tar
 
 
 def _layer_tar(files: dict) -> bytes:
@@ -173,7 +174,8 @@ def _scan_src(src):
     names = []
     for layer in src.layers:
         with layer.open() as tf:
-            names.extend(tf.getnames())
+            files, _opq, _wh = collect_layer_tar(tf)
+            names.extend(path for path, _size, _read in files)
     return names
 
 

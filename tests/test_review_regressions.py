@@ -15,6 +15,7 @@ def test_deb_missing_revision_equals_zero():
 
 def test_tar_walker_keeps_dotfiles_and_whiteouts():
     from trivy_tpu.artifact.walker import collect_layer_tar
+    from trivy_tpu.guard.safetar import LayerTar
     buf = io.BytesIO()
     with tarfile.open(fileobj=buf, mode="w") as tf:
         for name, data in [("./.env", b"secret"),
@@ -24,9 +25,9 @@ def test_tar_walker_keeps_dotfiles_and_whiteouts():
             ti = tarfile.TarInfo(name)
             ti.size = len(data)
             tf.addfile(ti, io.BytesIO(data))
-    buf.seek(0)
-    with tarfile.open(fileobj=buf) as tf:
+    with LayerTar(buf.getvalue()) as tf:
         files, opq, wh = collect_layer_tar(tf)
+        assert tf.indexed
     paths = [p for p, _, _ in files]
     assert ".env" in paths           # dotfile survives with its dot
     assert "abs/file" in paths

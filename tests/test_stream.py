@@ -34,6 +34,7 @@ from trivy_tpu.artifact.localreg import LocalRegistry
 from trivy_tpu.artifact.registry import DistributionClient
 from trivy_tpu.artifact.stream import (INGEST_METRICS,
                                        clear_config_memo, stream_image)
+from trivy_tpu.artifact.walker import collect_layer_tar
 from trivy_tpu.faults import FaultInjector, parse_fault_spec
 from trivy_tpu.faults.hostile import EXPECTED_STATUS
 from trivy_tpu.guard import (ResourceBudget, ResourceBudgetExceeded,
@@ -346,10 +347,11 @@ def pulled_source(tmp_path):
 
 
 def _walk(tf):
-    """Every member's name, type and bytes, in the archive's order."""
-    return [(m.name, m.type,
-             tf.extractfile(m).read() if m.isreg() else None)
-            for m in tf.getmembers()]
+    """Every file the walk keeps: its path, size and bytes, in the
+    archive's order."""
+    with tf:
+        files, _opq, _wh = collect_layer_tar(tf)
+        return [(path, size, read()) for path, size, read in files]
 
 
 class TestSpoolReadWhole:
@@ -358,10 +360,12 @@ class TestSpoolReadWhole:
         src = open_source()
         for layer, st in zip(src.layers, src._fetches):
             tf = layer.open()
-            assert isinstance(tf.fileobj, io.BytesIO)
+            assert isinstance(tf.data, bytes)
             with tarfile.open(st.spool) as from_file:
-                want = _walk(from_file)
+                want = [(m.name, m.size, from_file.extractfile(m).read())
+                        for m in from_file if m.isreg()]
             assert want and _walk(tf) == want
+            assert tf.indexed
 
     def test_spool_opened_and_read_once_a_layer(self, pulled_source,
                                                 monkeypatch):

@@ -168,6 +168,7 @@ class ImageArtifact:
         img = self.image
         artifact_id, blob_ids, base = self.cache_keys()
         self._bytes_analyzed = 0
+        self._layers_indexed = 0
 
         try:
             missing_artifact, missing = self.cache.missing_blobs(
@@ -196,7 +197,8 @@ class ImageArtifact:
             # benchmark's snapshots read it (artifact/metrics.py)
             INGEST_METRICS.note_inspect(
                 len(blob_ids), len(todo), self._bytes_analyzed,
-                len(base), self.group.take_gate_counts())
+                len(base), self.group.take_gate_counts(),
+                self._layers_indexed)
             if missing_artifact and \
                     getattr(self, "_os_found", None) is None:
                 # OS layer may be a cache hit while the artifact
@@ -297,6 +299,9 @@ class ImageArtifact:
                         self._bytes_analyzed += size
                         self.group.analyze_file(result, path, read,
                                                 size)
+                # walked from index_tar's records, not by tarfile
+                if tf.indexed:
+                    self._layers_indexed += 1
             add_event("layer_analyzed", layer=i,
                       files=len(files))
             layer_results.append((i, result, opq_dirs, wh_files))

@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import gzip
 import hashlib
-import io
 import json
 import os
 import tarfile
@@ -37,13 +36,13 @@ from typing import Callable, Optional
 
 from ..guard.budget import (MalformedArchiveError, ResourceBudget,
                             ResourceBudgetExceeded)
-from ..guard.safetar import open_layer_bytes
+from ..guard.safetar import LayerTar, open_layer_bytes
 
 
 @dataclass
 class LayerRef:
     diff_id: str                    # sha256 of the UNCOMPRESSED tar
-    open: Callable                  # () -> tarfile.TarFile
+    open: Callable                  # () -> guard.safetar.LayerTar
 
 
 @dataclass
@@ -320,19 +319,19 @@ def _canon_json(obj) -> bytes:
 
 
 def _member_layer_opener(arch: _Archive, member: str) -> Callable:
-    def open_layer() -> tarfile.TarFile:
+    def open_layer() -> LayerTar:
         data = arch.read(member)
         if arch.budget is not None:
             return open_layer_bytes(data, arch.budget)
         if data[:2] == b"\x1f\x8b":
             data = gzip.decompress(data)
-        return tarfile.open(fileobj=io.BytesIO(data))
+        return LayerTar(data)
     return open_layer
 
 
 def _open_layer_file(full: str,
                      budget: Optional[ResourceBudget] = None)\
-        -> tarfile.TarFile:
+        -> LayerTar:
     if budget is not None:
         budget.check_deadline()
         size = os.path.getsize(full)
@@ -346,7 +345,7 @@ def _open_layer_file(full: str,
         return open_layer_bytes(data, budget)
     if data[:2] == b"\x1f\x8b":
         data = gzip.decompress(data)
-    return tarfile.open(fileobj=io.BytesIO(data))
+    return LayerTar(data)
 
 
 def guess_base_layers(diff_ids: list, config: dict) -> list:

@@ -33,8 +33,11 @@ class IngestMetrics:
         "registry_requests", "bytes_inflated", "spool_bytes_read",
         # ImageArtifact.inspect: layers an image asked the cache
         # for, those it held, and those walked and analyzed instead
-        # (seen = cached + analyzed)
+        # (seen = cached + analyzed), and of those analyzed the
+        # layers walked from index_tar's one pass over their bytes
+        # (the rest fell back to tarfile: guard/safetar.LayerTar)
         "layers_seen", "layers_cached", "layers_analyzed",
+        "layers_indexed",
         # file bytes the walker handed the analyzers in those layers
         "bytes_analyzed",
         # layers whose secrets the base-image rule leaves out of the
@@ -65,7 +68,7 @@ class IngestMetrics:
 
     def note_inspect(self, layers: int, analyzed: int,
                      nbytes: int, base: int,
-                     gates: tuple = (0, 0)) -> None:
+                     gates: tuple = (0, 0), indexed: int = 0) -> None:
         with self._lock:
             c = self.counters
             c["gate_files"] += gates[0]
@@ -73,6 +76,7 @@ class IngestMetrics:
             c["layers_seen"] += layers
             c["layers_cached"] += layers - analyzed
             c["layers_analyzed"] += analyzed
+            c["layers_indexed"] += indexed
             c["bytes_analyzed"] += nbytes
             c["base_layers_skipped"] += base
 

@@ -53,7 +53,6 @@ import io
 import json
 import os
 import shutil
-import tarfile
 import tempfile
 import threading
 import zlib
@@ -62,7 +61,7 @@ from typing import Callable, Optional
 from ..guard.budget import (GuardError, LayerBudget,
                             MalformedArchiveError, ResourceBudget,
                             ResourceBudgetExceeded)
-from ..guard.safetar import _ARCHIVE_ERRORS, GZIP_MAGIC
+from ..guard.safetar import GZIP_MAGIC, LayerTar, open_layer_tar
 from ..obs.trace import (PhaseTally, activate_or_null, current_span,
                          phase_span)
 from ..utils import get_logger
@@ -313,8 +312,8 @@ class StreamingImageSource:
     compute cache keys and for the warm probe — while each
     ``LayerRef.open()`` blocks only until *that* layer's spool is
     ready. The spool is written chunk by chunk as the blob inflates
-    and read back whole when its layer is opened: the ``TarFile``
-    handed to the walk lies over a ``BytesIO``, not over the file
+    and read back whole when its layer is opened: the walk indexes
+    those bytes (a ``guard.safetar.LayerTar``), not the file
     (``spool_bytes_read`` counts those reads; on a cold pull it equals
     ``bytes_inflated``). ``close()`` deletes the spools, and a fetch
     still running then stops at its next chunk and deletes what it
@@ -512,11 +511,11 @@ class StreamingImageSource:
     # --- open ---
 
     def _make_opener(self, st: _LayerFetch) -> Callable:
-        def open_layer() -> tarfile.TarFile:
+        def open_layer() -> LayerTar:
             return self._open_layer(st)
         return open_layer
 
-    def _open_layer(self, st: _LayerFetch) -> tarfile.TarFile:
+    def _open_layer(self, st: _LayerFetch) -> LayerTar:
         for attempt in (0, 1):
             start = False
             with self._lock:
@@ -549,14 +548,7 @@ class StreamingImageSource:
             # the inflater charged every byte of the spool as it was
             # written, so the layer budget already bounds this read
             INGEST_METRICS.inc("spool_bytes_read", len(data))
-            try:
-                return tarfile.open(fileobj=io.BytesIO(data))
-            except _ARCHIVE_ERRORS as e:
-                if self.ingest_budget is not None:
-                    self.ingest_budget.malformed(
-                        f"unreadable layer tar: {e}")
-                raise MalformedArchiveError(
-                    f"unreadable layer tar: {e}") from e
+            return open_layer_tar(data, self.ingest_budget)
         raise AssertionError("unreachable")
 
 

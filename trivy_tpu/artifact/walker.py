@@ -4,6 +4,18 @@ Tar walker semantics (tar.go:33-125): iterate entries, collect
 whiteout files (``.wh.<name>``) and opaque dirs (``.wh..wh..opq``),
 skip non-regular files; paths are cleaned, no leading slash.
 
+How a layer is walked: the layer openers hold a layer's bytes whole
+and return them as a ``guard.safetar.LayerTar``; ``collect_layer_tar``
+takes its members from ``index_tar``, one pass over those bytes, and
+slices a regular file's bytes out of them. A layer with a header the
+index does not take (a bad checksum, a base-256 number, a sparse
+member, a global or unknown header type, a header or member past the
+end, no tar at all) is walked by ``tarfile`` as before, whole; the
+bytes decide, a layer at a time, and the rules below are the same
+either way. So does a layer with more members than the budget has
+entries left: the index stops one past them, and ``tarfile`` trips on
+the entry count where it always did.
+
 Hostile-input posture (docs/robustness.md): entry names whose
 normpath still contains ``..`` segments are never kept — without a
 budget they are skipped (and counted), with a budget the archive is
@@ -20,8 +32,10 @@ import tarfile
 from functools import partial
 from typing import Callable, Optional
 
-from ..guard.budget import GUARD_METRICS, ResourceBudget
-from ..guard.safetar import has_traversal, link_escapes, read_member
+from ..guard.budget import (GUARD_METRICS, MalformedArchiveError,
+                            ResourceBudget)
+from ..guard.safetar import (LayerTar, has_traversal, link_escapes,
+                             read_member)
 
 WH_PREFIX = ".wh."
 OPQ = ".wh..wh..opq"
@@ -29,12 +43,36 @@ OPQ = ".wh..wh..opq"
 SKIP_SYSTEM_DIRS = ["proc", "sys", "dev"]
 
 
-def collect_layer_tar(tf: tarfile.TarFile,
-                      budget: Optional[ResourceBudget] = None) \
-        -> tuple:
+def collect_layer_tar(tf: LayerTar,
+                      budget: Optional[ResourceBudget] = None) -> tuple:
     """Eagerly walk a layer tar: ([(path, size, read_fn)], opq_dirs,
-    wh_files)."""
-    from ..guard.budget import MalformedArchiveError
+    wh_files).
+
+    ``tf`` is the ``LayerTar`` a layer opener returns. Its members
+    come from ``index_tar``, one pass over the bytes the opener holds,
+    and a regular file's ``read_fn`` slices them; no ``TarInfo`` or
+    file object is built. Where a header is one the index does not
+    take (a bad checksum, a base-256 number, a sparse member, a global
+    or unknown header type, a header or member that runs past the
+    end, bytes that are no tar), or the layer has more members than
+    the budget's entries left, the whole layer is walked by
+    ``tarfile`` as before (``LayerTar.indexed`` stays False), so that
+    a hostile layer trips what it tripped, where it tripped it. The
+    rules below run on (name, type, size, linkname) either way, and
+    the budget is charged one entry a member."""
+    recs = tf.index(budget.remaining_entries()
+                    if budget is not None else None)
+    if recs is not None:
+        data = tf.data
+
+        def read_fn(off, size):
+            return partial(data.__getitem__, slice(off, off + size))
+    else:
+        tar = tf.tar
+        recs = _tar_members(tar, budget)
+
+        def read_fn(member, size):
+            return _tar_reader(tar, member, budget)
     files = []
     opq_dirs: list = []
     wh_files: list = []
@@ -48,22 +86,7 @@ def collect_layer_tar(tf: tarfile.TarFile,
     # count("/") only runs on names long enough to matter
     depth_gate = 2 * lim.max_depth if lim is not None else 0
     seen = 0
-    members = iter(tf)
-    while True:
-        try:
-            member = next(members)
-        except StopIteration:
-            break
-        except tarfile.TarError as e:
-            # truncated/corrupt layer surfacing mid-iteration: a
-            # typed malformed-archive trip, never a raw tarfile
-            # error past the artifact boundary
-            if budget is not None:
-                budget.malformed(
-                    f"truncated or corrupt layer tar: {e}")
-            raise MalformedArchiveError(
-                f"truncated or corrupt layer tar: {e}") from e
-        nm = member.name
+    for nm, typ, size, linkname, ref in recs:
         # strip the leading "./" / "/" PREFIX only — lstrip would eat
         # the dot of dotfiles (./.env → env) and break .wh. detection
         path = posixpath.normpath(nm)
@@ -117,18 +140,16 @@ def collect_layer_tar(tf: tarfile.TarFile,
                 continue
             wh_files.append(target)
             continue
-        if member.isreg():
+        if typ in tarfile.REGULAR_TYPES:
             if _skip_system(path):
                 continue
-            size = member.size
             if budget is not None and \
                     (size < 0 or size > max_file):
                 budget.check_file_size(size, path)
-            files.append((path, size,
-                          _tar_reader(tf, member, budget)))
+            files.append((path, size, read_fn(ref, size)))
             continue
-        if member.issym() or member.islnk():
-            if link_escapes(member):
+        if typ == tarfile.SYMTYPE or typ == tarfile.LNKTYPE:
+            if link_escapes(nm, linkname, typ == tarfile.LNKTYPE):
                 # never followed (only regular files are read), but
                 # worth surfacing: count, and report the slot
                 # degraded when a budget is watching
@@ -137,10 +158,34 @@ def collect_layer_tar(tf: tarfile.TarFile,
                     budget.note(
                         "malformed-archive",
                         f"link member {path!r} escapes the "
-                        f"archive root ({member.linkname!r})")
+                        f"archive root ({linkname!r})")
     if budget is not None:
         budget.charge_entries(seen & 31)
     return files, opq_dirs, wh_files
+
+
+def _tar_members(tf: tarfile.TarFile,
+                 budget: Optional[ResourceBudget] = None):
+    """``tarfile``'s members as ``index_tar`` gives them, (name,
+    type, size, linkname, TarInfo), one at a time: a layer that
+    breaks mid-iteration trips where it breaks."""
+    members = iter(tf)
+    while True:
+        try:
+            member = next(members)
+        except StopIteration:
+            return
+        except tarfile.TarError as e:
+            # truncated/corrupt layer surfacing mid-iteration: a
+            # typed malformed-archive trip, never a raw tarfile
+            # error past the artifact boundary
+            if budget is not None:
+                budget.malformed(
+                    f"truncated or corrupt layer tar: {e}")
+            raise MalformedArchiveError(
+                f"truncated or corrupt layer tar: {e}") from e
+        yield (member.name, member.type, member.size,
+               member.linkname, member)
 
 
 def _tar_reader(tf: tarfile.TarFile, member,

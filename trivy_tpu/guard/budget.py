@@ -251,6 +251,9 @@ class ResourceBudget:
         return max(0, self.limits.max_decompressed_bytes -
                    self.decompressed)
 
+    def remaining_entries(self) -> int:
+        return max(0, self.limits.max_files - self.entries)
+
     def charge_decompressed(self, n: int,
                             compressed_total: int = 0) -> None:
         """Charge ``n`` freshly produced bytes; ``compressed_total``
@@ -367,6 +370,10 @@ class LayerBudget(ResourceBudget):
         except GuardError:
             self._flush_metrics()
             raise
+
+    def remaining_entries(self) -> int:
+        return min(super().remaining_entries(),
+                   self.parent.remaining_entries())
 
     def charge_entries(self, n: int) -> None:
         if n <= 0:

@@ -428,6 +428,9 @@ def render_prometheus(stats: dict, phase_hists=None,
                 ("layers_cached", "Layers the blob cache held."),
                 ("layers_analyzed",
                  "Layers walked and analyzed on a cache miss."),
+                ("layers_indexed",
+                 "Of them, layers walked from the tar index rather "
+                 "than by tarfile."),
                 ("bytes_analyzed",
                  "File bytes handed to the analyzers in analyzed "
                  "layers."),
