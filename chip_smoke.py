@@ -1248,11 +1248,12 @@ def stage_mesh(sz: dict, work: str, n_devices: int = 4) -> dict:
           and set(per_dev.values()) == {rows // n_devices},
           f"sieve rows per device: {per_dev}")
     counts["sieve_rows_per_device"] = per_dev
-    from trivy_tpu.parallel.interval_shard import \
-        sharded_interval_hits_resident_async
+    from trivy_tpu.parallel.interval_shard import (
+        shard_rows, sharded_interval_hits_resident)
     P = 64 * n_devices
-    lazy = sharded_interval_hits_resident_async(
-        mesh, np.zeros(P, np.int32), np.zeros(P, np.int32),
+    lazy = sharded_interval_hits_resident(
+        mesh, *shard_rows(mesh, np.zeros(P, np.int32),
+                          np.zeros(P, np.int32)),
         cdb.device_tables(mesh=mesh))
     per_dev = {int(s.device.id): int(s.data.shape[0])
                for s in lazy.addressable_shards}

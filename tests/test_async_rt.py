@@ -408,7 +408,9 @@ class TestDonationResidency:
         # dispatch freeing them would force a re-upload (or crash)
         assert up1["uploads"] == up0["uploads"]
 
-    def test_resident_generation_survives_async_ring(self):
+    def test_resident_generation_survives_async_ring(self,
+                                                     monkeypatch):
+        import trivy_tpu.detect.batch as db
         from trivy_tpu.db import CompiledDB
         from trivy_tpu.detect.batch import (collect_dispatch,
                                             dispatch_jobs,
@@ -418,12 +420,12 @@ class TestDonationResidency:
         jobs = self._resident_jobs(cdb, n=200)
         base = dispatch_jobs(list(jobs), backend="tpu", stats={})
         up0 = cdb.device_stats()["uploads"]
+        monkeypatch.setattr(db, "_WAVE_ROWS", 64)
         ring = DispatchRing(depth=2, name="t-donate")
         try:
             for _ in range(3):
                 h = dispatch_jobs_async(list(jobs), backend="tpu",
-                                        stats={}, ring=ring,
-                                        max_wave_rows=64)
+                                        stats={}, ring=ring)
                 assert collect_dispatch(h) == base
         finally:
             ring.close()

@@ -10,8 +10,7 @@ import pytest
 
 from trivy_tpu.db import AdvisoryStore, CompiledDB, SwappableStore
 from trivy_tpu.db.fixtures import load_fixtures
-from trivy_tpu.detect.batch import (ResidentPairJob,
-                                    detect_pairs_resident)
+from trivy_tpu.detect.batch import ResidentPairJob, dispatch_jobs
 from trivy_tpu.vercmp import get_comparer
 from trivy_tpu.vercmp.base import is_vulnerable
 
@@ -94,8 +93,7 @@ def test_compiled_matches_host_on_fixtures(fixture_store,
                     jobs = [ResidentPairJob(
                         cdb=cdb, row=rows[0], grammar=grammar,
                         pkg_version=version, payload=1)]
-                    got = bool(detect_pairs_resident(jobs,
-                                                     backend="cpu-ref"))
+                    got = bool(dispatch_jobs(jobs, backend="cpu-ref"))
                     assert got == want, (bucket, pkg,
                                          adv.vulnerability_id,
                                          version)
@@ -126,7 +124,7 @@ def test_fuzz_resident_vs_host():
         jobs = [ResidentPairJob(cdb=cdb, row=r, grammar="semver",
                                 pkg_version=ver, payload=r)
                 for r in rows]
-        got = sorted(detect_pairs_resident(jobs, backend="cpu-ref"))
+        got = sorted(dispatch_jobs(jobs, backend="cpu-ref"))
         want = sorted(
             r for r in rows
             if is_vulnerable(comparer, ver,
@@ -146,7 +144,7 @@ def test_save_load_roundtrip(fixture_cdb, tmp_path):
     assert (loaded.flags == fixture_cdb.flags).all()
     # a detection through the loaded store matches
     jobs = _jobs_for(loaded, "pip::", "werkzeug", "0.11", "pep440")
-    got = detect_pairs_resident(jobs, backend="cpu-ref")
+    got = dispatch_jobs(jobs, backend="cpu-ref")
     assert len(got) == 2
 
 
@@ -176,7 +174,7 @@ def test_scale_100k_advisories_dispatch_is_o_packages():
         ver = f"{rng.randrange(9)}.{rng.randrange(20)}.0"
         jobs.extend(_jobs_for(cdb, "npm::", pkg, ver, "npm"))
     t0 = time.monotonic()
-    hits = detect_pairs_resident(jobs, backend="cpu-ref")
+    hits = dispatch_jobs(jobs, backend="cpu-ref")
     dispatch_s = time.monotonic() - t0
     assert jobs and hits is not None
     # O(packages) check: a full-universe rebuild costs ~compile_s per

@@ -6,7 +6,7 @@ import pytest
 
 from trivy_tpu.db import AdvisoryStore, load_fixtures
 from trivy_tpu.detect import new_library_driver, ospkg_detect
-from trivy_tpu.detect.batch import PairJob, detect_pairs
+from trivy_tpu.detect.batch import PairJob, dispatch_jobs
 from trivy_tpu.detect.enrich import fill_info
 from trivy_tpu.types import Package
 
@@ -196,9 +196,9 @@ class TestBatchKernel:
             if want:
                 expect.append(i)
 
-        got = sorted(detect_pairs(jobs, backend="cpu-ref"))
+        got = sorted(dispatch_jobs(jobs, backend="cpu-ref"))
         assert got == expect
-        got_tpu = sorted(detect_pairs(jobs))
+        got_tpu = sorted(dispatch_jobs(jobs))
         assert got_tpu == expect
         assert expect, "differential corpus must have positives"
 
@@ -221,14 +221,14 @@ class TestBatchKernel:
                     affected_version="1.5-r0", kind="ospkg",
                     payload="too-old"),
         ]
-        got = set(detect_pairs(jobs, backend="cpu-ref"))
+        got = set(dispatch_jobs(jobs, backend="cpu-ref"))
         assert got == {"hit", "unfixed"}
 
     def test_empty_string_forces(self):
         jobs = [PairJob(grammar="semver", pkg_version="9.9.9",
                         vulnerable=[""], patched=[], unaffected=[],
                         payload="forced")]
-        assert detect_pairs(jobs, backend="cpu-ref") == ["forced"]
+        assert dispatch_jobs(jobs, backend="cpu-ref") == ["forced"]
 
 
 class TestRedHatContentSets:

@@ -72,15 +72,15 @@ def _naive_truth(jobs) -> list:
     """Per-job host evaluation, no dedup, no batching — the oracle
     every device count must match."""
     from trivy_tpu.detect.batch import (PairJob, _host_eval,
-                                        detect_pairs)
+                                        dispatch_jobs)
     out = []
     for job in jobs:
         if isinstance(job, PairJob):
             if job.kind == "ospkg":
                 # single-job cpu-ref dispatch IS the reference ospkg
                 # evaluation (affected/fixed gate semantics)
-                if detect_pairs([job], backend="cpu-ref",
-                                stats={}):
+                if dispatch_jobs([job], backend="cpu-ref",
+                                 stats={}):
                     out.append(job.payload)
             elif _host_eval(job):
                 out.append(job.payload)

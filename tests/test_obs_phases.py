@@ -469,20 +469,48 @@ def test_stats_keys_are_their_spans(tmp_path):
         assert scanner.stats[key] == pytest.approx(
             busy("secret", *phases) - s0[key], abs=1e-4), key
 
-    # the cost ledger: a sieved dispatch bills the interval ladder
-    # its device_compute spans, the sieve the rest of the wall
+    # the cost ledger: a sieved dispatch bills the interval waves
+    # their h2d_upload + device_compute spans, the sieve the rest of
+    # the wall
     COST_LEDGER.reset()
     was, COST_LEDGER.enabled = COST_LEDGER.enabled, True
     try:
-        c0 = busy("detect", "device_compute")
+        c0 = busy("detect", "h2d_upload", "device_compute")
         _run_fleet_sched_on(tmp_path)
         billed = COST_LEDGER.snapshot()["totals"]["device_interval_s"]
         assert billed == pytest.approx(
-            busy("detect", "device_compute") - c0, abs=1e-4)
+            busy("detect", "h2d_upload", "device_compute") - c0,
+            abs=1e-4)
         assert billed > 0
     finally:
         COST_LEDGER.enabled = was
         COST_LEDGER.reset()
+
+
+def test_sched_device_s_is_upload_plus_kernel(tmp_path, monkeypatch):
+    """The ``device_s`` each scheduler batch hands the cost ledger
+    is its interval waves' h2d_upload + device_compute spans — the
+    same two phases ``scan_boms`` books as ``interval_device_s``."""
+    from trivy_tpu.obs.trace import phase_rows
+    from trivy_tpu.sched.scheduler import ScanScheduler
+
+    def busy() -> float:
+        rows = phase_rows("detect")
+        return sum(rows.get(p, {}).get("busy_s", 0.0)
+                   for p in ("h2d_upload", "device_compute"))
+
+    handed: list = []
+    real = ScanScheduler._meter_dispatch
+
+    def meter(self, reqs, t0, wall_s, kstats, sieved):
+        handed.append((kstats or {}).get("device_s", 0.0))
+        return real(self, reqs, t0, wall_s, kstats, sieved)
+
+    monkeypatch.setattr(ScanScheduler, "_meter_dispatch", meter)
+    b0 = busy()
+    _run_fleet_sched_on(tmp_path)
+    assert handed and all(d > 0 for d in handed)
+    assert sum(handed) == pytest.approx(busy() - b0, abs=1e-4)
 
 
 def test_phase_table_loses_no_update_under_threads():

@@ -58,6 +58,7 @@ def test_sharded_interval_hits_matches_host():
     from trivy_tpu.ops.intervals import (MAX_INTERVALS, NEG_INF,
                                          POS_INF, interval_hits_host)
     from trivy_tpu.parallel import make_mesh, sharded_interval_hits
+    from trivy_tpu.parallel.interval_shard import shard_rows
 
     rng = np.random.default_rng(7)
     P = 37                      # deliberately not a device multiple
@@ -70,8 +71,10 @@ def test_sharded_interval_hits_matches_host():
     s_hi[::3] = v_hi[::3] + 5
     flags = rng.integers(0, 8, P).astype(np.int32)
     mesh = make_mesh(8)
-    got = sharded_interval_hits(mesh, pkg_rank, v_lo, v_hi, s_lo,
-                                s_hi, flags)
+    lazy = sharded_interval_hits(mesh, *shard_rows(
+        mesh, pkg_rank, v_lo, v_hi, s_lo, s_hi, flags))
+    assert lazy.shape[0] == 40        # padded to a device multiple
+    got = np.asarray(lazy)[:P]
     want = interval_hits_host(pkg_rank, v_lo, v_hi, s_lo, s_hi, flags)
     np.testing.assert_array_equal(got, want)
 

@@ -25,10 +25,10 @@ def _mk_jobs(n_dups: int = 5):
 
 
 def test_dedup_folds_duplicates_and_fans_out():
-    from trivy_tpu.detect.batch import detect_pairs
+    from trivy_tpu.detect.batch import dispatch_jobs
     jobs = _mk_jobs(n_dups=5)
     stats: dict = {}
-    hits = detect_pairs(jobs, backend="cpu-ref", stats=stats)
+    hits = dispatch_jobs(jobs, backend="cpu-ref", stats=stats)
     assert stats["jobs_in"] == 20
     assert stats["jobs_unique"] == 4          # 4 distinct versions
     # versions 1.0.0 and 1.1.0 are < 1.2.0 → every duplicate's
@@ -41,7 +41,7 @@ def test_dedup_matches_naive_host_eval():
     """Seeded random job mix: deduped dispatch == per-job host
     truth, payload multiplicity preserved."""
     from trivy_tpu.detect.batch import (PairJob, _host_eval,
-                                        detect_pairs)
+                                        dispatch_jobs)
     rng = np.random.default_rng(20260804)
     jobs = []
     for k in range(200):
@@ -53,7 +53,7 @@ def test_dedup_matches_naive_host_eval():
             grammar="semver", pkg_version=v,
             vulnerable=[f"<{fixed}"], patched=[f">={fixed}"],
             payload=k))
-    got = sorted(detect_pairs(jobs, backend="cpu-ref", stats={}))
+    got = sorted(dispatch_jobs(jobs, backend="cpu-ref", stats={}))
     want = sorted(k for k, j in enumerate(jobs) if _host_eval(j))
     assert got == want
 
@@ -61,8 +61,7 @@ def test_dedup_matches_naive_host_eval():
 def test_resident_dedup_matches_full_eval(tmp_path):
     from trivy_tpu.db import AdvisoryStore
     from trivy_tpu.db.compiled import CompiledDB
-    from trivy_tpu.detect.batch import (ResidentPairJob,
-                                        detect_pairs_resident)
+    from trivy_tpu.detect.batch import ResidentPairJob, dispatch_jobs
     store = AdvisoryStore()
     for i in range(6):
         store.put_advisory("npm::Node.js", f"lib{i}",
@@ -77,8 +76,7 @@ def test_resident_dedup_matches_full_eval(tmp_path):
                 cdb=cdb, row=row, grammar=cdb.row_grammar[row],
                 pkg_version="1.2.5", payload=(row, rep)))
     stats: dict = {}
-    got = detect_pairs_resident(jobs, backend="cpu-ref",
-                                stats=stats)
+    got = dispatch_jobs(jobs, backend="cpu-ref", stats=stats)
     assert stats["jobs_unique"] == len(cdb.rows_meta)
     assert stats["jobs_in"] == 7 * len(cdb.rows_meta)
     # truth: 1.2.5 < 1.i.0 only for i in {3, 4, 5}
@@ -92,11 +90,10 @@ def test_resident_dedup_matches_full_eval(tmp_path):
 def test_resident_mixed_stores_evaluate_per_store():
     """A job list spanning two CompiledDBs must evaluate each job
     against ITS OWN store — row N means different advisories per
-    generation (dispatch_jobs pre-groups; direct callers may not)."""
+    generation: the dispatcher runs one segment per store."""
     from trivy_tpu.db import AdvisoryStore
     from trivy_tpu.db.compiled import CompiledDB
-    from trivy_tpu.detect.batch import (ResidentPairJob,
-                                        detect_pairs_resident)
+    from trivy_tpu.detect.batch import ResidentPairJob, dispatch_jobs
 
     def mk(fixed: str):
         store = AdvisoryStore()
@@ -111,8 +108,7 @@ def test_resident_mixed_stores_evaluate_per_store():
                             pkg_version="2.0.0", payload=name)
             for db, name in ((a, "a"), (b, "b"))]
     # 2.0.0: patched in a (<1.0.0 misses), vulnerable in b (<9.0.0)
-    assert detect_pairs_resident(jobs, backend="cpu-ref",
-                                 stats={}) == ["b"]
+    assert dispatch_jobs(jobs, backend="cpu-ref", stats={}) == ["b"]
     assert jobs[0].dedup_key() != jobs[1].dedup_key()
 
 

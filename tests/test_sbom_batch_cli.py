@@ -448,8 +448,8 @@ def test_no_new_program_when_rows_cross_a_rungs_edge(
                                   8192, 8193, 57000])
 def test_resident_waves_cover_every_row_once(rows):
     from trivy_tpu.detect.batch import (_WAVE_ROWS, _job_bucket,
-                                        _resident_waves)
-    waves = _resident_waves(rows)
+                                        _wave_plan)
+    waves = _wave_plan(rows)
     assert [a for a, _, _ in waves] == \
         [k * _WAVE_ROWS for k in range(len(waves))]
     assert sum(n for _, n, _ in waves) == rows

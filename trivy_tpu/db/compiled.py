@@ -20,9 +20,9 @@ time into
 
 At scan time, per-dispatch host work is O(packages): parse each
 distinct installed version once, binary-search its rank, gather
-candidate rows via the dict join — then ONE resident-table kernel
-dispatch (ops.intervals.interval_hits_resident) evaluates every
-(package, advisory) pair. The tables are pushed to device once and
+candidate rows via the dict join — then the resident-table kernel
+(ops.intervals.interval_hits_resident_donated) evaluates every
+(package, advisory) pair in waves. The tables are pushed to device once and
 reused across scans; ``SwappableStore`` double-buffers them for hot
 swaps (reference: pkg/rpc/server/listen.go:71-80).
 
@@ -589,7 +589,7 @@ class CompiledDB(ResidentTables):
                         adv.patched_versions or
                         adv.unaffected_versions)
         # the unfixed marker survives host fallback so the driver's
-        # report_unfixed filter still applies (detect_pairs_resident)
+        # report_unfixed filter still applies (detect/batch._prep_resident)
         unfixed = F_UNFIXED if is_ospkg and \
             adv.fixed_version == "" else 0
         if grammar is None:

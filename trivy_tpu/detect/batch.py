@@ -12,38 +12,44 @@ Dispatch shape (docs/performance.md): jobs are DEDUPED before any
 compilation — fleets repeat (version, constraint) pairs massively
 (every SBOM in a batch depends on the same lodash), so the kernel
 evaluates each distinct pair once and the hit fans back out to every
-duplicate's payload. Row tables are packed with bulk fancy-index
-stores into PREALLOCATED buffers padded to a small bucket ladder, so
-XLA's compile cache is keyed by a handful of shapes instead of one
-per arbitrary batch size.
+duplicate's payload.
 
-Two dispatch surfaces share those mechanics:
+One dispatcher, :func:`dispatch_jobs_async` / :func:`collect_dispatch`
+(docs/performance.md §8), with :func:`dispatch_jobs` its blocking
+form. The jobs split into segments — the classic rows, and the
+resident rows of each compiled store — and every segment runs one
+path:
 
-* :func:`dispatch_jobs` — the synchronous ladder (pack → upload →
-  compute → collect on the calling thread); cpu-ref, host fallback
-  and the quarantine path stay here.
-* :func:`dispatch_jobs_async` / :func:`collect_dispatch` — the
-  double-buffered slot runtime (docs/performance.md "Async device
-  runtime"): rows split into bounded waves, each wave's payload
-  buffers uploaded fresh and DONATED to the jitted kernel
-  (``interval_hits_donated`` — resident advisory tables are never
-  donated), the kernel enqueued non-blocking, and the blocking
-  materialize pushed to a :class:`runtime.ring.DispatchRing` drain
-  thread so wave N+1 packs while wave N computes. Results are
-  byte-identical to the synchronous ladder at every wave split,
-  dispatch depth, and device count (property-tested).
+* ``pack``: dedup, triage and every wave's host arrays, built before
+  the first launch. The wave plan (:func:`_wave_plan`) sends rows
+  that fit one wave at their ``_job_bucket`` rung and more rows in
+  waves of ``_WAVE_ROWS``, the last padded to the full rung, so XLA's
+  compile cache sees a handful of shapes.
+* each wave's upload (``h2d_upload``) and the non-blocking enqueue of
+  its DONATED program (the resident advisory tables are never
+  donated). With a :class:`runtime.ring.DispatchRing` these run as
+  the ring's launch, so a full ring parks before a wave stages HBM.
+* ``device_compute``: each wave fetched FIFO, on the ring's drain
+  thread or in :func:`collect_dispatch`; the hits and the host
+  verdicts fan back out to every duplicate.
+
+The host engine (``cpu-ref``) is one eager, unpadded evaluation of
+all kept rows, and a mesh runs each wave through
+parallel.interval_shard's sharded programs. Results are
+byte-identical at every wave size, ring depth and device count
+(property-tested).
 """
 
 from __future__ import annotations
 
 from bisect import bisect_left
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Callable, Optional
 
 import numpy as np
 
 from ..ops.intervals import (MAX_INTERVALS, NEG_INF, POS_INF,
-                             interval_hits, interval_hits_host)
+                             interval_hits_host)
 from ..utils import get_logger
 from ..vercmp import get_comparer
 from ..vercmp.base import Interval
@@ -110,12 +116,6 @@ class _RankSpace:
         return lo, hi
 
 
-# device-kernel wall time of the most recent dispatch_jobs call,
-# for the host/device split in stats + tracing. Callers that
-# dispatch from several threads (the sched device executor) pass
-# their own ``stats`` sink instead of sharing this module global.
-last_dispatch_stats: dict = {"device_s": 0.0, "dispatch_s": 0.0}
-
 
 def _book(sink: dict, span, *keys: str) -> None:
     """Add a finished phase's seconds to a per-call stats sink:
@@ -139,15 +139,15 @@ def _job_bucket(n: int) -> int:
     return _bucket(n, base=64, cap=8192)
 
 
-def _resident_waves(n: int) -> list:
-    """``(first row, rows, padded rows)`` of each wave the
-    synchronous resident dispatch sends ``n`` rows in. Rows that fit
-    one wave go at their ``_job_bucket`` rung, the rungs the
-    scheduler's waves take (``runtime/aot.interval_rungs``); more
-    rows go in waves of ``_WAVE_ROWS``, the last one padded to the
-    full rung too, so a pass of any size above one wave runs ONE
-    program, and a pass of ``scan_boms`` whose distinct rows differ
-    from the last pass's compiles nothing."""
+def _wave_plan(n: int) -> list:
+    """``(first row, rows, padded rows)`` of each wave ``n`` kept
+    rows go in, on one device and on a mesh. Rows that fit one wave
+    go at their ``_job_bucket`` rung, the rungs the scheduler's
+    waves take (``runtime/aot.interval_rungs``); more rows go in
+    waves of ``_WAVE_ROWS``, the last one padded to the full rung
+    too, so a pass of any size above one wave runs ONE program, and
+    a pass of ``scan_boms`` whose distinct rows differ from the last
+    pass's compiles nothing."""
     if n <= _WAVE_ROWS:
         return [(0, n, _job_bucket(n))]
     return [(a, min(_WAVE_ROWS, n - a), _WAVE_ROWS)
@@ -173,11 +173,11 @@ def _dedup(jobs: list, key_fn) -> tuple:
 
 
 def _prep_classic(jobs: list, sink: dict) -> tuple:
-    """Dedup + per-grammar compile shared by the sync and async
-    dispatch paths: ``(reps, members, spaces, rows, host_groups)``
-    where ``rows`` holds the kernel-path representatives in group
-    order. Rank spaces are NOT finalized yet (wave packing must see
-    every interned constraint bound first)."""
+    """Dedup + per-grammar compile of the classic rows:
+    ``(reps, members, spaces, rows, host_groups)`` where ``rows``
+    holds the kernel-path representatives in group order. Rank
+    spaces are NOT finalized yet (wave packing must see every
+    interned constraint bound first)."""
     reps, members = _dedup(jobs, PairJob.dedup_key)
     sink["jobs_in"] = sink.get("jobs_in", 0) + len(jobs)
     sink["jobs_unique"] = sink.get("jobs_unique", 0) + len(reps)
@@ -248,84 +248,6 @@ def _pack_classic(rows: list, spaces: dict, Pp: int) -> tuple:
         s_lo[si, sj] = b[:, 0]
         s_hi[si, sj] = b[:, 1]
     return pkg_rank, v_lo, v_hi, s_lo, s_hi, flags_arr
-
-
-def detect_pairs(jobs: list, backend: str = "tpu",
-                 mesh=None, stats: Optional[dict] = None) -> list:
-    """Returns payloads of vulnerable pairs, batch order preserved.
-    With ``mesh``, pair rows shard over every chip (see
-    parallel.interval_shard)."""
-    if not jobs:
-        return []
-    from ..obs.trace import phase_span
-    sink = stats if stats is not None else last_dispatch_stats
-    reps, members, spaces, rows, host_groups = \
-        _prep_classic(jobs, sink)
-
-    hit_jobs: list = []          # original job indices that hit
-    if rows:
-        with phase_span("pack", pipeline="detect", jobs=len(jobs),
-                        unique=len(reps)) as psp:
-            for sp in spaces.values():
-                sp.finalize()
-            P = len(rows)
-            Pp = P if backend == "cpu-ref" else _job_bucket(P)
-            (pkg_rank, v_lo, v_hi, s_lo, s_hi,
-             flags_arr) = _pack_classic(rows, spaces, Pp)
-        _book(sink, psp, "dispatch_s")
-        # device_compute brackets the kernel execution alone — it is
-        # what the idle-attribution timeline (obs/timeline.py) counts
-        # as the device being busy; the H2D upload keeps its own
-        # disjoint h2d_upload span, so upload
-        # wall attributes as upload_serialized, never as compute
-        if backend == "cpu-ref":
-            with phase_span("device_compute", pipeline="detect",
-                            kind="interval", rows=P) as csp:
-                hits = np.asarray(interval_hits_host(
-                    pkg_rank, v_lo, v_hi, s_lo, s_hi, flags_arr))
-        elif mesh is not None:
-            from ..parallel.interval_shard import \
-                sharded_interval_hits
-            with phase_span("device_compute", pipeline="detect",
-                            kind="interval", rows=P) as csp:
-                hits = sharded_interval_hits(
-                    mesh, pkg_rank, v_lo, v_hi, s_lo, s_hi,
-                    flags_arr)
-        else:
-            import jax
-            from ..ops.intervals import interval_hits_donated
-            arrs = (pkg_rank, v_lo, v_hi, s_lo, s_hi, flags_arr)
-            with phase_span("h2d_upload", pipeline="detect",
-                            bytes=int(sum(a.nbytes for a in arrs))) \
-                    as usp:
-                dev = [jax.device_put(a) for a in arrs]
-            _book(sink, usp, "device_s", "dispatch_s")
-            with phase_span("device_compute", pipeline="detect",
-                            kind="interval", rows=P) as csp:
-                # materialize INSIDE the span: interval_hits is
-                # jitted (async dispatch), so closing the span after
-                # the enqueue would leave the real kernel wall to
-                # dispatch_gap. Every operand is a fresh
-                # per-dispatch upload, so the donated variant lets
-                # the kernel reuse the payload HBM (buffer-donation
-                # audit, docs/performance.md §8)
-                hits = np.asarray(interval_hits_donated(*dev))
-        _book(sink, csp, "device_s", "dispatch_s")
-        if backend != "cpu-ref":
-            DETECT_METRICS.note_wave(P)
-        for i in np.nonzero(hits[:P])[0]:
-            hit_jobs.extend(members[rows[i][0]])
-
-    out = [jobs[i].payload for i in sorted(hit_jobs)]
-
-    # host fallback pairs: exact per-pair evaluation, once per
-    # distinct key — the verdict fans out to every duplicate
-    host_hits: list = []
-    for gi in host_groups:
-        if _host_eval(reps[gi]):
-            host_hits.extend(members[gi])
-    out.extend(jobs[i].payload for i in sorted(host_hits))
-    return out
 
 
 class _HostFallback(Exception):
@@ -418,17 +340,15 @@ class ResidentPairJob:
 
     def dedup_key(self) -> tuple:
         # the DB identity is part of the key: row N of one compiled
-        # generation says nothing about row N of another, and a
-        # caller may hand detect_pairs_resident a mixed list even
-        # though dispatch_jobs groups by store first
+        # generation says nothing about row N of another
         return (getattr(self.cdb, "generation", id(self.cdb)),
                 self.row, self.grammar, self.pkg_version,
                 self.report_unfixed)
 
 
 def _prep_resident(jobs: list, cdb, sink: dict) -> tuple:
-    """Dedup + row triage shared by the sync and async resident
-    paths: ``(reps, members, kept, ranks, rows, host)``."""
+    """Dedup + row triage of one store's resident rows:
+    ``(reps, members, kept, ranks, rows, host)``."""
     from ..db.compiled import F_HOST, F_UNFIXED
     reps, members = _dedup(jobs, ResidentPairJob.dedup_key)
     sink["jobs_in"] = sink.get("jobs_in", 0) + len(jobs)
@@ -458,238 +378,175 @@ def _prep_resident(jobs: list, cdb, sink: dict) -> tuple:
     return reps, members, kept, ranks, rows, host
 
 
-def detect_pairs_resident(jobs: list, backend: str = "tpu",
-                          mesh=None,
-                          stats: Optional[dict] = None) -> list:
-    """Evaluate ResidentPairJobs by gather-dispatch against the
-    resident tables: one gather on the host engine and on a mesh,
-    :func:`_resident_waves` on one device. Host work is O(distinct
-    jobs): duplicates are folded before rank lookup, rank lookups
-    are cached per (grammar, version), and the advisory universe is
-    never touched."""
-    if not jobs:
-        return []
-    from ..obs.trace import phase_span
-    sink = stats if stats is not None else last_dispatch_stats
+# ---- the dispatcher (docs/performance.md §8) ----
 
+
+@dataclass
+class _Rows:
+    """A segment's rows after dedup and triage, and the four things
+    in which the classic and the resident row kinds differ."""
+
+    reps: list                  # one job per distinct dedup key
+    members: list               # original job indices behind each rep
+    kept: list                  # rep indices on the kernel path
+    host: list                  # rep indices the host evaluates
+    pack: Callable              # (first, rows, padded) -> host arrays
+    evaluate: Callable          # host arrays -> hits (the host engine)
+    program: Callable           # mesh -> enqueue(device arrays)
+    host_eval: Callable         # rep -> vulnerable (host fallback)
+
+
+def _classic_rows(jobs: list, sink: dict) -> _Rows:
+    reps, members, spaces, rows, host = _prep_classic(jobs, sink)
+    for sp in spaces.values():
+        sp.finalize()
+
+    def pack(first: int, n: int, padded: int) -> tuple:
+        return _pack_classic(rows[first:first + n], spaces, padded)
+
+    def program(mesh):
+        if mesh is not None:
+            from ..parallel.interval_shard import sharded_interval_hits
+            return lambda dev: sharded_interval_hits(mesh, *dev)
+        # every operand is this wave's own upload → donated
+        from ..ops.intervals import interval_hits_donated
+        return lambda dev: interval_hits_donated(*dev)
+
+    return _Rows(reps, members, [r[0] for r in rows], host, pack,
+                 lambda arrays: interval_hits_host(*arrays), program,
+                 _host_eval)
+
+
+def _resident_rows(jobs: list, sink: dict) -> _Rows:
     cdb = jobs[0].cdb
-    if any(j.cdb is not cdb for j in jobs):
-        # the kernel path below gathers from ONE store's tables;
-        # a mixed list (dispatch_jobs pre-groups, direct callers
-        # may not) evaluates per store
-        by_db: dict = {}
-        for j in jobs:
-            by_db.setdefault(id(j.cdb), []).append(j)
-        out = []
-        for js in by_db.values():
-            out.extend(detect_pairs_resident(
-                js, backend=backend, mesh=mesh, stats=stats))
-        return out
-    hit_jobs: list = []
-    with phase_span("pack", pipeline="detect", jobs=len(jobs)) \
-            as psp:
-        reps, members, kept, ranks, rows, host = \
-            _prep_resident(jobs, cdb, sink)
-        psp.set("unique", len(reps))
-        P = len(kept)
-        # one gather for the host engine and for a mesh (its rows
-        # shard over the chips); on one device, waves of _WAVE_ROWS
-        if not kept:
-            waves = []
-        elif backend == "cpu-ref":
-            waves = [(0, P, P)]
-        elif mesh is not None:
-            waves = [(0, P, _job_bucket(P))]
-        else:
-            waves = _resident_waves(P)
-        packed = []
-        for a, n, padded in waves:
-            pkg_rank = np.zeros(padded, np.int32)
-            row_idx = np.zeros(padded, np.int32)
-            pkg_rank[:n] = ranks[a:a + n]
-            row_idx[:n] = rows[a:a + n]
-            packed.append((pkg_rank, row_idx))
-    _book(sink, psp, "dispatch_s")
+    reps, members, kept, ranks, rows, host = \
+        _prep_resident(jobs, cdb, sink)
 
-    if kept:
-        # device_compute = kernel execution only (obs/timeline.py
-        # busy set); table staging keeps its db_upload span
-        if backend == "cpu-ref":
-            pkg_rank, row_idx = packed[0]
-            with phase_span("device_compute", pipeline="detect",
-                            kind="interval", rows=P) as csp:
-                hits = interval_hits_host(
-                    pkg_rank, cdb.v_lo[row_idx], cdb.v_hi[row_idx],
-                    cdb.s_lo[row_idx], cdb.s_hi[row_idx],
-                    cdb.flags[row_idx])
-        elif mesh is not None:
+    def pack(first: int, n: int, padded: int) -> tuple:
+        pkg_rank = np.zeros(padded, np.int32)
+        row_idx = np.zeros(padded, np.int32)
+        pkg_rank[:n] = ranks[first:first + n]
+        row_idx[:n] = rows[first:first + n]
+        return pkg_rank, row_idx
+
+    def evaluate(arrays: tuple):
+        pkg_rank, row_idx = arrays
+        return interval_hits_host(
+            pkg_rank, cdb.v_lo[row_idx], cdb.v_hi[row_idx],
+            cdb.s_lo[row_idx], cdb.s_hi[row_idx], cdb.flags[row_idx])
+
+    def program(mesh):
+        # staged once per generation (its own db_upload span); the
+        # gather operands are donated, the resident tables are
+        # shared by every dispatch of the generation and never are
+        tables = cdb.device_tables(mesh=mesh)
+        if mesh is not None:
             from ..parallel.interval_shard import \
                 sharded_interval_hits_resident
-            tables = cdb.device_tables(mesh=mesh)
-            with phase_span("device_compute", pipeline="detect",
-                            kind="interval", rows=P) as csp:
-                hits = sharded_interval_hits_resident(
-                    mesh, *packed[0], tables)
-        else:
-            import jax
-            from ..ops.intervals import \
-                interval_hits_resident_donated
-            tables = cdb.device_tables()
-            with phase_span("h2d_upload", pipeline="detect",
-                            bytes=int(sum(a.nbytes + b.nbytes
-                                          for a, b in packed))) as usp:
-                staged = [(jax.device_put(a), jax.device_put(b))
-                          for a, b in packed]
-            _book(sink, usp, "device_s", "dispatch_s")
-            with phase_span("device_compute", pipeline="detect",
-                            kind="interval", rows=P) as csp:
-                # the gather operands are fresh per-wave uploads →
-                # donated; the resident tables are shared across
-                # every dispatch of this generation → never donated.
-                # Every wave is enqueued before the first is
-                # fetched, so the device runs through the fetches
-                lazy = [interval_hits_resident_donated(
-                    dr, di, *tables) for dr, di in staged]
-                hits = np.concatenate(
-                    [np.asarray(h)[:n]
-                     for h, (_, n, _) in zip(lazy, waves)])
-        _book(sink, csp, "device_s", "dispatch_s")
-        if backend != "cpu-ref":
-            for _, n, _ in waves:
-                DETECT_METRICS.note_wave(n)
-        for i in np.nonzero(hits[:P])[0]:
-            hit_jobs.extend(members[kept[i]])
-    out = [jobs[i].payload for i in sorted(hit_jobs)]
+            return lambda dev: sharded_interval_hits_resident(
+                mesh, *dev, tables)
+        from ..ops.intervals import interval_hits_resident_donated
+        return lambda dev: interval_hits_resident_donated(*dev, *tables)
 
-    host_hits: list = []
-    for gi in host:
-        job = reps[gi]
-        # each job's OWN store, not the batch head's — the kernel
-        # path above assumes a homogeneous batch, the host path
-        # need not
-        if job.cdb.host_eval(job.row, job.pkg_version):
-            host_hits.extend(members[gi])
-    out.extend(jobs[i].payload for i in sorted(host_hits))
-    return out
+    return _Rows(reps, members, kept, host, pack, evaluate, program,
+                 lambda job: job.cdb.host_eval(job.row,
+                                               job.pkg_version))
 
 
-def dispatch_jobs(jobs: list, backend: str = "tpu",
-                  mesh=None, stats: Optional[dict] = None) -> list:
-    """Mixed-job dispatcher: classic PairJobs (per-dispatch compile)
-    and ResidentPairJobs (compiled store), each in one kernel call.
-    ``stats`` (optional) receives this call's device_s and the
-    dedup counters (``jobs_in`` / ``jobs_unique``) instead of the
-    shared module global — pass one per thread."""
-    sink = stats if stats is not None else last_dispatch_stats
-    sink["device_s"] = 0.0
-    sink["dispatch_s"] = 0.0
-    sink["jobs_in"] = 0
-    sink["jobs_unique"] = 0
-    plain = [j for j in jobs if isinstance(j, PairJob)]
-    resident = [j for j in jobs if isinstance(j, ResidentPairJob)]
-    out = detect_pairs(plain, backend=backend, mesh=mesh,
-                       stats=sink) \
-        if plain else []
-    by_db: dict = {}
-    for j in resident:
-        by_db.setdefault(id(j.cdb), []).append(j)
-    for js in by_db.values():
-        out.extend(detect_pairs_resident(js, backend=backend,
-                                         mesh=mesh, stats=sink))
-    return out
+def _upload(arrays: tuple, mesh) -> tuple:
+    if mesh is not None:
+        from ..parallel.interval_shard import shard_rows
+        return shard_rows(mesh, *arrays)
+    import jax
+    return tuple(jax.device_put(a) for a in arrays)
 
 
-# ---- async slot dispatch (docs/performance.md §8) ----
-#
-# dispatch_jobs_async() splits the kernel rows into bounded WAVES,
-# enqueues every wave non-blocking (payload buffers device_put fresh
-# per wave and DONATED to the kernel), and defers the blocking
-# materialize to collect_dispatch() — or, when a DispatchRing is
-# passed, to the ring's drain thread, which blocks on wave N while
-# the submitting thread packs and uploads wave N+1. The drain
-# thread's wait is where the device wall actually passes, so its
-# device_compute spans carry the true kernel wall for the
-# idle-attribution timeline.
+class _Segment:
+    """One segment's dispatch: the rows, packed into the wave plan,
+    launched at construction; :meth:`collect` fetches the waves FIFO
+    and fans hits and host verdicts out through the dedup members."""
 
-
-def _activate_ctx(span):
-    from ..obs.trace import activate_or_null
-    return activate_or_null(span)
-
-
-class _EagerSegment:
-    """Backend with no async device path (cpu-ref): the synchronous
-    ladder already ran at dispatch; collect replays its output."""
-
-    def __init__(self, out: list):
-        self.out = out
-
-    def collect(self) -> list:
-        return self.out
-
-
-class _WaveSegment:
-    """Shared wave bookkeeping for the classic and resident async
-    paths: launch waves, collect them FIFO, fan hits back out
-    through the dedup members exactly like the synchronous path."""
-
-    def __init__(self, jobs: list, sink: dict, ring):
-        from ..obs.trace import current_span
+    def __init__(self, rows_fn, jobs: list, backend: str, mesh,
+                 sink: dict, ring):
+        from ..obs.trace import current_span, phase_span
         self.jobs = jobs
         self.sink = sink
         self.ring = ring
-        # phase spans from ring/pool threads parent under whatever
-        # span was active at launch (the batch's device span)
+        # phase spans from the ring's drain thread parent under the
+        # span active at launch (the batch's device span)
         self.ctx_span = current_span()
         self.waves: list = []
-        self.members: list = []
-        self.reps: list = []
+        with phase_span("pack", pipeline="detect",
+                        jobs=len(jobs)) as psp:
+            self.rows = rows = rows_fn(jobs, sink)
+            psp.set("unique", len(rows.reps))
+            n = len(rows.kept)
+            if not n:
+                plan = []
+            elif backend == "cpu-ref":
+                plan = [(0, n, n)]
+            else:
+                plan = _wave_plan(n)
+            packed = [rows.pack(*wave) for wave in plan]
+        _book(sink, psp, "dispatch_s")
+        if not plan:
+            return
+        if backend == "cpu-ref":
+            # the host engine: one eager evaluation, no device wave
+            with phase_span("device_compute", pipeline="detect",
+                            kind="interval", rows=n) as csp:
+                hits = rows.evaluate(packed[0])
+            _book(sink, csp, "device_s", "dispatch_s")
+            self.waves.append({"first": 0, "rows": n, "hits": hits})
+            return
+        run = rows.program(mesh)
+        for k, ((first, count, _), arrays) in enumerate(
+                zip(plan, packed)):
 
-    def _launch_wave(self, k: int, build) -> None:
-        """``build()`` does the upload + non-blocking enqueue and
-        returns the wave dict. With a ring it runs as the submit's
-        ``launch`` callable, AFTER capacity is acquired — so a full
-        ring parks before wave k+1 stages any HBM (the depth bound
-        covers staged buffers, not just bookkeeping)."""
-        if self.ring is not None:
-            built: dict = {}
+            def launch(first=first, count=count,
+                       arrays=arrays) -> dict:
+                with phase_span("h2d_upload", pipeline="detect",
+                                bytes=int(sum(a.nbytes
+                                              for a in arrays))) as usp:
+                    dev = _upload(arrays, mesh)
+                _book(sink, usp, "device_s", "dispatch_s")
+                return {"first": first, "rows": count,
+                        "lazy": run(dev)}
 
-            def _launch():
-                built["wave"] = build()
-                return built["wave"]
+            if ring is not None:
+                # launch runs AFTER the ring has a free position, so
+                # its bound covers staged HBM, not just bookkeeping
+                slot = ring.submit(self._collect_wave, launch=launch,
+                                   label=f"interval:w{k}")
+                wave = slot.payload
+                wave["slot"] = slot
+            else:
+                wave = launch()
+            DETECT_METRICS.note_wave(count)
+            self.waves.append(wave)
 
-            slot = self.ring.submit(self._collect_wave,
-                                    launch=_launch,
-                                    label=f"interval:w{k}")
-            wave = built["wave"]
-            wave["slot"] = slot
-        else:
-            wave = build()
-        DETECT_METRICS.note_wave(wave["rows"])
-        self.waves.append(wave)
-
-    def _collect_wave(self, wave: dict):
-        from ..obs.trace import phase_span
-        with _activate_ctx(self.ctx_span):
+    def _collect_wave(self, wave: dict) -> None:
+        from ..obs.trace import activate_or_null, phase_span
+        with activate_or_null(self.ctx_span):
             with phase_span("device_compute", pipeline="detect",
                             kind="interval",
                             rows=wave["rows"]) as sp:
                 # materializing blocks until the enqueued kernel
                 # finished — on the drain thread this runs
-                # concurrently with the next wave's pack/upload,
-                # and the span brackets the real device wall
-                hits = np.asarray(wave["lazy"])
-        wave["hits"] = hits
+                # concurrently with the next wave's upload, and the
+                # span brackets the real device wall
+                wave["hits"] = np.asarray(wave["lazy"])
         wave["lazy"] = None          # free the donated output early
         _book(self.sink, sp, "device_s")
         if self.ring is None:
             # collected inline: the caller's own wall (a ring's
             # drain thread overlaps the caller, whose wait is the
-            # wave_wait phase in _kernel_hits)
+            # wave_wait phase in collect)
             _book(self.sink, sp, "dispatch_s")
 
-    def _kernel_hits(self) -> list:
+    def collect(self) -> list:
         from ..obs.trace import phase_span
+        rows = self.rows
         hit_jobs: list = []
         for wave in self.waves:
             slot = wave.get("slot")
@@ -700,155 +557,17 @@ class _WaveSegment:
                 _book(self.sink, sp, "dispatch_s")
             elif "hits" not in wave:
                 self._collect_wave(wave)
+            first = wave["first"]
             for i in np.nonzero(wave["hits"][:wave["rows"]])[0]:
-                hit_jobs.extend(self.members[wave["groups"][i]])
-        return hit_jobs
-
-    def _host_hits(self, host_groups: list, eval_fn) -> list:
+                hit_jobs.extend(rows.members[rows.kept[first + i]])
+        out = [self.jobs[i].payload for i in sorted(hit_jobs)]
+        # host fallback pairs: exact per-pair evaluation, once per
+        # distinct key — the verdict fans out to every duplicate
         host_hits: list = []
-        for gi in host_groups:
-            if eval_fn(self.reps[gi]):
-                host_hits.extend(self.members[gi])
-        return host_hits
-
-
-class _ClassicSegment(_WaveSegment):
-    def __init__(self, jobs: list, mesh, sink: dict, ring,
-                 max_wave_rows: int):
-        super().__init__(jobs, sink, ring)
-        import jax
-        from ..obs.trace import phase_span
-        with phase_span("pack", pipeline="detect",
-                        jobs=len(jobs)) as psp:
-            (self.reps, self.members, spaces, rows,
-             self.host_groups) = _prep_classic(jobs, sink)
-            for sp in spaces.values():
-                sp.finalize()
-            psp.set("unique", len(self.reps))
-        _book(sink, psp, "dispatch_s")
-        if not rows:
-            return
-        w = max(1, int(max_wave_rows))
-        slices = [rows[a:a + w] for a in range(0, len(rows), w)]
-
-        def _pack(sl):
-            Pp = _job_bucket(len(sl))
-            with _activate_ctx(self.ctx_span):
-                with phase_span("pack", pipeline="detect",
-                                rows=len(sl)):
-                    return _pack_classic(sl, spaces, Pp)
-
-        # pool-parallel wave packing: the fancy-index fills of every
-        # wave run on the hostpool while this thread uploads and
-        # enqueues the waves in order (runtime/hostpool.py — pack is
-        # pure compute, never blocks on scheduler events)
-        futs = None
-        if len(slices) > 1:
-            from ..runtime.hostpool import get_host_pool
-            import threading as _threading
-            if not _threading.current_thread().name.startswith(
-                    "trivy-hostpool"):
-                pool = get_host_pool()
-                if pool is not None:
-                    futs = [pool.submit(_pack, sl) for sl in slices]
-        for k, sl in enumerate(slices):
-
-            def build(k=k, sl=sl):
-                arrays = futs[k].result() if futs is not None \
-                    else _pack(sl)
-                if mesh is not None:
-                    from ..parallel.interval_shard import \
-                        sharded_interval_hits_async
-                    lazy = sharded_interval_hits_async(mesh,
-                                                       *arrays)
-                else:
-                    from ..ops.intervals import \
-                        interval_hits_donated
-                    with phase_span("h2d_upload",
-                                    pipeline="detect", bytes=int(
-                                        sum(a.nbytes
-                                            for a in arrays))) as usp:
-                        dev = [jax.device_put(a) for a in arrays]
-                    _book(sink, usp, "dispatch_s")
-                    # dev buffers are this wave's alone → donated;
-                    # the kernel reuses the slot HBM for its output
-                    lazy = interval_hits_donated(*dev)
-                return {"lazy": lazy, "rows": len(sl),
-                        "groups": [r[0] for r in sl]}
-
-            self._launch_wave(k, build)
-
-    def collect(self) -> list:
-        hit_jobs = self._kernel_hits()
-        out = [self.jobs[i].payload for i in sorted(hit_jobs)]
-        host_hits = self._host_hits(self.host_groups, _host_eval)
-        out.extend(self.jobs[i].payload
-                   for i in sorted(host_hits))
-        return out
-
-
-class _ResidentSegment(_WaveSegment):
-    def __init__(self, jobs: list, cdb, mesh, sink: dict, ring,
-                 max_wave_rows: int):
-        super().__init__(jobs, sink, ring)
-        import jax
-        from ..obs.trace import phase_span
-        self.cdb = cdb
-        with phase_span("pack", pipeline="detect",
-                        jobs=len(jobs)) as psp:
-            (self.reps, self.members, kept, ranks, rows,
-             self.host_groups) = _prep_resident(jobs, cdb, sink)
-            psp.set("unique", len(self.reps))
-        _book(sink, psp, "dispatch_s")
-        if not kept:
-            return
-        w = max(1, int(max_wave_rows))
-        tables = cdb.device_tables(mesh=mesh) if mesh is not None \
-            else cdb.device_tables()
-        for k, a in enumerate(range(0, len(kept), w)):
-
-            def build(a=a):
-                sl_kept = kept[a:a + w]
-                P = len(sl_kept)
-                Pp = _job_bucket(P)
-                pkg_rank = np.zeros(Pp, np.int32)
-                row_idx = np.zeros(Pp, np.int32)
-                pkg_rank[:P] = ranks[a:a + w]
-                row_idx[:P] = rows[a:a + w]
-                if mesh is not None:
-                    from ..parallel.interval_shard import \
-                        sharded_interval_hits_resident_async
-                    lazy = sharded_interval_hits_resident_async(
-                        mesh, pkg_rank, row_idx, tables)
-                else:
-                    from ..ops.intervals import \
-                        interval_hits_resident_donated
-                    with phase_span("h2d_upload",
-                                    pipeline="detect", bytes=int(
-                                        pkg_rank.nbytes
-                                        + row_idx.nbytes)) as usp:
-                        dr = jax.device_put(pkg_rank)
-                        di = jax.device_put(row_idx)
-                    _book(sink, usp, "dispatch_s")
-                    # gather operands donated; the resident
-                    # advisory tables are shared state and NEVER
-                    # donated
-                    lazy = interval_hits_resident_donated(
-                        dr, di, *tables)
-                return {"lazy": lazy, "rows": P,
-                        "groups": sl_kept}
-
-            self._launch_wave(k, build)
-
-    def collect(self) -> list:
-        hit_jobs = self._kernel_hits()
-        out = [self.jobs[i].payload for i in sorted(hit_jobs)]
-        host_hits = self._host_hits(
-            self.host_groups,
-            lambda job: job.cdb.host_eval(job.row,
-                                          job.pkg_version))
-        out.extend(self.jobs[i].payload
-                   for i in sorted(host_hits))
+        for gi in rows.host:
+            if rows.host_eval(rows.reps[gi]):
+                host_hits.extend(rows.members[gi])
+        out.extend(self.jobs[i].payload for i in sorted(host_hits))
         return out
 
 
@@ -856,64 +575,62 @@ class IntervalDispatch:
     """Handle returned by :func:`dispatch_jobs_async`; pass it to
     :func:`collect_dispatch` (exactly once) to fetch results."""
 
-    def __init__(self, sink: dict):
+    def __init__(self, sink: dict, backend: str):
         self.sink = sink
+        self.backend = backend
         self.segments: list = []
 
     @property
     def waves(self) -> int:
-        # eager (cpu-ref) segments count as one synchronous
-        # dispatch; wave segments count their actual launches — a
-        # segment whose jobs all host-fell-back launched ZERO waves
-        # and must report zero
-        return sum(len(s.waves) if hasattr(s, "waves") else 1
-                   for s in self.segments)
+        # the host engine counts as one synchronous dispatch; device
+        # segments count their actual launches — a segment whose
+        # jobs all host-fell-back launched ZERO waves
+        if self.backend == "cpu-ref":
+            return 1
+        return sum(len(s.waves) for s in self.segments)
 
 
 def dispatch_jobs_async(jobs: list, backend: str = "tpu",
                         mesh=None, stats: Optional[dict] = None,
-                        ring=None,
-                        max_wave_rows: int = _WAVE_ROWS) \
-        -> IntervalDispatch:
-    """Async half of :func:`dispatch_jobs`: dedup + compile + pack,
-    then enqueue every wave without materializing. ``ring`` (a
+                        ring=None) -> IntervalDispatch:
+    """Dedup, triage and pack every segment's waves, then enqueue
+    each wave without materializing. ``ring`` (a
     runtime.ring.DispatchRing) bounds in-flight waves and collects
-    them on its drain thread; without one the waves collect lazily
-    inside :func:`collect_dispatch` on the calling thread. Output
-    (via collect_dispatch) is byte-identical to dispatch_jobs for
-    any wave size, ring depth, and device count."""
-    sink = stats if stats is not None else last_dispatch_stats
-    sink["device_s"] = 0.0
-    sink["dispatch_s"] = 0.0
-    sink["jobs_in"] = 0
-    sink["jobs_unique"] = 0
-    handle = IntervalDispatch(sink)
-    if backend == "cpu-ref":
-        # the exact host reference engine has no device work to
-        # overlap — run the synchronous ladder now (the differential
-        # baseline stays the differential baseline)
-        handle.segments.append(_EagerSegment(dispatch_jobs(
-            jobs, backend=backend, mesh=mesh, stats=sink)))
-        return handle
+    them on its drain thread; without one the waves collect inside
+    :func:`collect_dispatch` on the calling thread. ``stats``
+    receives this call's ``device_s`` (upload + kernel), its
+    ``dispatch_s`` and the dedup counters (``jobs_in`` /
+    ``jobs_unique``) — pass one per thread."""
+    sink = stats if stats is not None else {}
+    sink.update(device_s=0.0, dispatch_s=0.0, jobs_in=0,
+                jobs_unique=0)
+    handle = IntervalDispatch(sink, backend)
     plain = [j for j in jobs if isinstance(j, PairJob)]
-    resident = [j for j in jobs if isinstance(j, ResidentPairJob)]
-    if plain:
-        handle.segments.append(_ClassicSegment(
-            plain, mesh, sink, ring, max_wave_rows))
-    by_db = {}
-    for j in resident:
-        by_db.setdefault(id(j.cdb), []).append(j)
-    for js in by_db.values():
-        handle.segments.append(_ResidentSegment(
-            js, js[0].cdb, mesh, sink, ring, max_wave_rows))
+    by_db: dict = {}
+    for j in jobs:
+        if isinstance(j, ResidentPairJob):
+            by_db.setdefault(id(j.cdb), []).append(j)
+    groups = [(_classic_rows, plain)] if plain else []
+    groups += [(_resident_rows, js) for js in by_db.values()]
+    for rows_fn, js in groups:
+        handle.segments.append(
+            _Segment(rows_fn, js, backend, mesh, sink, ring))
     return handle
 
 
 def collect_dispatch(handle: IntervalDispatch) -> list:
     """Blocking half: wait for every wave (FIFO), fan hits out to
-    the duplicate payloads, evaluate host-fallback pairs — same
-    output, same order, as the synchronous dispatcher."""
+    the duplicate payloads, evaluate host-fallback pairs. The
+    classic rows' payloads come first, then each store's."""
     out: list = []
     for seg in handle.segments:
         out.extend(seg.collect())
     return out
+
+
+def dispatch_jobs(jobs: list, backend: str = "tpu", mesh=None,
+                  stats: Optional[dict] = None) -> list:
+    """The blocking form of :func:`dispatch_jobs_async`: the payloads
+    of vulnerable pairs, each segment's in batch order."""
+    return collect_dispatch(dispatch_jobs_async(
+        jobs, backend=backend, mesh=mesh, stats=stats))

@@ -57,7 +57,7 @@ def _hits(pkg_rank, vuln_lo, vuln_hi, sec_lo, sec_hi, flags):
 
 # Both implementations run under one named scope, so a profiler
 # trace names their operations ``…/interval_hits/…`` whatever jitted
-# program (plain, donated, shard_map) they were compiled into.
+# program (donated, shard_map) they were compiled into.
 
 def interval_hits_impl(pkg_rank: jax.Array, vuln_lo: jax.Array,
                        vuln_hi: jax.Array, sec_lo: jax.Array,
@@ -69,16 +69,13 @@ def interval_hits_impl(pkg_rank: jax.Array, vuln_lo: jax.Array,
                      flags)
 
 
-interval_hits = DeviceProgram(jax.jit(interval_hits_impl),
-                              "interval_hits")
-
-# donated variant for the async slot runtime (docs/performance.md
-# "Async device runtime"): every operand is a PER-BATCH payload
-# buffer staged into a dispatch-ring slot, so the kernel may reuse
-# the slot's HBM for its output — collect frees the slot for the
-# next upload instead of holding two copies alive per in-flight
-# batch. Callers must device_put fresh buffers per dispatch and
-# never touch them again (the arrays are deleted after the call).
+# donated (docs/performance.md "Async device runtime"): every
+# operand is a PER-WAVE payload buffer staged into a dispatch-ring
+# slot, so the kernel may reuse the slot's HBM for its output —
+# collect frees the slot for the next upload instead of holding two
+# copies alive per in-flight wave. Callers must device_put fresh
+# buffers per dispatch and never touch them again (the arrays are
+# deleted after the call).
 interval_hits_donated = DeviceProgram(
     jax.jit(interval_hits_impl, donate_argnums=(0, 1, 2, 3, 4, 5)),
     "interval_hits")
@@ -99,15 +96,12 @@ def interval_hits_resident_impl(pkg_rank: jax.Array,
                      flags[row_idx])
 
 
-interval_hits_resident = DeviceProgram(
-    jax.jit(interval_hits_resident_impl), "interval_hits_resident")
-
-# resident variant: ONLY the per-batch gather operands (pkg ranks +
-# candidate row indices) are donated — argnums 2..6 are the
-# HBM-resident advisory tables shared by every dispatch of a DB
-# generation, and donating one would free the store under every
-# concurrent scanner (the buffer-donation audit's hard rule:
-# payload buffers yes, resident tables never).
+# ONLY the per-wave gather operands (pkg ranks + candidate row
+# indices) are donated — argnums 2..6 are the HBM-resident advisory
+# tables shared by every dispatch of a DB generation, and donating
+# one would free the store under every concurrent scanner (the
+# buffer-donation audit's hard rule: payload buffers yes, resident
+# tables never).
 interval_hits_resident_donated = DeviceProgram(
     jax.jit(interval_hits_resident_impl, donate_argnums=(0, 1)),
     "interval_hits_resident")

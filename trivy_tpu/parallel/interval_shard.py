@@ -14,7 +14,8 @@ axes takes a slice of the (package, advisory) rows. Advisory tables:
 
 No collective is needed: hits are element-wise per pair, so the
 output inherits the input sharding and the host reads it back once
-per batch dispatch.
+per wave (detect/batch.py enqueues each wave through the lazy calls
+below and fetches it at collect).
 """
 
 from __future__ import annotations
@@ -74,41 +75,31 @@ def _build_resident_hits(mesh):
     return DeviceProgram(jax.jit(fn), "interval_hits_resident_mesh")
 
 
-def _pad_rows(n_devices: int, *arrs):
-    """Pad leading dim to a device-count multiple; pads are trimmed
-    from the output, so their (harmless) hit values never surface."""
-    P_ = arrs[0].shape[0]
-    Pp = pad_to_multiple(P_, n_devices)
-    if Pp == P_:
-        return arrs, P_
-    out = []
-    for a in arrs:
-        pad_shape = (Pp - P_,) + a.shape[1:]
-        out.append(np.concatenate([a, np.zeros(pad_shape, a.dtype)]))
-    return tuple(out), P_
+def shard_rows(mesh, *arrays) -> tuple:
+    """A wave's upload to a mesh: pad the rows to a device multiple
+    (pad rows are inert, and trimmed from the output) and place them
+    sharded over every chip."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    d, r = mesh_axis_sizes(mesh)
+    n = arrays[0].shape[0]
+    pad = pad_to_multiple(n, d * r) - n
+    return tuple(
+        jax.device_put(
+            np.concatenate([a, np.zeros((pad,) + a.shape[1:], a.dtype)])
+            if pad else a,
+            NamedSharding(mesh, P(_PAIR_AXES, *([None] * (a.ndim - 1)))))
+        for a in arrays)
 
 
 def sharded_interval_hits(mesh, pkg_rank, v_lo, v_hi, s_lo, s_hi,
-                          flags) -> np.ndarray:
-    """[P] ranks × per-pair [P, M] tables → [P] bool, pairs sharded
-    over every chip in the mesh."""
-    n = pkg_rank.shape[0]
-    lazy = sharded_interval_hits_async(mesh, pkg_rank, v_lo, v_hi,
-                                       s_lo, s_hi, flags)
-    return np.asarray(lazy)[:n]
-
-
-def sharded_interval_hits_async(mesh, pkg_rank, v_lo, v_hi, s_lo,
-                                s_hi, flags):
-    """Non-blocking variant for the slot runtime: pads + enqueues
-    the shard_map dispatch and returns the LAZY device array (rows
-    may carry device-multiple padding past the input length — pad
-    rows are inert, callers trim on materialize)."""
-    d, r = mesh_axis_sizes(mesh)
-    (pkg_rank, v_lo, v_hi, s_lo, s_hi, flags), _n = _pad_rows(
-        d * r, pkg_rank, v_lo, v_hi, s_lo, s_hi, flags)
-    fn = _build_pair_hits(mesh)
-    return fn(pkg_rank, v_lo, v_hi, s_lo, s_hi, flags)
+                          flags):
+    """[P'] ranks × per-pair [P', M] tables, placed by
+    :func:`shard_rows` → LAZY [P'] bool, enqueued and not fetched.
+    Pad rows are inert; callers trim on materialize."""
+    return _build_pair_hits(mesh)(pkg_rank, v_lo, v_hi, s_lo, s_hi,
+                                  flags)
 
 
 def replicate_tables(mesh, tables: tuple) -> tuple:
@@ -125,20 +116,8 @@ def replicate_tables(mesh, tables: tuple) -> tuple:
 
 
 def sharded_interval_hits_resident(mesh, pkg_rank, row_idx,
-                                   tables: tuple) -> np.ndarray:
-    """[P] ranks + [P] candidate-row indices against replicated
-    resident tables → [P] bool."""
-    n = pkg_rank.shape[0]
-    lazy = sharded_interval_hits_resident_async(
-        mesh, pkg_rank, row_idx, tables)
-    return np.asarray(lazy)[:n]
-
-
-def sharded_interval_hits_resident_async(mesh, pkg_rank, row_idx,
-                                         tables: tuple):
-    """Non-blocking resident variant (see
-    sharded_interval_hits_async): enqueue only, caller trims."""
-    d, r = mesh_axis_sizes(mesh)
-    (pkg_rank, row_idx), _n = _pad_rows(d * r, pkg_rank, row_idx)
-    fn = _build_resident_hits(mesh)
-    return fn(pkg_rank, row_idx, *tables)
+                                   tables: tuple):
+    """[P'] ranks + [P'] candidate-row indices, placed by
+    :func:`shard_rows`, against replicated resident tables → LAZY
+    [P'] bool (see sharded_interval_hits)."""
+    return _build_resident_hits(mesh)(pkg_rank, row_idx, *tables)

@@ -947,7 +947,7 @@ StreamingImageSource`: layer blobs decompress into the scan as they
         is given several documents: decode → name-join → one dedup
         of the whole call's jobs, whose distinct rows go to the
         resident advisory tables in waves of one warmed shape
-        (``detect/batch._resident_waves``: a call of any size above
+        (``detect/batch._wave_plan``: a call of any size above
         one wave compiles nothing the last call did not)."""
         if self.sched == "on":
             return self._scan_boms_scheduled(boms, options)
@@ -1029,7 +1029,8 @@ StreamingImageSource`: layer blobs decompress into the scan as they
         # h2d_upload / device_compute inside dispatch_jobs, finish,
         # and defer_gc's gc round this call) run one after the other
         # on the calling thread, so their seconds partition a pass's
-        # wall time; the stats keys are sums of their durations.
+        # wall time but for the waves' enqueues between them; the
+        # stats keys are sums of their durations.
 
         # ---- phase 1: decode + blob (host, this thread) ----
         # decode is the dominant host phase at fleet scale (PERF.md

@@ -12,8 +12,7 @@ from typing import Optional
 from ..applier import apply_layers
 from ..db import AdvisoryStore
 from ..db.compiled import CompiledDB
-from ..detect.batch import (PairJob, ResidentPairJob, detect_pairs,
-                            dispatch_jobs)
+from ..detect.batch import PairJob, ResidentPairJob, dispatch_jobs
 from ..detect.enrich import fill_info
 from ..detect.library import _TYPES as LIB_TYPES
 from ..detect.library import _fixed_versions, normalize_pkg_name
