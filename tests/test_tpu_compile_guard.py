@@ -98,6 +98,47 @@ def test_mesh_sieve_compiles_for_a_2x2_mesh(v5e, scanner):
              *tables).compile()
 
 
+def test_mesh_sieve_compiles_at_the_ladders_top_rung(v5e, scanner):
+    # what runtime/aot.warm_ladders warms last on a mesh: a full
+    # scheduled batch (8,192 rows), 2,048 a chip
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from trivy_tpu.parallel.mesh import (DATA_AXIS, RULES_AXIS,
+                                         make_mesh)
+    from trivy_tpu.parallel.secret_shard import sieve_rows
+    mesh = make_mesh(4, devices=v5e)
+    rows = NamedSharding(mesh, P((DATA_AXIS, RULES_AXIS), None))
+    tables = [_spec(a.shape, a.dtype,
+                    NamedSharding(mesh, P(*([None] * a.ndim))))
+              for a in scanner.table._resident_arrays()]
+    fn = scanner.table.mesh_sieve(
+        mesh, tuple(scanner.plan.run_specs), "tpu")
+    compiled = fn.lower(_spec((sieve_rows(8192, 4), SEG_LEN),
+                              jnp.uint8, rows), *tables).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mesh_resident_interval_compiles_for_a_2x2_mesh(v5e):
+    # a wave's 4,096 pairs over four chips, against trivy-db's
+    # 2,040,000 rows replicated on each (k8s-cluster-4chip)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from trivy_tpu.ops.intervals import MAX_INTERVALS
+    from trivy_tpu.parallel.interval_shard import _build_resident_hits
+    from trivy_tpu.parallel.mesh import (DATA_AXIS, RULES_AXIS,
+                                         make_mesh)
+    mesh = make_mesh(4, devices=v5e)
+    pairs = _spec((4096,), np.int32,
+                  NamedSharding(mesh, P((DATA_AXIS, RULES_AXIS))))
+    bounds = _spec((2_040_000, MAX_INTERVALS), np.int32,
+                   NamedSharding(mesh, P(None, None)))
+    flags = _spec((2_040_000,), np.int32, NamedSharding(mesh, P(None)))
+    compiled = _build_resident_hits(mesh).lower(
+        pairs, pairs, bounds, bounds, bounds, bounds, flags).compile()
+    mem = compiled.memory_analysis()
+    # the replicated table is every chip's own: 2,040,000 rows of
+    # 4 x 4 + 1 int32, about 139 MB a chip
+    assert mem.argument_size_in_bytes >= 2_040_000 * 17 * 4
+
+
 @pytest.mark.parametrize("table_rows", [1_000_000, 2_000_000])
 def test_resident_interval_kernel_compiles_for_v5e(v5e, table_rows):
     from jax.sharding import SingleDeviceSharding

@@ -1096,7 +1096,7 @@ def run_plugin(args) -> int:
 
 def run_k8s(args) -> int:
     """ref pkg/k8s/commands/run.go:58-151 — enumerate, scan, render."""
-    from .k8s import K8sScanner, ManifestClient
+    from .k8s import K8sScanner, ManifestClient, build_runner
     from .k8s.report import k8s_failed, write_k8s_report
     if _reject_unwired_fault_spec(args):
         return 2
@@ -1109,12 +1109,31 @@ def run_k8s(args) -> int:
         return 2
     _resolve_device(args)
     checks = [c for c in args.security_checks.split(",") if c]
+    store = _store(args)
+    runner = None
+    if args.images_dir and {"vuln", "secret"} & set(checks):
+        # the fleet path, on a mesh over every visible chip where
+        # there is more than one (k8s.build_runner)
+        try:
+            sched = _sched_config(args) if args.sched == "on" \
+                else "off"
+        except ValueError as e:
+            print(f"error: --tenant-config/--tenant-budget: {e}",
+                  file=sys.stderr)
+            return 2
+        runner = build_runner(store, backend=args.backend,
+                              sched=sched)
     scanner = K8sScanner(
-        store=_store(args),
+        store=store,
         backend=args.backend,
         images_dir=args.images_dir,
-        security_checks=checks)
-    report = scanner.scan(ManifestClient(args.target))
+        security_checks=checks,
+        runner=runner)
+    try:
+        report = scanner.scan(ManifestClient(args.target))
+    finally:
+        if runner is not None:
+            runner.close()
     import copy
     compliance_results = [copy.deepcopy(res) for group in
                           (report.misconfigurations,

@@ -6,9 +6,11 @@ manifests) through the Kubernetes API and loops them SEQUENTIALLY
 through the artifact runner (scanner.go:58-78). Here the enumerator
 is a seam: ``ManifestClient`` walks exported/declared manifests (this
 environment has no cluster API; a live client plugs into the same
-``artifacts()`` contract), and the scan fans the whole artifact fleet
-through ``BatchScanRunner`` — one sieve dispatch and one interval
-dispatch for every image in the cluster (SURVEY §2.6's fleet case).
+``artifacts()`` contract), and the scan fans the cluster's distinct
+images through the command's fleet runner (:func:`build_runner`: the
+scheduler, and a mesh over every visible chip) — SURVEY §2.6's fleet
+case — and maps each image's findings back to every workload that
+names it.
 """
 
 from __future__ import annotations
@@ -18,9 +20,10 @@ import re
 from dataclasses import dataclass, field
 from typing import Optional
 
+from ..obs.trace import phase_span
 from ..runtime import BatchScanRunner
-from ..types import Metadata, Report
 from ..utils import get_logger
+from .metrics import K8S_METRICS
 
 log = get_logger("k8s")
 
@@ -171,19 +174,48 @@ def resolve_image_ref(images_dir: str, ref: str) -> Optional[str]:
     return None
 
 
+def build_runner(store=None, *, backend: str = "tpu", sched="on",
+                 sched_config=None, mesh="visible") -> BatchScanRunner:
+    """The runner ``trivy-tpu k8s`` sweeps a cluster's images with,
+    built in one place so that the command and the benchmark's
+    cluster cell sweep alike: the fleet path (``sched``: ``"on"``, a
+    ``SchedConfig``, or ``"off"`` for the direct single batch) and,
+    where jax sees more than one device, a mesh over all of them
+    (``runtime.device.visible_mesh``) on which every sieve dispatch
+    and interval wave is sharded. ``mesh=None`` keeps one device.
+    The caller closes the runner."""
+    if isinstance(mesh, str):
+        from ..runtime.device import visible_mesh
+        mesh = visible_mesh(backend)
+    return BatchScanRunner(store=store, backend=backend, mesh=mesh,
+                           sched=sched, sched_config=sched_config)
+
+
 class K8sScanner:
     """ref pkg/k8s/scanner/scanner.go:30-78, with the sequential
-    artifact loop replaced by one fleet batch over every image."""
+    artifact loop replaced by one fleet scan of the cluster's
+    distinct images. ``runner`` (:func:`build_runner`'s) is the
+    caller's, who closes it; without one a sweep builds a
+    one-device runner of its own and closes it at its end."""
 
     def __init__(self, store=None, backend: str = "tpu",
-                 images_dir: str = "", security_checks=None):
+                 images_dir: str = "", security_checks=None,
+                 runner: Optional[BatchScanRunner] = None):
         self.store = store
         self.backend = backend
         self.images_dir = images_dir
         self.security_checks = security_checks or ["vuln", "config"]
+        self.runner = runner
 
     def scan(self, client) -> K8sReport:
-        artifacts = client.artifacts()
+        images = "vuln" in self.security_checks or \
+            "secret" in self.security_checks
+        with phase_span("enumerate", pipeline="k8s"):
+            artifacts = client.artifacts()
+            # (artifact, ref, path|None) for every container image
+            owners = [(a, ref, self._resolve(ref))
+                      for a in artifacts for ref in a.images] \
+                if images else []
         report = K8sReport(cluster_name=client.cluster_name)
 
         if "config" in self.security_checks or \
@@ -191,9 +223,9 @@ class K8sScanner:
             report.misconfigurations = [
                 self._scan_misconfig(a) for a in artifacts]
 
-        if "vuln" in self.security_checks or \
-                "secret" in self.security_checks:
-            report.vulnerabilities = self._scan_images(artifacts)
+        if images:
+            report.vulnerabilities = self._scan_images(artifacts,
+                                                       owners)
         return report
 
     # -- misconfigs: the manifests themselves --
@@ -224,50 +256,47 @@ class K8sScanner:
                         kind=artifact.kind, name=artifact.name,
                         results=results)
 
-    # -- vulns: every image in the cluster, ONE batch --
+    # -- vulns: every distinct image in the cluster, one fleet scan --
 
-    def _scan_images(self, artifacts: list) -> list:
-        owners: list = []       # (artifact, ref, path|None)
-        paths: list = []
-        for a in artifacts:
-            for ref in a.images:
-                path = self._resolve(ref)
-                owners.append((a, ref, path))
-                if path and path not in paths:
-                    paths.append(path)   # shared images scan once
-        if not paths:
-            return [Resource(namespace=a.namespace, kind=a.kind,
-                             name=a.name,
-                             error=f"image not resolvable: {ref}")
-                    for a, ref, path in owners if path is None]
+    def _scan_images(self, artifacts: list, owners: list) -> list:
+        # shared images scan once, in the order first named
+        paths = list(dict.fromkeys(p for _, _, p in owners if p))
+        K8S_METRICS.note_sweep(
+            workloads=sum(1 for a in artifacts if a.images),
+            image_refs=len(owners), images_distinct=len(paths),
+            images_unresolved=sum(1 for o in owners if o[2] is None))
+        by_path: dict = {}
+        if paths:
+            from ..types import ScanOptions
+            options = ScanOptions(
+                security_checks=[c for c in self.security_checks
+                                 if c in ("vuln", "secret")],
+                backend=self.backend)
+            runner = self.runner
+            if runner is None:
+                runner = build_runner(self.store,
+                                      backend=self.backend, mesh=None)
+            try:
+                if runner.sched == "on":
+                    runner.scheduler.k8s_metrics = K8S_METRICS
+                by_path = dict(zip(paths, runner.scan_paths(
+                    paths, options)))
+            finally:
+                if runner is not self.runner:
+                    runner.close()
 
-        runner = BatchScanRunner(store=self.store,
-                                 backend=self.backend)
-        options = None
-        from ..types import ScanOptions
-        options = ScanOptions(
-            security_checks=[c for c in self.security_checks
-                             if c in ("vuln", "secret")],
-            backend=self.backend)
-        batch = runner.scan_paths(paths, options)
-        by_path = {p: r for p, r in zip(paths, batch)}
-
-        out = []
-        for a, ref, path in owners:
-            if path is None:
+        with phase_span("report", pipeline="k8s"):
+            out = []
+            for a, ref, path in owners:
+                res = by_path.get(path)
+                if res is None:
+                    error, results = f"image not resolvable: {ref}", []
+                else:
+                    error = res.error
+                    results = [] if res.error else res.report.results
                 out.append(Resource(
                     namespace=a.namespace, kind=a.kind, name=a.name,
-                    error=f"image not resolvable: {ref}"))
-                continue
-            res = by_path[path]
-            if res.error:
-                out.append(Resource(
-                    namespace=a.namespace, kind=a.kind, name=a.name,
-                    error=res.error))
-            else:
-                out.append(Resource(
-                    namespace=a.namespace, kind=a.kind, name=a.name,
-                    results=res.report.results))
+                    results=results, error=error))
         return out
 
     def _resolve(self, ref: str) -> Optional[str]:

@@ -28,14 +28,21 @@ def render_summary(report) -> str:
     rows = [("Namespace", "Resource",
              "Vulnerabilities C/H/M/L/U",
              "Misconfigurations C/H/M/L/U")]
-    vuln_by_key = {}
-    for res in report.vulnerabilities:
-        key = (res.namespace, f"{res.kind}/{res.name}")
-        vuln_by_key[key] = _counts(res.results, "vulnerabilities")
-    misc_by_key = {}
-    for res in report.misconfigurations:
-        key = (res.namespace, f"{res.kind}/{res.name}")
-        misc_by_key[key] = _counts(res.results, "misconfigurations")
+
+    def by_key(resources, attr) -> dict:
+        # a workload is one entry an image: its row sums them all
+        out: dict = {}
+        for res in resources:
+            key = (res.namespace, f"{res.kind}/{res.name}")
+            c = _counts(res.results, attr)
+            if key in out:
+                c = {s: out[key][s] + c[s] for s in _SEVS}
+            out[key] = c
+        return out
+
+    vuln_by_key = by_key(report.vulnerabilities, "vulnerabilities")
+    misc_by_key = by_key(report.misconfigurations,
+                         "misconfigurations")
 
     def fmt(c):
         if c is None:
@@ -75,14 +82,17 @@ def render_all(report) -> str:
 def write_k8s_report(report, fmt: str = "table",
                      mode: str = "summary", output=None) -> None:
     import sys
+
+    from ..obs.trace import phase_span
     out = output or sys.stdout
-    if fmt == "json":
-        json.dump(report.to_dict(), out, indent=2)
-        out.write("\n")
-    elif mode == "all":
-        out.write(render_all(report))
-    else:
-        out.write(render_summary(report))
+    with phase_span("report", pipeline="k8s"):
+        if fmt == "json":
+            json.dump(report.to_dict(), out, indent=2)
+            out.write("\n")
+        elif mode == "all":
+            out.write(render_all(report))
+        else:
+            out.write(render_summary(report))
 
 
 def k8s_failed(report) -> bool:

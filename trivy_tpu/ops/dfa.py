@@ -676,7 +676,8 @@ def _build_mesh_sieve(table: DfaTable, mesh, run_specs: tuple,
     masks_fn = _masks_fn(table, platform)
     row = P((DATA_AXIS, RULES_AXIS), None)
 
-    def local(segments, *dev):
+    # its name is the program's in a profiler trace (jit_sieve_mesh)
+    def sieve_mesh(segments, *dev):
         masks = masks_fn(segments, dev).astype(jnp.uint16)
         if run_specs:
             hits = run_hits_impl(segments, run_specs)
@@ -688,7 +689,7 @@ def _build_mesh_sieve(table: DfaTable, mesh, run_specs: tuple,
                 for a in table._resident_arrays())
     # check_vma off: masks are row-elementwise per shard, nothing
     # is replicated on the way out
-    fn = jax.shard_map(local, mesh=mesh, in_specs=(row,) + rep,
+    fn = jax.shard_map(sieve_mesh, mesh=mesh, in_specs=(row,) + rep,
                        out_specs=(row, row), check_vma=False)
     return DeviceProgram(jax.jit(fn), "dfa_mesh_sieve")
 

@@ -60,13 +60,16 @@ def _build_resident_hits(mesh):
     row = P(_PAIR_AXES)
     rep = P(None, None)
 
-    def local(pkg_rank, row_idx, v_lo, v_hi, s_lo, s_hi, flags):
+    # its name is the program's in a profiler trace
+    # (jit_interval_hits_resident_mesh)
+    def interval_hits_resident_mesh(pkg_rank, row_idx, v_lo, v_hi,
+                                    s_lo, s_hi, flags):
         return interval_hits_impl(
             pkg_rank, v_lo[row_idx], v_hi[row_idx],
             s_lo[row_idx], s_hi[row_idx], flags[row_idx])
 
     fn = jax.shard_map(
-        local,
+        interval_hits_resident_mesh,
         mesh=mesh,
         in_specs=(row, row, rep, rep, rep, rep, P(None)),
         out_specs=row,

@@ -49,9 +49,16 @@ import threading
 
 import numpy as np
 
-from ..ops.keywords import CODE_CHUNK, code_blockmask_impl
+from ..ops.keywords import CODE_CHUNK, _bucket, code_blockmask_impl
 from .mesh import (DATA_AXIS, RULES_AXIS, mesh_axis_sizes,
                    pad_to_multiple)
+
+
+def sieve_rows(rows: int, n_devices: int) -> int:
+    """The rows of the buffer a sharded dispatch uploads for
+    ``rows`` segment rows: their ``ops.keywords._bucket`` rung, a
+    multiple of ``n_devices`` pallas tiles (TILE_B 32 rows)."""
+    return pad_to_multiple(_bucket(rows), n_devices * 32)
 
 
 class ShardedSieve:
@@ -72,6 +79,8 @@ class ShardedSieve:
         self.metas = metas
         self.lay = scanner._layout(metas)
         self.occupancy = self.lay["occupancy"]
+        # each shard's real segment rows (SECRET_METRICS' sieve_shard*)
+        self.shard_rows: list = []
         self.device_s = 0.0       # the join's dfa_scan span
         self._out = None
 
@@ -91,8 +100,11 @@ class ShardedSieve:
         self.n_valid = lay["B"]
         n_flat = int(sc.mesh.devices.size)
         # the shard_map splits the leading dim over every chip, and
-        # the pallas kernel tiles each chip's block by TILE_B rows
-        B = pad_to_multiple(lay["B"], n_flat * 32)
+        # the pallas kernel tiles each chip's block by TILE_B rows;
+        # the rows take a rung of the segment ladder, as the one-chip
+        # path's do, so that runtime/aot.warm_ladders can warm every
+        # shape a batch gives
+        B = sieve_rows(lay["B"], n_flat)
         self.buf = buf = np.zeros((B, sc.seg_len), np.uint8)
         self.seg_file = lay["seg_file"]
         self.seg_pos = lay["seg_pos"]
@@ -102,6 +114,8 @@ class ShardedSieve:
         for row0, mi in lay["layout"]:
             by_shard[row0 // rps].append((row0, mi))
         by_shard = [blk for blk in by_shard if blk]
+        self.shard_rows = [sum(self.metas[mi][2] for _, mi in blk)
+                           for blk in by_shard]
 
         pool = get_host_pool()
         on_pool = threading.current_thread().name.startswith(

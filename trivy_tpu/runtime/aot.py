@@ -369,8 +369,75 @@ def precompile_dfa_shapes(scanner, buckets: Iterable[int],
     return out
 
 
+def precompile_mesh_sieve_shapes(scanner, buckets: Iterable[int],
+                                 cache_dir: str = "") -> dict:
+    """:func:`precompile_dfa_shapes` for a scanner with a mesh: its
+    sharded sieve (``DfaTable.mesh_sieve``, the program
+    ``parallel/secret_shard.ShardedSieve`` dispatches) over the
+    segment ladder, each rung padded as a sharded dispatch pads it
+    (``secret_shard.sieve_rows``), staging the table on every chip
+    as a side effect. The mesh sieve fetches its whole mask, so it
+    has no full-fetch variant."""
+    import jax
+    import numpy as np
+
+    from ..parallel.secret_shard import sieve_rows
+    table, seg_len, mesh = scanner.table, scanner.seg_len, scanner.mesh
+    n_flat = int(mesh.devices.size)
+    manifest = _Manifest(cache_dir)
+    out = {"kernel": "dfa_mesh", "shapes": [], "seconds": 0.0}
+    tbl = table.device_tables(mesh)
+    fn = table.mesh_sieve(mesh, tuple(scanner.plan.run_specs),
+                          jax.default_backend())
+    for b in sorted({sieve_rows(int(x), n_flat) for x in buckets
+                     if int(x) > 0}):
+        seg = np.zeros((b, seg_len), np.uint8)
+        key = cache_key("dfa_mesh", f"B{b}xL{seg_len}xD{n_flat}",
+                        table.rules_hash)
+        out["seconds"] += _warm_call(
+            fn, (seg,) + tuple(tbl), key, manifest,
+            {"kernel": "dfa_mesh", "B": b, "devices": n_flat,
+             "rules_hash": table.rules_hash})
+        out["shapes"].append(b)
+    out["seconds"] = round(out["seconds"], 4)
+    return out
+
+
+def precompile_mesh_interval_shapes(cdb, mesh, buckets: Iterable[int],
+                                    cache_dir: str = "") -> dict:
+    """:func:`precompile_interval_shapes` on a mesh: the resident
+    interval kernel a wave runs there
+    (``parallel/interval_shard.sharded_interval_hits_resident``),
+    its gather operands placed as a wave places them
+    (``shard_rows``), against ``cdb``'s tables replicated on every
+    chip, which this stages."""
+    import numpy as np
+
+    from ..parallel.interval_shard import (
+        shard_rows, sharded_interval_hits_resident)
+    n_flat = int(mesh.devices.size)
+    manifest = _Manifest(cache_dir)
+    out = {"kernel": "interval_resident_mesh", "shapes": [],
+           "seconds": 0.0}
+    tables = cdb.device_tables(mesh=mesh)
+    sig = "x".join(str(d) for d in tables[0].shape)
+    for p in sorted(set(int(b) for b in buckets if int(b) > 0)):
+        args = shard_rows(mesh, np.zeros(p, np.int32),
+                          np.zeros(p, np.int32))
+        key = cache_key("interval_resident_mesh",
+                        f"P{p}xT{sig}xD{n_flat}")
+        out["seconds"] += _warm_call(
+            lambda *dev: sharded_interval_hits_resident(
+                mesh, *dev, tables), args, key, manifest,
+            {"kernel": "interval_resident_mesh", "P": p,
+             "devices": n_flat})
+        out["shapes"].append(p)
+    out["seconds"] = round(out["seconds"], 4)
+    return out
+
+
 def warm_ladders(secret_scanner=None, store=None, config=None,
-                 cache_dir: str = "") -> dict:
+                 cache_dir: str = "", mesh=None) -> dict:
     """Run every program a scheduled batch can meet once, on inert
     inputs, before the first request: the fused sieve at every
     :func:`sieve_rungs` rung of ``secret_scanner`` and the resident
@@ -387,9 +454,10 @@ def warm_ladders(secret_scanner=None, store=None, config=None,
     (``cache_dir``, see :func:`configure_compile_cache`); a
     directory that cannot be written costs compile time, not the
     boot; a kernel that does not compile is a program fault and
-    propagates (``ops.program.DeviceProgramError``). A sharded sieve
-    (a scanner with a mesh) and the cpu-ref engine have no program
-    here and are left alone."""
+    propagates (``ops.program.DeviceProgramError``). A scanner with
+    a mesh has its sharded sieve warmed instead, and with ``mesh``
+    the interval rungs are the mesh's; the cpu-ref engine has no
+    program here and is left alone."""
     from ..db import CompiledDB
     if config is None:
         from ..sched import SchedConfig
@@ -406,22 +474,25 @@ def warm_ladders(secret_scanner=None, store=None, config=None,
         summary["error"] = repr(e)
         cache_dir = ""
     if secret_scanner is not None \
-            and getattr(secret_scanner, "mesh", None) is None \
             and getattr(secret_scanner, "backend", "") != "cpu-ref":
-        summary["kernels"].append(precompile_dfa_shapes(
-            secret_scanner,
-            sieve_rungs(config.max_batch_bytes,
-                        secret_scanner.seg_len,
-                        secret_scanner.overlap),
-            cache_dir))
+        rungs = sieve_rungs(config.max_batch_bytes,
+                            secret_scanner.seg_len,
+                            secret_scanner.overlap)
+        warm = precompile_dfa_shapes \
+            if getattr(secret_scanner, "mesh", None) is None \
+            else precompile_mesh_sieve_shapes
+        summary["kernels"].append(warm(secret_scanner, rungs,
+                                       cache_dir))
     release = None
     if hasattr(store, "acquire") and hasattr(store, "release"):
         store, release = store.acquire(), store.release
     try:
         if isinstance(store, CompiledDB):
-            summary["kernels"].append(precompile_interval_shapes(
-                store, interval_rungs(config.max_batch_jobs),
-                cache_dir))
+            rungs = interval_rungs(config.max_batch_jobs)
+            summary["kernels"].append(
+                precompile_interval_shapes(store, rungs, cache_dir)
+                if mesh is None else precompile_mesh_interval_shapes(
+                    store, mesh, rungs, cache_dir))
     finally:
         if release is not None:
             release()

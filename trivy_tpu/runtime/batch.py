@@ -157,11 +157,13 @@ class BatchScanRunner:
             from .aot import warm_ladders
             from .device import on_accelerator
             if self.warm and self.backend != "cpu-ref" \
-                    and self.mesh is None and on_accelerator():
+                    and on_accelerator():
                 # every shape a batch can take compiles now, or
                 # comes from the persistent cache, and not in the
-                # middle of the fleet
-                warm_ladders(self.secret_scanner, self.store, cfg)
+                # middle of the fleet: on a mesh, its sharded
+                # programs
+                warm_ladders(self.secret_scanner, self.store, cfg,
+                             mesh=self.mesh)
         return self._scheduler
 
     def close(self) -> None:

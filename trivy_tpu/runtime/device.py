@@ -116,3 +116,18 @@ def resolve_device(backend: str = "tpu") -> DeviceInfo:
         log.info("device: platform=%s device_kind=%s devices=%d",
                  info.platform, info.device_kind, info.devices)
     return info
+
+
+def visible_mesh(backend: str = "tpu"):
+    """A ``(data, rules)`` mesh (``parallel.mesh.make_mesh``) over
+    every device jax sees, where it sees more than one, else None:
+    one device keeps the single-device path. Call it after
+    :func:`resolve_device`. ``cpu-ref`` has no device and no
+    mesh."""
+    if backend == "cpu-ref":
+        return None
+    import jax
+    if len(jax.devices()) < 2:
+        return None
+    from ..parallel.mesh import make_mesh
+    return make_mesh()

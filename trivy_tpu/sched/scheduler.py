@@ -102,6 +102,10 @@ class ScanScheduler:
         # that rides this scheduler (it sets this), or None;
         # ``stats()["rpc"]`` carries its snapshot
         self.rpc_metrics = None
+        # k8s_metrics: the cluster sweeps' ``k8s.metrics.K8sMetrics``
+        # where ``K8sScanner`` rides this scheduler, or None;
+        # ``stats()["k8s"]`` carries its snapshot
+        self.k8s_metrics = None
         # tracer: trivy_tpu.obs.Tracer — every admitted request gets
         # a root span with per-stage children (docs/observability.md)
         self.tracer = tracer if tracer is not None else get_tracer()
@@ -374,6 +378,8 @@ class ScanScheduler:
             # and the ``rpc`` rows of the phase clock
             # (rpc/metrics.py), as "ingest" holds the walkers'
             out["rpc"] = self.rpc_metrics.snapshot()
+        if self.k8s_metrics is not None:
+            out["k8s"] = self.k8s_metrics.snapshot()
         return out
 
     def cost_snapshot(self) -> dict:

@@ -463,6 +463,7 @@ class BatchSecretScanner:
             "device_s": round(handle["device_s"], 4),
             "verify_s": round(vsp.duration_s, 4),
             "shard_occupancy": handle.get("shard_occupancy", []),
+            "shard_rows": handle.get("shard_rows", []),
             "mode": handle.get("mode", ""),
         }
         SECRET_METRICS.note_batch(self.stats)
@@ -498,7 +499,8 @@ class BatchSecretScanner:
                 sharded.start()
             handle.update(mode="sharded", sharded=sharded,
                           pack_s=sp.duration_s,
-                          shard_occupancy=sharded.occupancy)
+                          shard_occupancy=sharded.occupancy,
+                          shard_rows=sharded.shard_rows)
             return handle
 
         with phase_span("pack", pipeline="secret",

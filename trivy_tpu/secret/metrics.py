@@ -42,6 +42,10 @@ class SecretMetrics:
         "dfa_invalidations",
         # async sharded submission (parallel/secret_shard.py)
         "shards_dispatched", "decode_tasks",
+        # a sharded dispatch's shards, their real segment rows, and
+        # the real rows of its fullest shard: max x shards / rows is
+        # 1.0 where the LPT layout (parallel/balance.py) is even
+        "sieve_shards", "sieve_shard_rows", "sieve_shard_rows_max",
         # dispatches whose hit rows passed SIEVE_CAP: the whole mask
         # fetched by a second dispatch (secret/batch._decode)
         "sieve_full_fetches",
@@ -82,6 +86,11 @@ class SecretMetrics:
             c["verify_bytes"] += stats.get("verify_bytes", 0)
             if stats.get("mode") in ("fused", "sharded"):
                 c["device_bytes"] += stats.get("bytes_total", 0)
+            rows = stats.get("shard_rows")
+            if stats.get("mode") == "sharded" and rows:
+                c["sieve_shards"] += len(rows)
+                c["sieve_shard_rows"] += sum(rows)
+                c["sieve_shard_rows_max"] += max(rows)
             if stats.get("mode") == "fused":
                 c["sieve_rows"] += stats.get("sieve_rows", 0)
                 c["sieve_rows_padded"] += stats.get(
