@@ -1236,14 +1236,16 @@ def stage_mesh(sz: dict, work: str, n_devices: int = 4) -> dict:
 
     # the shard layout of the mesh programs' own outputs: rows must
     # flatten over all four chips of the (2, 2) mesh, not two
+    from trivy_tpu.parallel.secret_shard import row_sharding
     sc = runner.secret_scanner
     rows = 32 * n_devices * 4
-    masks, _ = sc.table.mesh_sieve(
+    *_, run_hits = sc.table.mesh_sieve(
         mesh, tuple(sc.plan.run_specs), jax.default_backend())(
-            np.zeros((rows, sc.seg_len), np.uint8),
+            jax.device_put(np.zeros((rows, sc.seg_len), np.uint8),
+                           row_sharding(mesh)),
             *sc.table.device_tables(mesh))
     per_dev = {int(s.device.id): int(s.data.shape[0])
-               for s in masks.addressable_shards}
+               for s in run_hits.addressable_shards}
     check(len(per_dev) == n_devices
           and set(per_dev.values()) == {rows // n_devices},
           f"sieve rows per device: {per_dev}")

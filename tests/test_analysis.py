@@ -300,19 +300,19 @@ class TestLockDiscipline:
 
 
 PR5_POOL_SELF_JOIN = """
-from trivy_tpu.runtime.hostpool import get_host_pool, map_in_pool
+from trivy_tpu.runtime.hostpool import get_host_pool
 
 def pack_segment(seg):
     pool = get_host_pool()
     return list(pool.map(str, seg))
 
 def sieve_enqueue(items):
-    return map_in_pool(pack_segment, items)
+    return [get_host_pool().submit(pack_segment, s) for s in items]
 """
 
 PR5_GUARDED = """
 import threading
-from trivy_tpu.runtime.hostpool import get_host_pool, map_in_pool
+from trivy_tpu.runtime.hostpool import get_host_pool
 
 def pack_segment(seg):
     if threading.current_thread().name.startswith(
@@ -322,7 +322,7 @@ def pack_segment(seg):
     return list(pool.map(str, seg))
 
 def sieve_enqueue(items):
-    return map_in_pool(pack_segment, items)
+    return [get_host_pool().submit(pack_segment, s) for s in items]
 """
 
 
@@ -345,30 +345,30 @@ class TestHostpoolBlocking:
         shadow each other in the index — the second job's blocking
         facts were silently dropped before (review regression)."""
         src = ("from trivy_tpu.runtime.hostpool import "
-               "get_host_pool, map_in_pool\n"
+               "get_host_pool\n"
                "def parent_a(items):\n"
                "    def job(x):\n"
                "        return x\n"
-               "    return map_in_pool(job, items)\n"
+               "    return get_host_pool().submit(job, items)\n"
                "def parent_b(items):\n"
                "    def job(x):\n"
                "        pool = get_host_pool()\n"
                "        return pool.submit(str, x).result()\n"
-               "    return map_in_pool(job, items)\n")
+               "    return get_host_pool().submit(job, items)\n")
         fs = _findings(src, rule="hostpool-blocking")
         assert len(fs) >= 1
         assert any(f.line == 9 for f in fs), fs
 
     def test_transitive_reach_flagged(self):
         src = ("from trivy_tpu.runtime.hostpool import "
-               "get_host_pool, map_in_pool\n"
+               "get_host_pool\n"
                "def leaf(x):\n"
                "    pool = get_host_pool()\n"
                "    return pool.submit(str, x).result()\n"
                "def middle(x):\n"
                "    return leaf(x)\n"
                "def outer(items):\n"
-               "    return map_in_pool(middle, items)\n")
+               "    return get_host_pool().submit(middle, items)\n")
         fs = _findings(src, rule="hostpool-blocking")
         assert len(fs) == 1 and "leaf" in fs[0].message
 

@@ -240,25 +240,18 @@ class TestMetricsSurface:
         assert after["verify_s"] >= before["verify_s"]
 
 
-class TestHostpoolChunking:
-    def test_chunked_map_preserves_order(self):
-        from trivy_tpu.runtime.hostpool import map_in_pool
-        items = list(range(333))
-        assert map_in_pool(lambda x: x * 3, items, chunk=64) == \
-            [x * 3 for x in items]
+class TestHostpoolSize:
+    def test_env_sets_the_size(self, monkeypatch):
+        from trivy_tpu.runtime.hostpool import pool_size
+        monkeypatch.setenv("TRIVY_TPU_HOST_POOL", "3")
+        assert pool_size() == 3
+        monkeypatch.setenv("TRIVY_TPU_HOST_POOL", "0")
+        assert pool_size() == 0
 
-    def test_chunked_map_fewer_tasks(self, monkeypatch):
-        from concurrent.futures import ThreadPoolExecutor
-
+    def test_default_leaves_two_cores_and_caps_at_eight(
+            self, monkeypatch):
         import trivy_tpu.runtime.hostpool as hp
-        from trivy_tpu.detect.metrics import DETECT_METRICS
-        pool = ThreadPoolExecutor(max_workers=2,
-                                  thread_name_prefix="trivy-hostpool")
-        monkeypatch.setattr(hp, "_POOL", pool)
-        try:
-            before = DETECT_METRICS.snapshot()["pack_tasks"]
-            hp.map_in_pool(lambda x: x, list(range(256)), chunk=64)
-            after = DETECT_METRICS.snapshot()["pack_tasks"]
-            assert after - before == 4      # 256/64 slab tasks
-        finally:
-            pool.shutdown(wait=False)
+        monkeypatch.setenv("TRIVY_TPU_HOST_POOL", "not-a-number")
+        for cores, want in ((1, 0), (2, 0), (5, 3), (64, 8)):
+            monkeypatch.setattr(hp.os, "cpu_count", lambda c=cores: c)
+            assert hp.pool_size() == want, cores

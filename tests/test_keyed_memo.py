@@ -275,11 +275,10 @@ def _cyclonedx(n: int, rng: random.Random) -> bytes:
         "components": comps}).encode()
 
 
-def test_pooled_decode_equals_the_one_thread_loop(monkeypatch):
-    """``decode_to_blob`` over 200 documents through ``map_in_pool``
-    with eight threads, as ``scan_boms`` spreads it: blobs and blob
-    ids equal the plain loop's, document for document."""
-    import trivy_tpu.runtime.hostpool as hp
+def test_pooled_decode_equals_the_one_thread_loop():
+    """``decode_to_blob`` over 200 documents on eight threads that
+    share the purl memo: blobs and blob ids equal the plain loop's,
+    document for document."""
     from trivy_tpu.artifact.sbom import decode_to_blob
     rng = random.Random(28)
     docs = [_cyclonedx(n, rng) for n in range(200)]
@@ -293,11 +292,10 @@ def test_pooled_decode_equals_the_one_thread_loop(monkeypatch):
     purl._parse_cache().clear()
     pool = ThreadPoolExecutor(max_workers=THREADS,
                               thread_name_prefix="trivy-hostpool")
-    monkeypatch.setattr(hp, "_POOL", pool)
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-6)
     try:
-        pooled = hp.map_in_pool(decode_to_blob, docs, chunk=8)
+        pooled = list(pool.map(decode_to_blob, docs))
     finally:
         sys.setswitchinterval(interval)
         pool.shutdown(wait=True)

@@ -110,12 +110,12 @@ def test_host_sieve_brackets_kernel_not_decode(tmp_path):
 
 
 def test_sharded_sieve_busy_span_at_join():
-    """Mesh/sharded path: the dfa_scan busy span lives at decode()'s
-    blocking join (fetch=True) — where the async dispatch's device
-    wall actually passes — and the dispatch side (pool-parallel
-    packing + non-blocking enqueue) brackets as pack, so mesh-run
-    idle attribution doesn't count host packing as device-busy or
-    the sieve compute as collect_bound."""
+    """Mesh/sharded path: the spans of a one-chip fused dispatch —
+    pack (which names its layout's shards), h2d_upload, the
+    non-blocking enqueue as dfa_scan, and the dfa_scan busy span at
+    the blocking join (fetch=True), where the async dispatch's device
+    wall actually passes — so mesh-run idle attribution reads as one
+    chip's."""
     from trivy_tpu.obs import Tracer
     from trivy_tpu.parallel import make_mesh
     from trivy_tpu.secret.batch import BatchSecretScanner
@@ -132,8 +132,10 @@ def test_sharded_sieve_busy_span_at_join():
     spans = tracer.recorder.get(root.trace_id)
     dfa = [s for s in spans if s.name == "dfa_scan"]
     assert dfa, "sharded sieve recorded no dfa_scan span"
-    assert all(s.attrs.get("fetch") for s in dfa), \
-        [s.attrs for s in dfa]
+    assert sorted(bool(s.attrs.get("fetch")) for s in dfa) == \
+        [False, True], [s.attrs for s in dfa]
+    assert not any(s.attrs.get("host") for s in dfa)
+    assert any(s.name == "h2d_upload" for s in spans)
     assert any(s.name == "pack" and "shards" in s.attrs
                for s in spans)
 

@@ -302,23 +302,26 @@ def test_purl_cache_isolation():
         purl.from_string("not-a-purl")
 
 
-def test_nested_map_in_pool_runs_inline_no_deadlock(monkeypatch):
-    """A pool task that itself calls map_in_pool must run the inner
-    map inline: with every worker occupied by such a task, the
-    nested pool.map would deadlock (the direct path's sieve enqueue
-    packs segments through map_in_pool from a pool thread)."""
-    from concurrent.futures import ThreadPoolExecutor
+def test_host_pool_is_one_named_pool_or_none(monkeypatch):
+    """``get_host_pool`` builds one pool a process, its threads
+    named ``trivy-hostpool`` (the guard ``hostpool-blocking`` and the
+    lock witness key on the name), and none where it is switched
+    off: the direct path's sieve enqueue then runs inline."""
+    import threading
 
     import trivy_tpu.runtime.hostpool as hp
-    pool = ThreadPoolExecutor(max_workers=1,
-                              thread_name_prefix="trivy-hostpool")
-    monkeypatch.setattr(hp, "_POOL", pool)
+    monkeypatch.setattr(hp, "_POOL", None)
+    monkeypatch.setenv("TRIVY_TPU_HOST_POOL", "0")
+    assert hp.get_host_pool() is None
+    monkeypatch.setenv("TRIVY_TPU_HOST_POOL", "1")
+    pool = hp.get_host_pool()
     try:
-        fut = pool.submit(
-            lambda: hp.map_in_pool(lambda x: x * 2,
-                                   list(range(20))))
-        assert fut.result(timeout=30) == [x * 2 for x in range(20)]
+        assert pool is not None and hp.get_host_pool() is pool
+        name = pool.submit(
+            lambda: threading.current_thread().name).result(timeout=30)
+        assert name.startswith("trivy-hostpool")
     finally:
+        monkeypatch.setattr(hp, "_POOL", None)
         pool.shutdown(wait=False)
 
 

@@ -483,12 +483,15 @@ def test_a_malformed_document_fails_its_own_slot(bad, table, docs, cdb):
             expected(table, facts), name
 
 
-def test_scan_boms_decodes_on_the_calling_thread(docs, cdb):
+def test_scan_boms_decodes_on_the_calling_thread(docs, cdb,
+                                                monkeypatch):
     """One ``decode_task`` a call, on the caller's thread, and no
     task handed to the host pool, however many the documents."""
     import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    import trivy_tpu.runtime.hostpool as hp
     from trivy_tpu.artifact import sbom as artifact_sbom
-    from trivy_tpu.detect.metrics import DETECT_METRICS
     from trivy_tpu.obs.trace import phase_rows
     from trivy_tpu.runtime import BatchScanRunner
     runner = BatchScanRunner(store=cdb, backend="cpu")
@@ -499,16 +502,21 @@ def test_scan_boms_decodes_on_the_calling_thread(docs, cdb):
         seen.add(threading.current_thread().name)
         return real(data)
 
+    class Refusing(ThreadPoolExecutor):
+        def submit(self, *a, **kw):
+            raise AssertionError("a task handed to the host pool")
+
+    pool = Refusing(max_workers=1, thread_name_prefix="trivy-hostpool")
+    monkeypatch.setattr(hp, "_POOL", pool)
     artifact_sbom.decode_to_blob = watched
     try:
-        tasks = DETECT_METRICS.snapshot()["pack_tasks"]
         spans = phase_rows("detect").get("decode_task", {}).get("n", 0)
         results = runner.scan_boms([(n, d) for n, d, _ in docs])
     finally:
         artifact_sbom.decode_to_blob = real
+        pool.shutdown(wait=False)
     assert len(docs) > 64 and all(r.status == "ok" for r in results)
     assert seen == {threading.current_thread().name}
-    assert DETECT_METRICS.snapshot()["pack_tasks"] == tasks
     assert phase_rows("detect")["decode_task"]["n"] == spans + 1
 
 

@@ -421,11 +421,10 @@ def test_pack_equals_fill_rows_reference(packers, case, layout):
 
 def test_secret_pack_submits_no_pool_task(monkeypatch):
     """``dispatch_files`` packs on the calling thread: the host pool
-    sees no task and ``pack_tasks`` stays where it was."""
+    sees no task."""
     from concurrent.futures import ThreadPoolExecutor
 
     import trivy_tpu.runtime.hostpool as hp
-    from trivy_tpu.detect.metrics import DETECT_METRICS
 
     class Counting(ThreadPoolExecutor):
         submitted = 0
@@ -443,10 +442,8 @@ def test_secret_pack_submits_no_pool_task(monkeypatch):
         files = [(f"f{i}", rng.integers(32, 127, int(n))
                   .astype(np.uint8).tobytes())
                  for i, n in enumerate(rng.integers(1, 3000, 600))]
-        before = DETECT_METRICS.snapshot()["pack_tasks"]
         handle = s.dispatch_files(files)
         assert handle["buf"].shape[0] >= 600
-        assert DETECT_METRICS.snapshot()["pack_tasks"] == before
         assert Counting.submitted == 0
     finally:
         pool.shutdown(wait=False)
@@ -458,8 +455,8 @@ def test_pack_buffer_is_allocated_padded():
     from trivy_tpu.ops.keywords import _bucket, pad_batch
     s = BatchSecretScanner(backend="cpu-ref")
     entries = _entries([5000] * 100 + [300] * 50, seed=3)
-    padded, seg_file, _pos, _occ = s._pack(entries)
-    B = len(seg_file)
+    padded, lay = s._pack(entries)
+    B = len(lay["seg_file"])
     assert B == 100 * s._n_segs(5000) + 50
     assert padded.shape == (_bucket(B), s.seg_len) and B < _bucket(B)
     assert padded[:B].all(axis=1).sum() >= 100   # full rows written

@@ -117,6 +117,24 @@ def test_mesh_sieve_compiles_at_the_ladders_top_rung(v5e, scanner):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_mesh_full_sieve_compiles_at_the_ladders_top_rung(v5e,
+                                                          scanner):
+    # the whole-mask twin a mesh dispatch falls back to where a
+    # chip's hit rows overflow its capacity, warmed at the top rung
+    from trivy_tpu.parallel.mesh import make_mesh
+    from trivy_tpu.parallel.secret_shard import row_sharding, sieve_rows
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = make_mesh(4, devices=v5e)
+    tables = [_spec(a.shape, a.dtype,
+                    NamedSharding(mesh, P(*([None] * a.ndim))))
+              for a in scanner.table._resident_arrays()]
+    fn = scanner.table.mesh_full_sieve(mesh, (), "tpu")
+    compiled = fn.lower(_spec((sieve_rows(8192, 4), SEG_LEN),
+                              jnp.uint8, row_sharding(mesh)),
+                        *tables).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_mesh_resident_interval_compiles_for_a_2x2_mesh(v5e):
     # a wave's 4,096 pairs over four chips, against trivy-db's
     # 2,040,000 rows replicated on each (k8s-cluster-4chip)

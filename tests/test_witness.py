@@ -268,17 +268,25 @@ class TestPoolSelfJoin:
         pool = fresh_pool.get_host_pool()
         assert pool.submit(str, 7).result(timeout=10) == "7"
 
-    def test_map_in_pool_guard_still_safe(self, fresh_pool,
+    def test_thread_name_guard_still_safe(self, fresh_pool,
                                           lock_witness):
-        """``map_in_pool`` from a pool thread falls back inline
-        (the PR-5 fix) — the witness must not misfire on it."""
-        from trivy_tpu.runtime.hostpool import map_in_pool
+        """A task that runs its inner work inline on a pool thread
+        (the PR-5 fix, the guard ``hostpool-blocking`` looks for)
+        and joins the pool only from elsewhere: the witness must not
+        misfire on it."""
+        pool = fresh_pool.get_host_pool()
+        digits = list("123456789" * 2)
 
         def task(_):
-            return sum(map_in_pool(int, list("123456789" * 2)))
+            if threading.current_thread().name.startswith(
+                    "trivy-hostpool"):
+                return sum(int(c) for c in digits)
+            return sum(f.result(timeout=10) for f in
+                       [pool.submit(int, c) for c in digits])
 
-        out = map_in_pool(task, list(range(12)))
-        assert out == [sum(int(c) for c in "123456789" * 2)] * 12
+        futs = [pool.submit(task, i) for i in range(12)]
+        out = [f.result(timeout=10) for f in futs] + [task(12)]
+        assert out == [sum(int(c) for c in digits)] * 13
 
 
 # ---------------------------------------------------------------

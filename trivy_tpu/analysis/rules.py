@@ -324,9 +324,6 @@ class Index:
                 if call.args:
                     facts.pool_entries.append(
                         (call.lineno, call.args[0]))
-            if name == "map_in_pool" and call.args:
-                facts.pool_entries.append(
-                    (call.lineno, call.args[0]))
         if submit_seen:
             for _held, call in facts.calls:
                 if _call_name(call) == "result":
@@ -581,7 +578,7 @@ class LockDisciplineRule(Rule):
 
 class HostpoolBlockingRule(Rule):
     """Every callable handed to the host pool (first argument of
-    ``map_in_pool`` / ``pool.submit``) is an entry; the rule walks
+    ``pool.submit``) is an entry; the rule walks
     the call graph from each entry and flags any reachable
     function that blocks on the pool (``pool.map``, submit-then-
     ``result()``) WITHOUT the thread-name guard

@@ -51,8 +51,6 @@ class DetectMetrics:
         # advisory rows the name join built from the table's columns
         # (scan/local._vuln_jobs, added once a join)
         "table_rows_decoded",
-        # host packing pool (runtime/hostpool.py)
-        "pack_tasks",
     )
 
     def __init__(self):

@@ -378,22 +378,30 @@ class DfaTable(ResidentTables):
         return self._fn(("full", run_specs, platform))
 
     def mesh_sieve(self, mesh, run_specs: tuple, platform: str):
-        """Mesh variant: the segment rows shard over EVERY chip
-        (flat — masks are row-elementwise, no collective needed),
-        the band arrays replicate, and the whole sieve is ONE
-        shard_map dispatch — one compile per (mesh, shape), where a
-        per-device dispatch loop would compile once per DEVICE per
-        shape (measured ~1.3 s × devices × shapes of pure compile
-        thrash on the CPU sim). Returns (masks [B, K] uint16,
-        run_hits [B, n_specs])."""
+        """The sharded twin of :meth:`fused_sieve`: the segment rows
+        shard over EVERY chip (flat — masks are row-elementwise, no
+        collective), the band arrays replicate, and each chip
+        compacts its own hit rows. ONE shard_map dispatch — one
+        compile per (mesh, shape), where a per-device dispatch loop
+        would compile once per DEVICE per shape. Returns (nhit [n]
+        a chip, idx [n * cap] global rows, cmasks [n * cap, K],
+        run_hits [B, n_specs]); a chip's ``cap`` is
+        ``SIEVE_CAP // n`` or its rows, whichever is fewer."""
         return self._fn(("mesh", mesh, run_specs, platform))
+
+    def mesh_full_sieve(self, mesh, run_specs: tuple, platform: str):
+        """The sharded twin of :meth:`full_sieve`: (masks [B, K]
+        uint16, run_hits). The mesh path falls back to it (with
+        ``run_specs=()``) when a chip's hit rows overflow its
+        capacity."""
+        return self._fn(("mesh_full", mesh, run_specs, platform))
 
     def _fn(self, key: tuple):
         with self._fns_lock:
             fn = self._fns.get(key)
             if fn is None:
-                if key[0] == "mesh":
-                    fn = _build_mesh_sieve(self, *key[1:])
+                if key[0].startswith("mesh"):
+                    fn = _build_mesh_sieve(self, *key)
                 else:
                     fn = _build_sieve(self, *key)
                 self._fns[key] = fn
@@ -667,17 +675,31 @@ def _masks_fn(table: DfaTable, platform: str):
     return masks_fn
 
 
-def _build_mesh_sieve(table: DfaTable, mesh, run_specs: tuple,
-                      platform: str):
+def _compacted(masks, cap: int, K: int) -> tuple:
+    """(nhit, idx, cmasks): the rows of ``masks`` with a hit, the
+    first ``cap`` of them gathered to the front (the rest of ``idx``
+    is 0); ``nhit`` over ``cap`` means the fetch has to be whole."""
+    B = masks.shape[0]
+    seg_any = (masks != 0).any(axis=1) if K else \
+        jnp.zeros((B,), bool)
+    nhit = seg_any.sum(dtype=jnp.int32)
+    idx = jnp.nonzero(seg_any, size=cap, fill_value=0)[0]
+    return nhit, idx, masks[idx]
+
+
+def _build_mesh_sieve(table: DfaTable, kind: str, mesh,
+                      run_specs: tuple, platform: str):
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DATA_AXIS, RULES_AXIS
     from .runs import run_hits_impl
     masks_fn = _masks_fn(table, platform)
-    row = P((DATA_AXIS, RULES_AXIS), None)
+    K = table.n_patterns
+    axes = (DATA_AXIS, RULES_AXIS)
+    n = int(mesh.devices.size)
+    row = P(axes, None)
 
-    # its name is the program's in a profiler trace (jit_sieve_mesh)
-    def sieve_mesh(segments, *dev):
+    def local(segments, dev):
         masks = masks_fn(segments, dev).astype(jnp.uint16)
         if run_specs:
             hits = run_hits_impl(segments, run_specs)
@@ -685,13 +707,34 @@ def _build_mesh_sieve(table: DfaTable, mesh, run_specs: tuple,
             hits = jnp.zeros((segments.shape[0], 0), jnp.bool_)
         return masks, hits
 
+    # the names are the programs' in a profiler trace
+    # (jit_sieve_mesh, jit_sieve_mesh_full)
+    def sieve_mesh_full(segments, *dev):
+        return local(segments, dev)
+
+    def sieve_mesh(segments, *dev):
+        masks, hits = local(segments, dev)
+        b = segments.shape[0]          # this chip's rows
+        nhit, idx, cmasks = _compacted(masks, min(SIEVE_CAP // n, b),
+                                       K)
+        return (nhit[None], idx + lax.axis_index(axes) * b, cmasks,
+                hits)
+
     rep = tuple(P(*([None] * a.ndim))
                 for a in table._resident_arrays())
-    # check_vma off: masks are row-elementwise per shard, nothing
+    # check_vma off: each chip's outputs are its own rows, nothing
     # is replicated on the way out
-    fn = jax.shard_map(sieve_mesh, mesh=mesh, in_specs=(row,) + rep,
-                       out_specs=(row, row), check_vma=False)
-    return DeviceProgram(jax.jit(fn), "dfa_mesh_sieve")
+    if kind == "mesh_full":
+        fn, name, out = sieve_mesh_full, "dfa_mesh_full_sieve", \
+            (row, row)
+    else:
+        fn, name, out = sieve_mesh, "dfa_mesh_sieve", \
+            (P(axes), P(axes), row, row)
+    # the segment buffer is donated, as on one chip (_build_sieve)
+    return DeviceProgram(
+        jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(row,) + rep,
+                              out_specs=out, check_vma=False),
+                donate_argnums=(0,)), name)
 
 
 def _build_sieve(table: DfaTable, kind: str, run_specs: tuple,
@@ -725,12 +768,7 @@ def _build_sieve(table: DfaTable, kind: str, run_specs: tuple,
     def fused(segments, *dev):
         masks = masks_fn(segments, dev).astype(jnp.uint16)
         B = segments.shape[0]
-        cap = min(SIEVE_CAP, B)
-        seg_any = (masks != 0).any(axis=1) if K else \
-            jnp.zeros((B,), bool)
-        nhit = seg_any.sum(dtype=jnp.int32)
-        idx = jnp.nonzero(seg_any, size=cap, fill_value=0)[0]
-        cmasks = masks[idx]
+        nhit, idx, cmasks = _compacted(masks, min(SIEVE_CAP, B), K)
         if run_specs:
             hits = run_hits_impl(segments, run_specs)
         else:

@@ -372,33 +372,45 @@ def precompile_dfa_shapes(scanner, buckets: Iterable[int],
 def precompile_mesh_sieve_shapes(scanner, buckets: Iterable[int],
                                  cache_dir: str = "") -> dict:
     """:func:`precompile_dfa_shapes` for a scanner with a mesh: its
-    sharded sieve (``DfaTable.mesh_sieve``, the program
-    ``parallel/secret_shard.ShardedSieve`` dispatches) over the
-    segment ladder, each rung padded as a sharded dispatch pads it
-    (``secret_shard.sieve_rows``), staging the table on every chip
-    as a side effect. The mesh sieve fetches its whole mask, so it
-    has no full-fetch variant."""
+    sharded sieve (``DfaTable.mesh_sieve``, the program a mesh
+    dispatch of ``secret/batch.py`` calls) over the segment ladder,
+    each rung padded as a mesh dispatch pads it
+    (``secret_shard.sieve_rows``) and uploaded as it uploads it (a
+    committed row-sharded array), staging the table on every chip
+    as a side effect; and its whole-mask twin
+    (``DfaTable.mesh_full_sieve``) at every rung where a chip's rows
+    are more than its compacted capacity, so can overflow it."""
     import jax
     import numpy as np
 
-    from ..parallel.secret_shard import sieve_rows
+    from ..ops.dfa import SIEVE_CAP
+    from ..parallel.secret_shard import row_sharding, sieve_rows
     table, seg_len, mesh = scanner.table, scanner.seg_len, scanner.mesh
     n_flat = int(mesh.devices.size)
+    rows = row_sharding(mesh)
     manifest = _Manifest(cache_dir)
-    out = {"kernel": "dfa_mesh", "shapes": [], "seconds": 0.0}
+    out = {"kernel": "dfa_mesh", "shapes": [], "full_shapes": [],
+           "seconds": 0.0}
     tbl = table.device_tables(mesh)
+    platform = jax.default_backend()
     fn = table.mesh_sieve(mesh, tuple(scanner.plan.run_specs),
-                          jax.default_backend())
+                          platform)
+    full = table.mesh_full_sieve(mesh, (), platform)
     for b in sorted({sieve_rows(int(x), n_flat) for x in buckets
                      if int(x) > 0}):
-        seg = np.zeros((b, seg_len), np.uint8)
-        key = cache_key("dfa_mesh", f"B{b}xL{seg_len}xD{n_flat}",
-                        table.rules_hash)
-        out["seconds"] += _warm_call(
-            fn, (seg,) + tuple(tbl), key, manifest,
-            {"kernel": "dfa_mesh", "B": b, "devices": n_flat,
-             "rules_hash": table.rules_hash})
-        out["shapes"].append(b)
+        warm = [("dfa_mesh", fn, "shapes")]
+        if b // n_flat > SIEVE_CAP // n_flat:
+            warm.append(("dfa_mesh_full", full, "full_shapes"))
+        for kernel, prog, shapes in warm:
+            # the sieve donates its segment buffer: a fresh one a call
+            seg = jax.device_put(np.zeros((b, seg_len), np.uint8), rows)
+            key = cache_key(kernel, f"B{b}xL{seg_len}xD{n_flat}",
+                            table.rules_hash)
+            out["seconds"] += _warm_call(
+                prog, (seg,) + tuple(tbl), key, manifest,
+                {"kernel": kernel, "B": b, "devices": n_flat,
+                 "rules_hash": table.rules_hash})
+            out[shapes].append(b)
     out["seconds"] = round(out["seconds"], 4)
     return out
 

@@ -1,15 +1,16 @@
-"""Bounded host worker pool for packing and decode work
-(docs/performance.md "host/device overlap").
+"""Bounded host worker pool (docs/performance.md "host/device
+overlap").
 
 One process-wide pool, sized to the host's spare cores, reserved for
-tasks that NEVER block on scheduler events: the mesh sieve's
-per-shard segment packing and block decode
-(parallel/secret_shard.py), interval wave packing (detect/batch.py)
-and the direct path's sieve enqueue.
-The one-device secret pack is NOT here: it runs on the thread that
-calls ``dispatch_files`` without letting go of the interpreter
-(secret/batch.py ``_pack``), because a task a file made that thread
-wait for the lock hundreds of times a batch. Keeping the pool
+tasks that NEVER block on scheduler events. Its one user is the
+direct path's sieve enqueue (runtime/batch.py), which runs a batch's
+``dispatch_files`` here while the calling thread enqueues the
+interval waves. The secret sieve's pack and decode are NOT split
+into tasks here, on one device or on a mesh: they run on the thread
+that calls ``dispatch_files`` or ``collect`` without letting go of
+the interpreter (secret/batch.py ``_pack``), because a task a file
+or a shard made those threads wait for the lock hundreds of times a
+batch. Keeping the pool
 separate from the scheduler's worker pool is load-bearing, not
 stylistic — the scheduler pool runs
 ``finish`` tasks that wait on patch events only the device thread
@@ -58,7 +59,7 @@ def pool_size() -> int:
 
 
 def get_host_pool():
-    """The shared packing/decode pool, or None when disabled."""
+    """The shared pool, or None when disabled."""
     global _POOL
     if _POOL is None:
         with _LOCK:
@@ -70,38 +71,3 @@ def get_host_pool():
                     max_workers=n,
                     thread_name_prefix="trivy-hostpool")
     return _POOL
-
-
-def map_in_pool(fn, items: list, chunk: int = 1) -> list:
-    """``[fn(x) for x in items]`` spread over the pool (input order
-    preserved). Falls back to the inline loop when the pool is
-    disabled, the batch is too small to amortize the hops, or the
-    CALLER is itself a pool worker — a task that blocks on
-    ``pool.map`` of its own pool deadlocks the moment every worker
-    is such a task (the direct path's sieve enqueue runs here and
-    then packs segments through here again). ``fn`` must capture
-    its own errors — a raising task would abandon the batch.
-
-    ``chunk > 1`` batches that many items per pool task, for
-    items whose work is less than a hop to a worker costs."""
-    from ..detect.metrics import DETECT_METRICS
-    on_pool_thread = threading.current_thread().name.startswith(
-        "trivy-hostpool")
-    pool = get_host_pool() \
-        if len(items) > max(8, chunk) and not on_pool_thread \
-        else None
-    def task(slab: list) -> list:
-        return [fn(x) for x in slab]
-
-    if pool is None:
-        return task(items)
-    if chunk > 1:
-        slabs = [items[i:i + chunk]
-                 for i in range(0, len(items), chunk)]
-        DETECT_METRICS.inc("pack_tasks", len(slabs))
-        out: list = []
-        for part in pool.map(task, slabs):
-            out.extend(part)
-        return out
-    DETECT_METRICS.inc("pack_tasks", len(items))
-    return list(pool.map(fn, items))

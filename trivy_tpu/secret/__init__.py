@@ -4,7 +4,7 @@ CPU path: exact reference semantics (pkg/fanal/secret/scanner.go).
 TPU path: multi-pattern DFA sieve (trivy_tpu.ops.dfa — full-length
 keywords, anchors, and per-rule fixed chains in one banded table) +
 class-run gates (trivy_tpu.ops.runs) + sparse host verification,
-orchestrated by trivy_tpu.secret.batch (sharded async over a mesh —
+orchestrated by trivy_tpu.secret.batch (the rows sharded over a mesh:
 trivy_tpu.parallel.secret_shard).
 """
 

@@ -25,14 +25,12 @@ pkg/fanal/secret/scanner.go:341):
      those rules — byte-identical findings, because every rule that
      could contribute findings (or censoring) survives the sieve.
 
-With a mesh, the sieve is submitted SHARDED AND ASYNC
-(parallel/secret_shard.py): per-shard segment packing fans over the
-host pool concurrently, ONE non-blocking shard_map dispatch splits
-the rows across every chip (so the sieve computes while the caller
-squashes layers, preps interval jobs, and packs the next batch), and
-per-shard result decode fans back over the pool — the host thread
-never serializes the whole sieve, which is what used to make
-``secret_batch_s`` GROW with device count.
+With a mesh the host steps are the same: the rows are laid out in
+per-shard blocks balanced by volume, packed on the calling thread,
+uploaded by one ``device_put`` to the row sharding, and decoded by
+the same loop; only the device program is the fused one's sharded
+twin (parallel/secret_shard.py, ``DfaTable.mesh_sieve``), in which
+each chip compacts its own hit rows.
 """
 
 from __future__ import annotations
@@ -42,8 +40,8 @@ from typing import Iterable, Optional
 
 import numpy as np
 
-from ..ops.keywords import (MAX_CODE_LEN, N_BLOCKS, PART_ROWS,
-                            _bucket, pad_batch)
+from ..ops.keywords import MAX_CODE_LEN, N_BLOCKS, PART_ROWS, _bucket
+from ..parallel.secret_shard import row_sharding, sieve_rows
 from ..utils import get_logger
 from .plan import ScanPlan, build_scan_plan
 from .rx.anchor import SPACE_1TO1
@@ -226,11 +224,8 @@ class BatchSecretScanner:
     def _fill_rows(self, buf: np.ndarray, row0: int, content: bytes,
                    n_segs: int) -> None:
         """Pack one file's overlapping segments into ``buf`` rows
-        [row0, row0+n_segs) with one bulk strided copy. The mesh
-        path packs with it, a shard a pool task
-        (parallel/secret_shard.py); the one-device path packs a
-        whole batch in ``_pack`` instead, and the tests hold that to
-        the rows this writes."""
+        [row0, row0+n_segs) with one bulk strided copy: the plain
+        reference the tests hold ``_pack``'s rows to."""
         L, step = self.seg_len, self.seg_len - self.overlap
         n = len(content)
         arr = np.frombuffer(content, np.uint8)
@@ -254,7 +249,8 @@ class BatchSecretScanner:
         (rows of ``seg_file == -1`` are inert — all-zero segments
         match no pattern and the decoders skip them). Returns
         {B, layout: [(row0, meta idx)], seg_file, seg_pos,
-        occupancy, n_shards, rows_per_shard}."""
+        occupancy, n_shards, rows_per_shard, shard_rows (each
+        non-empty shard's real rows)}."""
         step = self.seg_len - self.overlap
         n_shards = self._shard_count()
         occupancy: list = []
@@ -290,12 +286,16 @@ class BatchSecretScanner:
             # overflowing its LPT block (occupancy shows it) can
             # force a wider shard
             rows_per_shard = Bp // n_shards
-            nat = max(sum(metas[mi][2] for mi in block) or 1
-                      for block in by_shard)
+            # a shard's rows; a file has one at least, so the
+            # non-empty shards are those with rows
+            shard_rows = [sum(metas[mi][2] for mi in block)
+                          for block in by_shard]
+            nat = max(shard_rows) or 1
             if nat > rows_per_shard:
                 rows_per_shard = -(-nat // MIN_SHARD_ROWS) * \
                     MIN_SHARD_ROWS
             B = n_shards * rows_per_shard
+            shard_rows = [r for r in shard_rows if r]
             layout = []          # (row0, meta index)
             for s, block in enumerate(by_shard):
                 row = s * rows_per_shard
@@ -309,6 +309,7 @@ class BatchSecretScanner:
                 layout.append((row, mi))
                 row += m[2]
             n_shards, rows_per_shard = 1, B
+            shard_rows = [B] if B else []
 
         seg_file = [-1] * B
         seg_pos = [0] * B
@@ -320,18 +321,20 @@ class BatchSecretScanner:
         return {"B": B, "layout": layout, "seg_file": seg_file,
                 "seg_pos": seg_pos, "occupancy": occupancy,
                 "n_shards": n_shards,
-                "rows_per_shard": rows_per_shard}
+                "rows_per_shard": rows_per_shard,
+                "shard_rows": shard_rows}
 
     def _metas(self, files: list) -> list:
         return [(fe, len(fe.content), self._n_segs(len(fe.content)))
                 for fe in files if len(fe.content) > 0]
 
     def _pack(self, files: list) -> tuple:
-        """Flatten files into [_bucket(B), L] uint8 with per-file
-        overlap chaining. Returns (padded buffer, seg_file, seg_pos,
-        shard_occupancy); the real rows are the first
-        ``len(seg_file)``, the rest are zero, so ``pad_batch`` has
-        nothing to add before the upload.
+        """Flatten files into [B', L] uint8 with per-file overlap
+        chaining. Returns (padded buffer, the ``_layout`` it was
+        packed to); the real rows are the layout's ``B``, the rest are
+        zero, so the buffer goes to the device as it is. ``B'`` is
+        ``secret_shard.sieve_rows``: on one device and on a mesh
+        alike, the rung of ``_bucket``.
 
         The whole pack runs on the calling thread and never lets go
         of the interpreter: one zeroed allocation, then one
@@ -339,15 +342,13 @@ class BatchSecretScanner:
         task. On the scheduler's launch thread every call that drops
         the lock (a pool future, a numpy copy of over ~500 elements)
         is a wait of up to the switch interval behind the analyze
-        workers, and that wait, not the copy, was the pack's cost.
-        (The sharded-async path packs a shard a pool task instead:
-        parallel.secret_shard.)"""
+        workers, and that wait, not the copy, was the pack's cost."""
         L, step = self.seg_len, self.seg_len - self.overlap
         metas = self._metas(files)
-        if not metas:
-            return np.zeros((0, L), np.uint8), [], [], []
         lay = self._layout(metas)
-        raw = bytearray(_bucket(lay["B"]) * L)
+        if not metas:
+            return np.zeros((0, L), np.uint8), lay
+        raw = bytearray(sieve_rows(lay["B"], self._shard_count()) * L)
         out = memoryview(raw)
         for row0, mi in lay["layout"]:
             fe, n, n_segs = metas[mi]
@@ -358,15 +359,14 @@ class BatchSecretScanner:
                 out[at:at + L] = src[off:off + L]
                 at += L
             out[at:at + n - last] = src[last:]
-        return (np.frombuffer(raw, np.uint8).reshape(-1, L),
-                lay["seg_file"], lay["seg_pos"], lay["occupancy"])
+        return np.frombuffer(raw, np.uint8).reshape(-1, L), lay
 
     def _segment(self, files: list) -> tuple:
         """``_pack`` without the pad rows: (buffer [B, L], seg_file,
         seg_pos, shard_occupancy)."""
-        padded, seg_file, seg_pos, occupancy = self._pack(files)
-        return (padded[:len(seg_file)], seg_file, seg_pos,
-                occupancy)
+        padded, lay = self._pack(files)
+        return (padded[:lay["B"]], lay["seg_file"], lay["seg_pos"],
+                lay["occupancy"])
 
     # --- the public API ---
 
@@ -389,8 +389,8 @@ class BatchSecretScanner:
         interval job prep); ``collect`` fetches + verifies.
 
         On the cpu-ref backend the dispatch runs eagerly; with a
-        mesh, per-shard packing fans over the host pool and one
-        non-blocking shard_map dispatch covers every chip."""
+        mesh, one non-blocking shard_map dispatch covers every
+        chip."""
         entries = [
             _FileEntry(path=p, content=c, index=i)
             for i, (p, c) in enumerate(files)
@@ -444,9 +444,9 @@ class BatchSecretScanner:
         self.stats = {
             "files_total": len(entries),
             "bytes_total": sum(len(fe.content) for fe in entries),
-            # real segment rows, and the rows of the rung a fused
-            # dispatch uploaded (the sharded path pads a shard at a
-            # time and says nothing here)
+            # the layout's segment rows (a mesh's shard padding
+            # among them), and the rows of the rung a device
+            # dispatch uploaded
             "sieve_rows": len(handle.get("seg_file", ())),
             "sieve_rows_padded": handle.get("padded_rows", 0),
             "files_gated": len(candidates),
@@ -473,45 +473,21 @@ class BatchSecretScanner:
 
     def _dispatch(self, entries: list) -> dict:
         """Segment + enqueue the sieve. Returns the handle `_decode`
-        consumes; on the fused and sharded paths the jax arrays
-        inside are NOT yet materialized — the device(s) compute in
-        the background."""
+        consumes; on the device paths the jax arrays inside are NOT
+        yet materialized — the device(s) compute in the background.
+        One chip and a mesh take the same host steps; only the
+        program and the upload's placement differ."""
         from ..obs.trace import phase_span
         handle = {"entries": entries, "device_s": 0.0}
-        if self.mesh is not None and self.backend != "cpu-ref":
-            # sharded async submission: concurrent per-shard packs
-            # on the host pool, one non-blocking mesh dispatch,
-            # decode fanned back over the pool at collect time
-            from ..parallel.secret_shard import ShardedSieve
-            metas = self._metas(entries)
-            if not metas:
-                handle["mode"] = "empty"
-                return handle
-            # start() is host work: shard layout + pool-parallel
-            # segment fills, then a NON-blocking mesh enqueue — so
-            # it brackets as pack, not device-busy; the dfa_scan
-            # busy span lives at ShardedSieve.decode()'s join,
-            # where the device wall actually passes
-            with phase_span("pack", pipeline="secret",
-                            files=len(entries),
-                            shards=self._shard_count()) as sp:
-                sharded = ShardedSieve(self, metas)
-                sharded.start()
-            handle.update(mode="sharded", sharded=sharded,
-                          pack_s=sp.duration_s,
-                          shard_occupancy=sharded.occupancy,
-                          shard_rows=sharded.shard_rows)
-            return handle
-
         with phase_span("pack", pipeline="secret",
                         files=len(entries)) as sp:
-            padded, seg_file, seg_pos, occupancy = \
-                self._pack(entries)
-            buf = padded[:len(seg_file)]
+            padded, lay = self._pack(entries)
+            buf = padded[:lay["B"]]
             sp.set("segments", int(buf.shape[0]))
-        handle.update(buf=buf, padded=padded, seg_file=seg_file,
-                      seg_pos=seg_pos, pack_s=sp.duration_s,
-                      shard_occupancy=occupancy)
+            sp.set("shards", lay["n_shards"])
+        handle.update(buf=buf, padded=padded, seg_file=lay["seg_file"],
+                      seg_pos=lay["seg_pos"], pack_s=sp.duration_s,
+                      shard_occupancy=lay["occupancy"])
         if buf.shape[0] == 0:
             handle["mode"] = "empty"
             return handle
@@ -529,31 +505,80 @@ class BatchSecretScanner:
             handle["mode"] = "host"
             handle["device_s"] += sp.duration_s
             return handle
-        # fused path: the segment buffer is uploaded ONCE,
-        # pattern blockmasks + run hits come out of a single dispatch
-        # against the resident band table, and the mask fetch is
-        # compacted to the hit rows (selectivity makes this ~1% of
-        # the full [B, K] array; the >CAP fallback fetches all)
+        # the segment buffer is uploaded ONCE, pattern blockmasks +
+        # run hits come out of a single dispatch against the
+        # resident band table, and the mask fetch is compacted to
+        # the hit rows, a chip's own on a mesh (selectivity makes
+        # this ~1% of the full [B, K] array; the >CAP fallback
+        # fetches all)
         import jax
         platform = jax.default_backend()
         specs = tuple(self.plan.run_specs)
-        tbl = self.table.device_tables()
-        fn = self.table.fused_sieve(specs, platform)
+        if self.mesh is None:
+            mode, place = "fused", None
+            fn = self.table.fused_sieve(specs, platform)
+            tbl = self.table.device_tables()
+        else:
+            from .metrics import SECRET_METRICS
+            mode, place = "sharded", row_sharding(self.mesh)
+            fn = self.table.mesh_sieve(self.mesh, specs, platform)
+            tbl = self.table.device_tables(self.mesh)
+            SECRET_METRICS.inc("shards_dispatched",
+                               len(lay["shard_rows"]))
+            handle["shard_rows"] = lay["shard_rows"]
         with phase_span("h2d_upload", pipeline="secret",
                         bytes=int(buf.nbytes)) as usp:
-            dev = jax.device_put(pad_batch(padded))
-        padded_rows = int(dev.shape[0])
+            dev = jax.device_put(padded, place)
         with phase_span("dfa_scan", pipeline="secret",
                         segments=int(buf.shape[0]),
                         patterns=self.table.n_patterns) as sp:
             # the segment buffer is donated to the kernel — ``dev``
             # is dead after this call (the >CAP fallback re-uploads)
             nhit, idx, cm, h = fn(dev, *tbl)
-        handle.update(mode="fused", platform=platform,
-                      padded_rows=padded_rows,
+        handle.update(mode=mode, platform=platform, place=place,
+                      padded_rows=int(dev.shape[0]),
                       tbl=tbl, nhit=nhit, idx=idx, cm=cm, h=h)
         handle["device_s"] += usp.duration_s + sp.duration_s
         return handle
+
+    def _fetch_hits(self, handle: dict) -> tuple:
+        """(segment rows, pattern columns, blockmasks) of every hit
+        a device dispatch found. ``nhit`` is one int a chip, and a
+        chip's hit rows are the head of its ``cap`` rows of ``idx``
+        and ``cm``; where any chip found more than ``cap``, the
+        whole mask comes back by a second dispatch."""
+        B = handle["buf"].shape[0]
+        K = self.table.n_patterns
+        nhit = np.asarray(handle["nhit"]).reshape(-1)
+        cap = handle["idx"].shape[0] // nhit.shape[0]
+        if int(nhit.max()) > cap:
+            import jax
+            from .metrics import SECRET_METRICS
+            SECRET_METRICS.inc("sieve_full_fetches")
+            # run hits (h) were already computed by the first
+            # dispatch. It DONATED its segment buffer (ops/dfa.py),
+            # so this rare overflow path re-uploads rather than
+            # reuse freed HBM
+            if self.mesh is None:
+                full = self.table.full_sieve((), handle["platform"])
+            else:
+                full = self.table.mesh_full_sieve(
+                    self.mesh, (), handle["platform"])
+            m, _ = full(jax.device_put(handle["padded"],
+                                       handle["place"]),
+                        *handle["tbl"])
+            masks = np.asarray(m)[:B, :K]
+            seg_nz, code_nz = np.nonzero(masks)
+            return seg_nz, code_nz, masks[seg_nz, code_nz]
+        # one chip: the head of its rows, a view; a mesh: each
+        # chip's head, gathered
+        keep = slice(int(nhit[0])) if nhit.shape[0] == 1 else \
+            (np.arange(cap) < nhit[:, None]).reshape(-1)
+        rows = np.asarray(handle["cm"])[keep, :K]
+        rnz, code_nz = np.nonzero(rows)
+        # padded rows (index ≥ B) never hit: zero segments
+        return (np.asarray(handle["idx"])[keep][rnz], code_nz,
+                rows[rnz, code_nz])
 
     def _decode(self, handle: dict) -> dict:
         """file index → {rule index: verify spans or None}.
@@ -567,25 +592,6 @@ class BatchSecretScanner:
         if handle["mode"] == "empty":
             return {}
         entries = handle["entries"]
-
-        if handle["mode"] == "sharded":
-            # the join (dfa_scan, fetch=True) comes first, the
-            # pool-fanned block decode and the rule choice after it
-            masks, runs = handle["sharded"].fetch()
-            handle["device_s"] += handle["sharded"].device_s
-            with phase_span("decode", pipeline="secret",
-                            mode="sharded") as sp:
-                file_codes, runs_map = handle["sharded"].decode(
-                    masks, runs)
-
-                def file_runs(fidx) -> set:
-                    return runs_map.get(fidx, set())
-
-                out = self._choose(handle, entries, file_codes,
-                                   file_runs)
-            handle["decode_s"] = sp.duration_s
-            return out
-
         buf = handle["buf"]
         seg_file = handle["seg_file"]
         seg_pos = handle["seg_pos"]
@@ -604,37 +610,8 @@ class BatchSecretScanner:
             # the timeline counts it as device-busy, not collect work
             with phase_span("dfa_scan", pipeline="secret",
                             fetch=True) as fsp:
-                B = buf.shape[0]
-                K = self.table.n_patterns
-                nhit = int(handle["nhit"])
-                cm = handle["cm"]
-                h = handle["h"]
-                if nhit > min(cm.shape[0], handle["padded_rows"]):
-                    from .metrics import SECRET_METRICS
-                    SECRET_METRICS.inc("sieve_full_fetches")
-                    # fetch the full mask array; run hits (h) were
-                    # already computed by the fused dispatch. The
-                    # fused dispatch DONATED its segment buffer
-                    # (ops/dfa.py), so this rare overflow path
-                    # re-uploads rather than reuse freed HBM
-                    import jax as _jax
-                    full = self.table.full_sieve(
-                        (), handle["platform"])
-                    m, _ = full(
-                        _jax.device_put(pad_batch(handle["padded"])),
-                        *handle["tbl"])
-                    masks = np.asarray(m)[:B, :K]
-                    seg_nz, code_nz = np.nonzero(masks)
-                    hit_vals = masks[seg_nz, code_nz]
-                else:
-                    rows = np.asarray(cm)[:nhit, :K]
-                    ridx = np.asarray(handle["idx"])[:nhit]
-                    rnz, code_nz = np.nonzero(rows)
-                    # padded rows (index ≥ B) never hit: zero
-                    # segments
-                    seg_nz = ridx[rnz]
-                    hit_vals = rows[rnz, code_nz]
-                run_fetch = np.asarray(h)[:B]
+                seg_nz, code_nz, hit_vals = self._fetch_hits(handle)
+                run_fetch = np.asarray(handle["h"])[:buf.shape[0]]
             handle["device_s"] += fsp.duration_s
 
         # run-hits decode is lazy: it happens at most once per batch,

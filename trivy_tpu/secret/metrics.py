@@ -40,7 +40,8 @@ class SecretMetrics:
         # DFA table residency (ops/dfa.py DfaTable hooks)
         "dfa_uploads", "dfa_upload_bytes", "dfa_dispatches",
         "dfa_invalidations",
-        # async sharded submission (parallel/secret_shard.py)
+        # a mesh dispatch's non-empty layout shards; decode_tasks
+        # stays 0 (the decode runs on the collecting thread)
         "shards_dispatched", "decode_tasks",
         # a sharded dispatch's shards, their real segment rows, and
         # the real rows of its fullest shard: max x shards / rows is
